@@ -76,6 +76,15 @@ def _time_call(fn, *args, reps=3):
     return (time.perf_counter() - t0) / reps * 1e6
 
 
+def _fake_device_env():
+    """Environment of a child process that forces fake host devices: it
+    runs on the CPU, so it never reaches for a chip this process holds."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 # Fields every BENCH_sampling.json row must carry (each sweep includes
 # its sweep-specific payload beside them).
 _BENCH_REQUIRED_FIELDS = ("section", "timestamp", "mode")
@@ -170,11 +179,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time, jax
 from repro.core import AdaptiveConfig, rmat_graph, run_kadabra
-from repro.launch.mesh import make_mesh_compat
 g = rmat_graph(9, 8, seed=1)
 for agg in ["hierarchical", "flat", "root"]:
     cfg = AdaptiveConfig(eps=0.08, delta=0.1, aggregation=agg, n0_base=400)
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     t0 = time.perf_counter()
     res = run_kadabra(g, mesh=mesh, config=cfg, key=jax.random.PRNGKey(0))
     print(f"AGG {agg} {time.perf_counter()-t0:.3f} {res.tau} {res.n_epochs}")
@@ -194,8 +203,7 @@ def bench_fig2(full: bool):
              f"pct={100*sec/max(total,1e-9):.1f}")
 
     print("\n== §IV-E/F analogue: aggregation modes on a 2x2x2 mesh ==")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _fake_device_env()
     out = subprocess.run([sys.executable, "-c", _AGG_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=1200)
     if out.returncode:
@@ -362,7 +370,7 @@ def bench_node_blocked_sweep(full: bool, interpret: bool = True):
         dist, sigma = res.dist, res.sigma
         levels = jnp.full((B,), 2, jnp.int32)
         # eligibility: the flat kernel's all-resident (V+1, B) state
-        flat_ok = pallas_supported(g.n_nodes, g.e_pad, batch=B)
+        flat_ok = pallas_supported(g.n_nodes, batch=B)
         lanes = {
             "xla_ref": jax.jit(lambda d, s: frontier_expand_batched_ref(
                 g.src, g.dst, d, s, levels)),
@@ -544,7 +552,6 @@ os.environ["XLA_FLAGS"] = (
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map, make_mesh_compat
 from repro.core import (build_csc_layout, erdos_renyi_graph, exchange_plan,
                         grid_graph, max_active_source_chunks,
                         partition_graph)
@@ -554,7 +561,8 @@ from repro.core.sampler import sample_batch
 B = int(args.get("batch", 8))
 n = int(args.get("n_samples", 16))
 reps = int(args.get("reps", 1))
-mesh = make_mesh_compat((n_dev,), ("data",))
+mesh = jax.make_mesh((n_dev,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 axes = ("data",)
 
 def timeit(fn, *a):
@@ -623,13 +631,13 @@ for family, scale in instances:
     rep_gspec = jax.tree.map(lambda _: P(), g)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(gspec, P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, P()),
              out_specs=(P(), P()), check_vma=False)
     def shard_samp(pgl, k):
         return sample_batch(pgl, k, n, batch_size=B, axis=axes)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(rep_gspec, P("data")),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(rep_gspec, P("data")),
              out_specs=(P("data"), P("data")), check_vma=False)
     def rep_samp(gl, ks):
         c, t = sample_batch(gl, ks[0], n, batch_size=B)
@@ -695,8 +703,7 @@ def run_partition_sweep(scales, n_dev: int = 8, batch: int = 8,
     are never silently compared against hardware runs.  Returns the
     rows; ``write_json`` appends to BENCH_sampling.json."""
     import json
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _fake_device_env()
     env["PARTITION_SWEEP_ARGS"] = json.dumps({
         "scales": list(scales), "grid_scales": list(grid_scales),
         "n_dev": n_dev, "batch": batch,
@@ -1119,8 +1126,7 @@ def run_fault_matrix(n_dev: int = 8, smoke: bool = False,
     tier-1 CI gate: 4 cells on a smaller instance, no BENCH row.
     """
     import json
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _fake_device_env()
     env["FAULT_MATRIX_ARGS"] = json.dumps({
         "n_dev": n_dev, "n_nodes": 120 if smoke else (400 if full else 200),
         "eps": 0.1 if smoke else 0.08, "smoke": smoke, "seed": seed})
@@ -1230,6 +1236,8 @@ def main():
         ap.error(f"conflicting sections: positional '{args.section}' "
                  f"vs --only '{args.only}'")
     args.only = args.only or args.section
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     jobs = {
         "table2": bench_table2, "fig2": bench_fig2, "fig3": bench_fig3,

@@ -9,6 +9,8 @@ its per-level dense vs sparse volume printed from a real BFS trace.
     PYTHONPATH=src python examples/betweenness_scaling.py
 """
 import os
+# eight fake host devices, on the CPU: never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import time
@@ -21,12 +23,15 @@ from repro.core import (AdaptiveConfig, brandes_numpy, exchange_plan,
                         grid_graph, max_active_source_chunks,
                         partition_graph, rmat_graph, run_kadabra)
 from repro.core.bfs import bfs_sssp_batched
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 graph = rmat_graph(10, 8, seed=1)   # R-MAT, Graph500 parameters
 print(f"R-MAT graph: |V|={graph.n_nodes} |E|={graph.n_edges_undirected}")
 
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 exact = brandes_numpy(graph)
 
 for agg in ["hierarchical", "flat", "root"]:

@@ -13,6 +13,9 @@ from repro.models.gnn.message_passing import GraphBatch
 from repro.models.gnn.models import NequipConfig, nequip_init, nequip_loss
 from repro.optim.adamw import AdamWConfig, init_state
 from repro.train.step import make_train_step
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 
 def make_molecules(step, n_mol=16, atoms=8, seed=0):

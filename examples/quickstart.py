@@ -8,6 +8,9 @@ import numpy as np
 
 from repro.core import (AdaptiveConfig, brandes_numpy, hyperbolic_graph,
                         run_adaptive, run_kadabra)
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # a power-law graph (the paper's synthetic family, laptop scale)
 graph = hyperbolic_graph(2000, avg_degree=12.0, seed=0)

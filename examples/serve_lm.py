@@ -11,6 +11,9 @@ import numpy as np
 
 from repro.models.transformer import (TransformerConfig, decode_step,
                                       init_params, prefill_step)
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 cfg = TransformerConfig(
     name="serve-demo", n_layers=6, d_model=256, n_heads=8, n_kv_heads=4,

@@ -22,6 +22,9 @@ from repro.models.transformer import TransformerConfig, init_params, lm_loss
 from repro.launch.mesh import make_single_device_mesh
 from repro.optim.adamw import AdamWConfig, init_state
 from repro.train.step import make_train_step
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=150)

@@ -59,17 +59,20 @@ import jax.numpy as jnp
 
 from .graph import Graph
 from .partition import PartitionedGraph, axis_tuple
+from repro.kernels import resolve_interpret
 from repro.kernels.frontier import (dag_sigma_batched_ref,
                                     dag_sigma_sharded_ref,
                                     edge_bitmap_from_source_bits,
                                     frontier_expand, frontier_relax,
-                                    frontier_source_block_bitmap)
+                                    frontier_source_block_bitmap,
+                                    select_route)
 
 __all__ = [
     "BFSResult", "bfs_sssp", "bfs_sssp_batched", "bfs_sssp_batched_sharded",
     "BidirResult", "bidirectional_bfs", "bidirectional_bfs_batched",
     "bidirectional_bfs_batched_sharded",
     "SSSPResult", "delta_sssp_batched", "delta_sssp_batched_sharded",
+    "frontier_route",
 ]
 
 _RESCALE_THRESHOLD = 1e30
@@ -106,6 +109,21 @@ def _state_rows(graph: Graph) -> int:
     layout is persisted — allocating at the kernel's padded row count up
     front is what makes every while_loop iteration pad/slice-free."""
     return graph.csc.v_pad if graph.csc is not None else graph.n_nodes + 1
+
+
+def frontier_route(graph, batch: int, *, weighted: bool = False) -> str:
+    """The frontier lane the BFS drivers take on ``graph`` (a
+    :class:`Graph` or a :class:`PartitionedGraph`) at sample width
+    ``batch`` on this backend — the automatic dispatch of
+    ``repro.kernels.frontier.frontier_expand`` (``frontier_relax`` when
+    ``weighted``), decided from the same static shapes: one of "flat",
+    "node_blocked", "ref", "sharded_nb", "sharded_ref"."""
+    interpret = resolve_interpret(None)
+    if isinstance(graph, PartitionedGraph):
+        return select_route(graph.n_nodes, batch, shard=graph.shards,
+                            interpret=interpret, weighted=weighted)
+    return select_route(_state_rows(graph) - 1, batch, csc=graph.csc,
+                        interpret=interpret, weighted=weighted)
 
 
 def _init_state(graph: Graph, sources):

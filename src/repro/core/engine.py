@@ -53,7 +53,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from . import distributed as dist
 from .diameter import (estimate_diameter, estimate_diameter_sharded,
                        estimate_diameter_weighted,
@@ -61,6 +60,7 @@ from .diameter import (estimate_diameter, estimate_diameter_sharded,
 from .epoch import epoch_length, frame_schema_id
 from .estimators import get_estimator
 from .estimators.base import DrawBatch, Estimator, MetricReport, RunContext
+from .bfs import frontier_route
 from .graph import Graph
 from .partition import PartitionedGraph, exchange_plan
 from .sampler import (sample_path_batched, sample_path_batched_sharded,
@@ -153,6 +153,10 @@ class AdaptiveRunResult(NamedTuple):
     vertex_diameter: int
     stats: list                 # list[EngineEpochStats]
     phase_seconds: dict         # diameter / calibration / sampling
+    # the sampling phase: concurrent samples per BFS round and the
+    # frontier-expansion lane its BFS levels ran on (bfs.frontier_route)
+    batch_size: int = 0
+    route: str = ""
 
 
 def _pad_len(v: int, n_dev: int) -> int:
@@ -406,7 +410,7 @@ def make_epoch_step_spmd(mesh, aggregation: str, n_nodes: int, v_pad: int,
         gspec = jax.tree.map(lambda _: rep, g)
         pspec = jax.tree.map(lambda _: rep, params)
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(gspec, pspec, rep, rep, frame_spec, rep,
                            frame_spec, rep, key_spec),
                  out_specs=(rep, rep, frame_spec, rep, frame_spec, rep,
@@ -495,7 +499,7 @@ def make_epoch_step_sharded(mesh, n_nodes: int, v_pad: int, n0: int,
         gspec = g.partition_spec(all_axes)
         pspec = jax.tree.map(lambda _: rep, params)
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(gspec, pspec, rep, rep, rep, rep, rep, rep, rep),
                  out_specs=(rep,) * (10 if with_exchange else 9),
                  check_vma=False)
@@ -602,7 +606,7 @@ def _sharded_diameter(pg: PartitionedGraph, mesh, n_sweeps: int):
     gspec = pg.partition_spec(all_axes)
     want_dist = pg.exchange_budget_auto
 
-    @partial(shard_map, mesh=mesh, in_specs=(gspec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec,),
              out_specs=(rep, P(all_axes)) if want_dist else rep,
              check_vma=False)
     def diam_step(g):
@@ -734,7 +738,7 @@ def _spmd_lane(graph: Graph, mesh: Mesh, cfg: AdaptiveConfig, estimators,
 
     def calibrate(k_cal, bsz, ctx):
         # pleasingly parallel sampling + blocking reduce (MPI_Reduce)
-        @partial(shard_map, mesh=mesh, in_specs=(gspec, key_spec),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, key_spec),
                  out_specs=(rep, rep), check_vma=False)
         def calib_step(g, keys):
             c, t = draw_fold(g, keys[0], cfg.calib_samples_per_device,
@@ -765,7 +769,7 @@ def _spmd_lane(graph: Graph, mesh: Mesh, cfg: AdaptiveConfig, estimators,
     def make_flush(ctx):
         # per-device frame + its surplus tail, then one reduction —
         # the PR 1-6 flush association, channel-stacked
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(frame_spec, rep, frame_spec, rep),
                  out_specs=(rep, rep), check_vma=False)
         def _flush(fr_c, fr_t, sur_c, sur_t):
@@ -817,7 +821,7 @@ def _sharded_lane(pg: PartitionedGraph, mesh: Mesh, cfg: AdaptiveConfig,
     ns.vd, pg = _sharded_diameter(pg, mesh, cfg.diameter_sweeps)
     ns.dist_cap = 0.0
     if stream == "weighted":
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(pg.partition_spec(all_axes),),
                  out_specs=(rep, rep), check_vma=False)
         def wdiam_step(g):
@@ -837,7 +841,7 @@ def _sharded_lane(pg: PartitionedGraph, mesh: Mesh, cfg: AdaptiveConfig,
         # mesh cooperatively draws what n_dev independent devices would
         n_cal = cfg.calib_samples_per_device * n_dev
 
-        @partial(shard_map, mesh=mesh, in_specs=(gspec, rep),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, rep),
                  out_specs=(rep, rep), check_vma=False)
         def calib_step(g, k):
             c, t = draw_fold(g, k, n_cal, batch_size=bsz,
@@ -988,6 +992,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     ctx = RunContext(int(lane.graph.n_nodes), lane.vd, lane.dist_cap)
     bsz = resolve_sample_batch_size(cfg.sample_batch_size, ctx.n_nodes,
                                     ctx.vertex_diameter)
+    route = frontier_route(lane.graph, bsz, weighted=stream == "weighted")
     # the static per-level price list for the sharded lane's exchange
     # tally (host-side observation only)
     xplan = (exchange_plan(lane.graph, bsz)
@@ -1130,12 +1135,13 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     tau_total = (int(last_flush[1]) if last_flush is not None
                  else int(ft_np.max(initial=0)))
     telemetry.emit("run.end", tau=tau_total, n_epochs=epoch,
-                   converged=bool(converged.all()))
+                   converged=bool(converged.all()), batch_size=bsz,
+                   route=route)
     return AdaptiveRunResult(
         tuple(reports), tau_total, epoch, bool(converged.all()),
         ctx.vertex_diameter, stats,
         {"diameter": lane.t_diam, "calibration": t_cal,
-         "sampling": t_samp})
+         "sampling": t_samp}, bsz, route)
 
 
 def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
@@ -1181,7 +1187,7 @@ def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
         rep = P()
         dcap = 0.0
         if stream == "weighted" and needs_vd:
-            @partial(shard_map, mesh=mesh,
+            @partial(jax.shard_map, mesh=mesh,
                      in_specs=(graph.partition_spec(all_axes),),
                      out_specs=(rep, rep), check_vma=False)
             def wdiam_step(g):
@@ -1193,7 +1199,7 @@ def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
         ctx = RunContext(int(graph.n_nodes), vd if needs_vd else 0, dcap)
         gspec = graph.partition_spec(all_axes)
 
-        @partial(shard_map, mesh=mesh, in_specs=(gspec, rep),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, rep),
                  out_specs=(rep, rep), check_vma=False)
         def fixed_step(g, k):
             return draw_fold(g, k, n_samples, estimators=estimators,
@@ -1228,7 +1234,7 @@ def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
             key_spec = P(all_axes)
             gspec = jax.tree.map(lambda _: rep, graph)
 
-            @partial(shard_map, mesh=mesh, in_specs=(gspec, key_spec),
+            @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, key_spec),
                      out_specs=(rep, rep), check_vma=False)
             def fixed_step(g, keys):
                 c, t = draw_fold(g, keys[0], per_dev,

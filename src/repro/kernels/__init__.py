@@ -6,3 +6,14 @@
 #               identified by DESIGN.md §Perf cell 1)
 # Each subpackage ships kernel.py (pl.pallas_call + BlockSpec), ops.py
 # (jit'd dispatching wrapper) and ref.py (pure-jnp oracle).
+
+import jax
+
+
+def resolve_interpret(interpret):
+    """A kernel entry point's ``interpret`` flag: ``None`` compiles the
+    Pallas kernel on a TPU backend and interprets it on any other, so a
+    caller on the chip never lands in interpret mode by accident."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

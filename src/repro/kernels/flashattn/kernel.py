@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
@@ -70,7 +72,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q, k, v: (BH, S, dh) -> (BH, S, dh).  S must divide the blocks."""
     bh, s, dh = q.shape
     block_q = min(block_q, s)
@@ -95,5 +97,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),       # running sum l
             pltpu.VMEM((block_q, dh), jnp.float32),    # accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -27,7 +27,7 @@ def _fold_gqa(q, k, v):
 
 @partial(jax.jit, static_argnames=("causal", "use_pallas", "interpret"))
 def flash_attention(q, k, v, *, causal=True, use_pallas=True,
-                    interpret=True):
+                    interpret=None):
     """q (B,S,H,dh), k/v (B,S,KV,dh) -> (B,S,H,dh)."""
     b, s, h, dh = q.shape
     qf, kf, vf = _fold_gqa(q, k, v)

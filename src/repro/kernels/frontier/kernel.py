@@ -22,9 +22,12 @@ Two kernels share that skeleton:
 
 ``frontier_expand_batched_pallas`` — the *flat* (single-level) kernel.
 Grid ``(E_pad / block_e,)``; the whole (V+1, B) dist/sigma/contrib state
-is VMEM-resident across all steps and the one-hot operand is
-(V+1, block_e).  Fast while V * B fits in VMEM (~1.3M cells in 16 MiB at
-12 B per cell, i.e. ~20K vertices at B=64), impossible beyond.
+is VMEM-resident across all steps, and both the per-edge gather and the
+scatter are one-hot matmuls against (V+1, block_e) operands.
+:func:`flat_fits` is its fit model: VMEM pads B to 128 lanes, so each
+state array costs (V+1) * 128 * 4 bytes for any B <= 128, and the
+one-hot operand grows with V * block_e.  :func:`flat_block_e` picks the
+largest edge block that fits; at most ~4K vertices fit at all.
 
 ``frontier_expand_node_blocked_pallas`` — the *two-level* (node-blocked
 CSC) kernel that lifts the cap.  Edges are pre-bucketed by
@@ -34,8 +37,8 @@ walks the flattened (node block, edge block) cells.  Per step only the
 (zeroed on each bucket's first edge block via the scalar-prefetched
 ``block_first`` flags; the output index map follows ``block_nb``), the
 one-hot operand shrinks from (V+1, block_e) to (block_v, block_e), and
-dist/sigma stay in ``pltpu.ANY`` memory — gathered per edge block rather
-than pinned whole.  VMEM residency is O(block_v * B + block_v * block_e)
+dist/sigma stay in HBM: each edge block reads one (block_v, B) source
+tile of them rather than the whole state.  VMEM residency is O(block_v * B + block_v * block_e)
 independent of V, so the kernel reaches million-vertex graphs; it also
 does V/block_v fewer one-hot MACs than the flat kernel (each edge is
 compared against one tile of rows, not all of them).  Revisits of an
@@ -54,7 +57,7 @@ block ``k`` holds at least one edge whose source ``u`` satisfies
 (:func:`frontier_block_bitmap` computes exactly this: a blockwise
 segment-max of the per-sample frontier mask gathered over the CSC
 source ids).  It rides in as a third scalar-prefetch operand; inactive
-cells skip the whole DMA + gather + matmul body under ``pl.when`` and
+cells skip the whole copy + gather + matmul body under ``pl.when`` and
 only perform the (mandatory) tile zeroing on each bucket's first edge
 block.  A conservative all-ones bitmap is always legal — skipping is
 semantics-preserving, the kernel output is bit-identical with any
@@ -68,51 +71,51 @@ overhead overstates the penalty a compiled kernel would see).  Callers
 that know their frontiers are dense can pass ``skip_inactive=False``
 through the dispatcher.
 
-**Double-buffered edge-block pipeline.**  The ``src``/``dst`` edge
-blocks live in ``pltpu.ANY`` (HBM) and are staged into VMEM scratch by
-explicit ``pltpu.make_async_copy`` DMA with two slots: at grid step
-``k`` the copy for block ``k + 1`` is started *before* the one-hot MXU
-matmuls of block ``k`` run, so the next block's edge stream is in
-flight behind the current block's compute (slot parity ``k % 2``;
-inactive blocks start no copy and wait on none).  This replaces the
-BlockSpec auto-pipeline so the copy schedule can follow the occupancy
-bitmap — an auto-pipelined operand would prefetch skipped blocks too.
+**Pipelined edge blocks and source tiles.**  The ``(1, block_e)``
+src/dst edge blocks and the (block_v, B) dist/sigma source tiles are
+plain BlockSpec operands, which the Pallas pipeline double-buffers:
+step ``k + 1``'s copies run behind step ``k``'s matmuls.  Their index
+maps follow a fifth scalar-prefetch operand, the fetch map
+(:func:`_fetch_map`: the last active edge block at or before each
+step), so an inactive step names the blocks already resident and the
+pipeline issues no copy for it.  (Copies staged by hand into a
+``(2, block_e)`` int32 scratch do not compile: Mosaic refuses the
+one-row slot slice, which is not aligned to the buffer's (2, 128)
+tiling.)
 
-**Staged dist/sigma gather (the Mosaic-compilable formulation).**
-Every edge block of the layout is *source-block-pure*
-(:func:`repro.core.graph.bucket_layout` additionally sorts each
+**Block-local dist/sigma gather.**  Every edge block of the layout is
+*source-block-pure* (:func:`repro.core.graph.bucket_layout` sorts each
 destination bucket by source block and records the block in
 ``block_sb``), so the per-edge gather needs rows from exactly ONE
-(block_v, B) dist tile and one sigma tile.  Those tiles ride the same
-double-buffered DMA pipeline as the edge blocks (semaphore lanes 2-3 of
-the shared (2, 4) array): ``issue`` for block ``k`` starts the edge
-copies and — only when the slot does not already hold source block
-``block_sb[k]`` (an SMEM (slot, [held, pending]) tracker; consecutive
-blocks of the same pair reuse the resident tiles without re-DMA) — the
-two state-tile copies.  The gather itself is then block-local: the
-frontier-value tile ``where(dist_tile == levels, sigma_tile, 0)`` is
-computed once per staged tile, and the per-edge read becomes a second
-one-hot matmul ``onehot(src_local) @ fval`` (a (block_e x block_v) x
-(block_v x B) MXU product) — no ``pltpu.ANY`` ref is ever indexed
-directly in the kernel body (``tools/check_kernels.py`` enforces this),
-which is exactly the restriction Mosaic imposes.  Sink-padded edges
-carry ``src = n_nodes``: when the sink row lies outside the staged tile
-the one-hot row is all zero, and when it lies inside it the tile's sink
-dist (-3) never matches a level — inert either way.  Both one-hot
-matmuls accumulate exact small-integer float32 values, so the staged
-path is bit-for-bit identical to a direct gather even though the pair
-sort reorders edges within a bucket (the additions commute exactly).
+(block_v, B) dist tile and one sigma tile; consecutive edge blocks of
+one (dst, src)-block pair name the same tiles, which the pipeline does
+not fetch again.  The frontier-value tile
+``where(dist_tile == levels, sigma_tile, 0)`` is computed once per
+step, and the per-edge read is a second one-hot matmul
+``onehot(src_local)^T @ fval``.  No kernel indexes a ref with a vector
+of row ids (Mosaic has no such gather; ``tools/check_kernels.py`` also
+rejects direct reads of ``pltpu.ANY`` refs).  Sink-padded edges carry
+``src = n_nodes``: when the sink row lies outside the tile the one-hot
+column is all zero, and when it lies inside, the tile's sink dist (-3)
+never matches a level — inert either way.
 
-On real TPUs pick B as a multiple of the f32 lane tiling (8; ideally
-128 to fill the MXU); both kernels are now written to the compiled
-Mosaic contract (explicit DMA staging of everything read from ANY
-memory).  The staged gather trades slot padding for compilability:
-every (dst block, src block) pair is padded to a ``block_e`` multiple,
-which stays ~2-3x on locality-friendly instances (grids, roads — the
-source span of a destination block is O(1) blocks) but grows with the
-number of populated pairs on scattered graphs (see DESIGN.md §Perf
-"Staged gather" for the accounting and ``choose_csc_blocks`` for the
-VMEM budget the four staged tile slots join).
+**Precision.**  Every one-hot matmul runs at ``Precision.HIGHEST``
+(:func:`_onehot_matmul`).  The MXU's default pass rounds float32
+operands to bfloat16, and sigma outgrows bfloat16's 8-bit significand
+within a few BFS levels; at HIGHEST a gathered value keeps all its
+float32 bits and the scatter accumulates in float32, so the kernels
+match the XLA reference bit for bit (checked on a v5e chip on a
+512 x 512 grid by ``chip_smoke.py``).  The staged path reorders edges
+within a bucket; the additions of exact small-integer sigma commute
+exactly.
+
+Both kernels compile for TPU v5e (``tests/test_tpu_compile.py``
+compiles them for a described chip) and run there through
+``chip_smoke.py``.  The pair sort trades slot padding for block-local
+reads: every (dst block, src block) pair is padded to a ``block_e``
+multiple, which stays ~2-3x on locality-friendly instances (grids,
+roads) but grows with the number of populated pairs on scattered graphs
+(DESIGN.md §Perf "Staged gather").
 
 All shapes static; padded edges target the sink row V (dist = -3) and
 contribute exactly 0.
@@ -126,12 +129,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_E = 2048
-# node-blocked tile defaults: the (block_v, block_e) one-hot operand is
-# the VMEM-dominant term, so the two-level blocks are sized below the
-# flat kernel's edge block (512 * 1024 + streams ~ 0.7M cells at B=64)
-DEFAULT_BLOCK_V = 512
-DEFAULT_CSC_BLOCK_E = 1024
+from repro.kernels import resolve_interpret
+
+# The flat kernel's fit model.  Per-kernel VMEM budget: v5e's 16 MiB
+# of default scoped VMEM less headroom.
+_VMEM_BYTES = 14 * 2**20
+# Cap on the cells of a (rows, block_e) one-hot operand.  Mosaic
+# unrolls the one-hot matmuls, so compile time grows with this product:
+# about 2.5 s at 2^19 cells for v5e.
+_ONEHOT_CELLS = 1 << 19
+
+
+def _tile_bytes(rows: int, batch: int) -> int:
+    """VMEM bytes of a (rows, B) 32-bit tile: B pads to 128 lanes."""
+    return rows * 128 * -(-max(batch, 1) // 128) * 4
+
+
+def flat_fits(v1: int, block_e: int, batch: int) -> bool:
+    """The flat kernel at ``block_e`` fits: dist, sigma and the contrib
+    accumulator, each double-buffered, one float32 one-hot operand and
+    the edge stream within :data:`_VMEM_BYTES`, and the one-hot within
+    :data:`_ONEHOT_CELLS`.  The model is conservative: the v5e compiler
+    reported 2.8 MiB of scoped VMEM at V+1 = 2001, B = 64,
+    block_e = 256, where it counts 7.8 MiB."""
+    state = 6 * _tile_bytes(v1, batch)
+    return (v1 * block_e <= _ONEHOT_CELLS
+            and state + 4 * v1 * block_e + 16 * block_e <= _VMEM_BYTES)
+
+
+def flat_block_e(n_nodes: int, batch: int) -> int:
+    """Largest edge block (128..2048) the flat kernel fits with at
+    ``n_nodes`` vertices and sample width ``batch``; 0 when none does."""
+    for block_e in (2048, 1024, 512, 256, 128):
+        if flat_fits(n_nodes + 1, block_e, batch):
+            return block_e
+    return 0
+
+
+def _onehot_matmul(onehot, x, dims):
+    """``dot_general(onehot, x)`` of a 0/1 matrix and float32 values at
+    full float32 contract precision.
+
+    Sigma values outgrow bfloat16's 8-bit significand after a few BFS
+    levels, so the MXU's default single bfloat16 pass would round them;
+    ``Precision.HIGHEST`` keeps every float32 bit of a gathered value
+    and accumulates the scatter in float32.
+    """
+    return jax.lax.dot_general(onehot.astype(jnp.float32), x, dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+_NN = (((1,), (0,)), ((), ()))    # (m, k) @ (k, n)
+_TN = (((0,), (0,)), ((), ()))    # (k, m)^T @ (k, n)
 
 
 def _flat_kernel(src_ref, dst_ref, dist_ref, sigma_ref, level_ref, out_ref,
@@ -142,19 +192,18 @@ def _flat_kernel(src_ref, dst_ref, dist_ref, sigma_ref, level_ref, out_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    src = src_ref[...]           # (block_e,)
-    dst = dst_ref[...]           # (block_e,)
-    levels = level_ref[...]      # (B,) per-sample frontier depth
-    # frontier gather (VMEM-resident (V1, B) state): one edge-index read
-    # serves every sample column
-    vals = jnp.where(dist_ref[src, :] == levels[None, :],
-                     sigma_ref[src, :], 0.0)              # (block_e, B)
+    src = src_ref[...]           # (1, block_e)
+    dst = dst_ref[...]           # (1, block_e)
+    levels = level_ref[...]      # (1, B) per-sample frontier depth
+    # frontier values of the VMEM-resident (V1, B) state: rows on a
+    # sample's frontier carry their sigma
+    fval = jnp.where(dist_ref[...] == levels, sigma_ref[...], 0.0)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (v1, block_e), 0)
+    # gather as a one-hot matmul: vals[e, b] = fval[src[e], b]
+    vals = _onehot_matmul(src == rows, fval, _TN)           # (block_e, B)
     # scatter-add as a one-hot matmul on the MXU:
     #   contrib[v, b] += sum_e [dst[e] == v] * vals[e, b]
-    onehot = (dst[None, :] == jax.lax.broadcasted_iota(
-        jnp.int32, (v1, block_e), 0)).astype(jnp.float32)
-    out_ref[...] += jnp.dot(onehot, vals,
-                            preferred_element_type=jnp.float32)
+    out_ref[...] += _onehot_matmul(dst == rows, vals, _NN)
 
 
 def _pad_edges(src, dst, block_e, sink):
@@ -170,8 +219,8 @@ def _pad_edges(src, dst, block_e, sink):
 
 
 def frontier_expand_batched_pallas(src, dst, dist, sigma, levels, *,
-                                   block_e: int = DEFAULT_BLOCK_E,
-                                   interpret: bool = True):
+                                   block_e: int | None = None,
+                                   interpret: bool | None = None):
     """B batched BFS frontier expansions sharing one edge stream.
 
     ``dist``/``sigma`` are vertex-major (V+1, B) with per-sample frontier
@@ -180,33 +229,40 @@ def frontier_expand_batched_pallas(src, dst, dist, sigma, levels, *,
     conversions happen here, the caller's vertex-major state is used
     as-is.
 
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on a real TPU pass ``interpret=False``.
+    ``block_e=None`` takes the largest edge block that fits
+    (:func:`flat_block_e`).  ``interpret=None`` compiles the kernel on a
+    TPU backend and interprets it elsewhere.
     """
     v1, batch = dist.shape
+    if block_e is None:
+        block_e = flat_block_e(v1 - 1, batch)
+        if not block_e:
+            raise ValueError(
+                f"the flat kernel cannot fit V+1 = {v1} rows at B={batch} "
+                "in VMEM; use the node-blocked kernel")
     src, dst = _pad_edges(src, dst, block_e, v1 - 1)
     grid = (src.shape[0] // block_e,)
-    levels = jnp.asarray(levels, jnp.int32).reshape(batch)
+    levels = jnp.asarray(levels, jnp.int32).reshape(1, batch)
 
     return pl.pallas_call(
         functools.partial(_flat_kernel, block_e=block_e, v1=v1),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_e,), lambda i: (i,)),     # src: stream blocks
-            pl.BlockSpec((block_e,), lambda i: (i,)),     # dst: stream blocks
-            pl.BlockSpec((v1, batch), lambda i: (0, 0)),  # dist: VMEM-pinned
-            pl.BlockSpec((v1, batch), lambda i: (0, 0)),  # sigma: VMEM-pinned
-            pl.BlockSpec((batch,), lambda i: (0,)),       # per-sample levels
+            pl.BlockSpec((1, block_e), lambda i: (0, i)),  # src: stream
+            pl.BlockSpec((1, block_e), lambda i: (0, i)),  # dst: stream
+            pl.BlockSpec((v1, batch), lambda i: (0, 0)),   # dist: pinned
+            pl.BlockSpec((v1, batch), lambda i: (0, 0)),   # sigma: pinned
+            pl.BlockSpec((1, batch), lambda i: (0, 0)),    # levels
         ],
         out_specs=pl.BlockSpec((v1, batch), lambda i: (0, 0)),  # accumulate
         out_shape=jax.ShapeDtypeStruct((v1, batch), jnp.float32),
-        interpret=interpret,
-    )(src, dst, dist, sigma, levels)
+        interpret=resolve_interpret(interpret),
+    )(src.reshape(1, -1), dst.reshape(1, -1), dist, sigma, levels)
 
 
 def frontier_expand_pallas(src, dst, dist, sigma, level, *,
-                           block_e: int = DEFAULT_BLOCK_E,
-                           interpret: bool = True):
+                           block_e: int | None = None,
+                           interpret: bool | None = None):
     """One BFS frontier expansion (B=1 lane of the batched kernel); same
     contract as ``ref.frontier_expand_ref``."""
     out = frontier_expand_batched_pallas(
@@ -295,71 +351,11 @@ def edge_bitmap_from_source_bits(csc, src_bits, chunk_rows: int):
     return jnp.max(hit.reshape(csc.n_edge_blocks, csc.block_e), axis=1)
 
 
-def _nb_kernel(nb_ref, sb_ref, first_ref, act_ref, level_ref, src_any,
-               dst_any, dist_any, sigma_any, out_ref, src_s, dst_s,
-               dist_s, sigma_s, tile_state, sem, *,
+def _nb_kernel(nb_ref, sb_ref, first_ref, act_ref, fetch_ref, level_ref,
+               src_ref, dst_ref, dist_ref, sigma_ref, out_ref, *,
                block_v: int, block_e: int):
+    del fetch_ref                # read by the index maps only
     k = pl.program_id(0)         # flattened (node block, edge block) cell
-    nsteps = pl.num_programs(0)
-    slot = jax.lax.rem(k, 2)
-
-    def edge_dma(block_idx, s):
-        # HBM -> VMEM stage of one (block_e,) src/dst edge block
-        return (pltpu.make_async_copy(
-                    src_any.at[pl.ds(block_idx * block_e, block_e)],
-                    src_s.at[s], sem.at[s, 0]),
-                pltpu.make_async_copy(
-                    dst_any.at[pl.ds(block_idx * block_e, block_e)],
-                    dst_s.at[s], sem.at[s, 1]))
-
-    def tile_dma(sb, s):
-        # HBM -> VMEM stage of one (block_v, B) dist/sigma source tile
-        return (pltpu.make_async_copy(
-                    dist_any.at[pl.ds(sb * block_v, block_v)],
-                    dist_s.at[s], sem.at[s, 2]),
-                pltpu.make_async_copy(
-                    sigma_any.at[pl.ds(sb * block_v, block_v)],
-                    sigma_s.at[s], sem.at[s, 3]))
-
-    def issue(block_idx, s):
-        # start block_idx's copies into slot s: edges always; the state
-        # tiles only when the slot does not already hold this source
-        # block (consecutive blocks of a (dst, src)-block pair reuse the
-        # resident tiles — the payoff of the source-block sort).  The
-        # SMEM tracker rows are (held source block, wait pending).
-        for dma in edge_dma(block_idx, s):
-            dma.start()
-        sb = sb_ref[block_idx]
-
-        @pl.when(tile_state[s, 0] != sb)
-        def _stage_tiles():
-            for dma in tile_dma(sb, s):
-                dma.start()
-            tile_state[s, 0] = sb
-            tile_state[s, 1] = 1
-
-    @pl.when(k == 0)
-    def _reset():                # scratch persists across pallas_calls
-        tile_state[0, 0] = -1
-        tile_state[0, 1] = 0
-        tile_state[1, 0] = -1
-        tile_state[1, 1] = 0
-
-    # -- double-buffered pipeline: block k+1's copies are started before
-    # block k's compute; slots alternate on block-index parity.  Copies
-    # are only issued for ACTIVE blocks (an auto-pipelined BlockSpec
-    # operand would prefetch skipped blocks too), and only waited on by
-    # the matching active compute step below — every issued copy is
-    # waited, because issue and wait share the act[j] == 1 condition.
-    @pl.when((k == 0) & (act_ref[0] == 1))
-    def _warmup():               # block 0 has no predecessor step
-        issue(0, 0)
-
-    nxt = jnp.minimum(k + 1, nsteps - 1)     # clamp: trace-safe at the end
-
-    @pl.when((k + 1 < nsteps) & (act_ref[nxt] == 1))
-    def _prefetch_next():
-        issue(nxt, jax.lax.rem(k + 1, 2))
 
     @pl.when(first_ref[k] == 1)
     def _init():                 # first edge block of this bucket: the
@@ -367,43 +363,37 @@ def _nb_kernel(nb_ref, sb_ref, first_ref, act_ref, level_ref, src_any,
 
     @pl.when(act_ref[k] == 1)
     def _expand():               # skipped entirely on inactive cells
-        for dma in edge_dma(k, slot):
-            dma.wait()
-
-        @pl.when(tile_state[slot, 1] == 1)
-        def _wait_tiles():       # tiles staged for this block (or a
-            for dma in tile_dma(sb_ref[k], slot):   # reused resident
-                dma.wait()                          # pair needs no wait)
-            tile_state[slot, 1] = 0
-
-        src = src_s[slot]        # (block_e,) — all inside source block
-        dst = dst_s[slot]        # (block_e,) — all inside this node block
-        levels = level_ref[...]  # (B,)
+        src = src_ref[...]       # (1, block_e) — all inside source block
+        dst = dst_ref[...]       # (1, block_e) — all inside this node block
         # block-local frontier values of the staged source tile: rows
         # whose dist matches a sample's level carry their sigma
-        fval = jnp.where(dist_s[slot] == levels[None, :],
-                         sigma_s[slot], 0.0)          # (block_v, B)
+        fval = jnp.where(dist_ref[...] == level_ref[...],
+                         sigma_ref[...], 0.0)         # (block_v, B)
         # gather = one-hot matmul against the staged tile.  Sink-padded
         # edges (src = n_nodes) either fall outside [0, block_v) — an
         # all-zero one-hot row — or hit the sink row whose dist (-3)
         # never matches a level: inert either way.
-        src_local = src - sb_ref[k] * block_v
-        onehot_src = (src_local[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (block_e, block_v), 1)).astype(jnp.float32)
-        vals = jnp.dot(onehot_src, fval,
-                       preferred_element_type=jnp.float32)  # (block_e, B)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_v, block_e), 0)
+        vals = _onehot_matmul((src - sb_ref[k] * block_v) == rows, fval,
+                              _TN)                    # (block_e, B)
         # local scatter rows inside the current (block_v, B) contrib
         # tile; sink-padded edges fall outside [0, block_v) (all-zero
         # one-hot column) or hit the sink row with a 0 value — inert
-        dst_local = dst - nb_ref[k] * block_v
-        onehot = (dst_local[None, :] == jax.lax.broadcasted_iota(
-            jnp.int32, (block_v, block_e), 0)).astype(jnp.float32)
-        out_ref[...] += jnp.dot(onehot, vals,
-                                preferred_element_type=jnp.float32)
+        out_ref[...] += _onehot_matmul((dst - nb_ref[k] * block_v) == rows,
+                                       vals, _NN)
+
+
+def _fetch_map(block_active):
+    """(n_edge_blocks,) int32: the last active edge block at or before
+    each grid step (0 before the first).  The input index maps follow
+    it, so an inactive step names the block already resident and the
+    pipeline issues no copy for it."""
+    idx = jnp.arange(block_active.shape[0], dtype=jnp.int32)
+    return jax.lax.cummax(jnp.where(block_active == 1, idx, 0))
 
 
 def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
-                                        interpret: bool = True,
+                                        interpret: bool | None = None,
                                         block_active=None,
                                         skip_inactive: bool = True,
                                         wide_state: bool = False):
@@ -423,8 +413,8 @@ def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
     as scalar-prefetch operands (``PrefetchScalarGridSpec``): the
     output index map follows ``block_nb`` to the current node block's
     tile, ``block_sb`` names the (block_v, B) dist/sigma source tile
-    the kernel DMA-stages for each edge block (module docstring,
-    "Staged dist/sigma gather"), the tile is zeroed on each bucket's
+    each edge block reads (module docstring, "Block-local dist/sigma
+    gather"), the tile is zeroed on each bucket's
     first edge block, and cells whose edge block holds no frontier
     source are skipped (see the module docstring for the bitmap
     contract).  ``block_active=None`` with ``skip_inactive=True``
@@ -435,15 +425,15 @@ def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
     v_rows, batch = dist.shape
     levels = jnp.asarray(levels, jnp.int32).reshape(batch)
     v_pad = csc.v_pad
-    # the staged gather DMAs source tiles [sb*block_v, (sb+1)*block_v)
-    # for every sb < n_src_blocks — the state must cover them all
+    # the gather reads source tiles [sb*block_v, (sb+1)*block_v) for
+    # every sb < n_src_blocks — the state must cover them all
     src_rows = csc.n_src_blocks * csc.block_v
     if wide_state:
         # Sharded lane: ``csc`` is one shard's LOCAL layout view
         # (ShardedCSCLayout.local(): global src ids, local dst rows)
         # while dist/sigma cover the all-gathered GLOBAL row space —
-        # strictly more rows than the local tiles.  The staged gather
-        # tiles the wide state (ANY memory) by GLOBAL source block, the
+        # strictly more rows than the local tiles.  The gather tiles
+        # the wide state by GLOBAL source block, the
         # output is the local (csc.v_pad, B) tile stack; no pad/slice
         # of the state.
         if v_rows < max(v_pad, src_rows):
@@ -471,37 +461,34 @@ def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
         block_active = jnp.asarray(block_active, jnp.int32).reshape(
             csc.n_edge_blocks)
 
+    block_v, block_e = csc.block_v, csc.block_e
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # block_nb, block_sb, block_first, block_active
-        num_scalar_prefetch=4,
+        # block_nb, block_sb, block_first, block_active, fetch
+        num_scalar_prefetch=5,
         grid=(csc.n_edge_blocks,),
         in_specs=[
-            pl.BlockSpec((batch,),
-                         lambda k, nb, sb, first, act: (0,)),  # levels
-            pl.BlockSpec(memory_space=pltpu.ANY),   # src: manual DMA stage
-            pl.BlockSpec(memory_space=pltpu.ANY),   # dst: manual DMA stage
-            pl.BlockSpec(memory_space=pltpu.ANY),   # dist: DMA-staged tiles
-            pl.BlockSpec(memory_space=pltpu.ANY),   # sigma: DMA-staged tiles
+            pl.BlockSpec((1, batch),
+                         lambda k, nb, sb, first, act, f: (0, 0)),  # levels
+            pl.BlockSpec((1, block_e),                           # src
+                         lambda k, nb, sb, first, act, f: (0, f[k])),
+            pl.BlockSpec((1, block_e),                           # dst
+                         lambda k, nb, sb, first, act, f: (0, f[k])),
+            pl.BlockSpec((block_v, batch),                       # dist tile
+                         lambda k, nb, sb, first, act, f: (sb[f[k]], 0)),
+            pl.BlockSpec((block_v, batch),                       # sigma tile
+                         lambda k, nb, sb, first, act, f: (sb[f[k]], 0)),
         ],
-        out_specs=pl.BlockSpec((csc.block_v, batch),
-                               lambda k, nb, sb, first, act: (nb[k], 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, csc.block_e), jnp.int32),   # src double buffer
-            pltpu.VMEM((2, csc.block_e), jnp.int32),   # dst double buffer
-            pltpu.VMEM((2, csc.block_v, batch), jnp.int32),    # dist tiles
-            pltpu.VMEM((2, csc.block_v, batch), jnp.float32),  # sigma tiles
-            pltpu.SMEM((2, 2), jnp.int32),  # (slot, [held sb, pending])
-            pltpu.SemaphoreType.DMA((2, 4)),  # (slot, src|dst|dist|sigma)
-        ],
+        out_specs=pl.BlockSpec((block_v, batch),
+                               lambda k, nb, sb, first, act, f: (nb[k], 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_nb_kernel, block_v=csc.block_v,
-                          block_e=csc.block_e),
+        functools.partial(_nb_kernel, block_v=block_v, block_e=block_e),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((v_pad, batch), jnp.float32),
-        interpret=interpret,
-    )(csc.block_nb, csc.block_sb, csc.block_first, block_active, levels,
-      csc.src, csc.dst, dist, sigma)
+        interpret=resolve_interpret(interpret),
+    )(csc.block_nb, csc.block_sb, csc.block_first, block_active,
+      _fetch_map(block_active), levels.reshape(1, batch),
+      csc.src.reshape(1, -1), csc.dst.reshape(1, -1), dist, sigma)
     if wide_state:
         return out                     # local (csc.v_pad, B) tile stack
     return out if v_rows == v_pad else out[:v_rows]

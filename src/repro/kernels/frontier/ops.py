@@ -8,8 +8,8 @@ design).  With the default ``use_pallas=None`` the dispatch is automatic
 and actually consults the fit predicates:
 
   * flat Pallas kernel      — when :func:`pallas_supported` says the
-    whole vertex-major (V+1, B) dist/sigma/contrib state fits the VMEM
-    cell budget;
+    whole vertex-major (V+1, B) dist/sigma/contrib state and its one-hot
+    operands fit VMEM at some edge block (``kernel.flat_fits``);
   * node-blocked kernel     — above that budget, when a
     :class:`repro.core.graph.CSCLayout` is supplied (``csc=...``) and
     :func:`node_blocked_supported` accepts its per-step tiles;
@@ -65,7 +65,7 @@ the SHARDED expansion.  The operand contract of that route, precisely:
     never serve this route.
 
 Its fit predicate is :func:`sharded_supported` (the shard's local
-blocking only: the gathered state lives in ANY memory, so the GLOBAL
+blocking only: the gathered state stays in HBM, so the GLOBAL
 vertex count never enters the VMEM budget); on compiled TPU backends
 the lane reuses the node-blocked kernel in ``wide_state`` mode,
 elsewhere the ``frontier_expand_sharded_ref`` segment sum.
@@ -84,7 +84,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .kernel import (DEFAULT_BLOCK_E, frontier_block_bitmap,
+from repro.kernels import resolve_interpret
+
+from .kernel import (flat_block_e, flat_fits, frontier_block_bitmap,
                      frontier_expand_batched_pallas,
                      frontier_expand_node_blocked_pallas,
                      frontier_expand_pallas)
@@ -93,34 +95,32 @@ from .ref import (frontier_expand_batched_ref,
                   frontier_expand_sharded_ref, frontier_relax_batched_ref,
                   frontier_relax_sharded_ref)
 
-# dist(4B) + sigma(4B) + contrib(4B) per (vertex, sample) cell, 16 MiB
-# VMEM, ~25% headroom
+# Node-blocked blocking budget, in 4-byte cells of per-step residency
+# (:func:`_nb_cells`); well inside the 16 MiB of scoped VMEM.
 _VMEM_CELL_BUDGET = 1_000_000
 
 
-def pallas_supported(n_nodes: int, e_pad: int,
-                     block_e: int = DEFAULT_BLOCK_E, batch: int = 1) -> bool:
-    """True when the *flat* kernel's all-resident state fits VMEM.
-
-    Purely a cell-budget check on the (V+1, B) dist/sigma/contrib state;
-    ``e_pad``/``block_e`` do not constrain it (the kernel pads the edge
-    stream to ``block_e`` internally with inert sink edges — requiring
-    pre-aligned inputs here used to spuriously reject ~15/16 of real
-    graphs, whose arrays are padded to 128, not 2048).
-    """
-    del e_pad, block_e  # kept for API stability; alignment is internal
-    return (n_nodes + 1) * max(batch, 1) <= _VMEM_CELL_BUDGET
+def pallas_supported(n_nodes: int, batch: int = 1, block_e=None) -> bool:
+    """True when the *flat* kernel fits VMEM: some edge block (or the
+    given ``block_e``) keeps its VMEM-resident state and one-hot
+    operands within budget (:func:`repro.kernels.frontier.kernel.flat_block_e`).
+    Edge alignment is no constraint: the kernel pads the edge stream
+    to ``block_e`` with inert sink edges."""
+    if block_e is None:
+        return flat_block_e(n_nodes, batch) > 0
+    return flat_fits(n_nodes + 1, block_e, batch)
 
 
 def node_blocked_supported(csc, batch: int = 1) -> bool:
     """True when the node-blocked kernel's per-step tiles fit VMEM.
 
     Resident per grid step: the (block_v, B) contrib tile, the
-    frontier-value tile and the four double-buffered staged dist/sigma
-    source tiles (6 * block_v * B total), the two (block_v, block_e)
-    one-hot operands, the (block_e, B) gathered values, and the
-    double-buffered (2, block_e) src/dst edge-block stage — independent
-    of V.
+    frontier-value tile and the double-buffered dist/sigma source tiles
+    (6 * block_v * B total), the two (block_v, block_e) one-hot
+    operands, the (block_e, B) gathered values, and the double-buffered
+    src/dst edge blocks — independent of V.  The cell count is a budget
+    heuristic; ``tests/test_tpu_compile.py`` checks that the default
+    blockings compile for a v5e chip.
     """
     b = max(batch, 1)
     cells = _nb_cells(csc.block_v, csc.block_e, b)
@@ -141,8 +141,8 @@ def sharded_supported(shard, batch: int = 1) -> bool:
     :class:`repro.core.partition.ShardedCSCLayout` — only the static
     blocking is read).  Per grid step the sharded kernel touches the
     same tiles as the node-blocked kernel over the shard's LOCAL
-    (block_v, block_e) blocking; the all-gathered frontier state lives
-    in ANY memory and never counts against the VMEM cell budget, so a
+    (block_v, block_e) blocking; the all-gathered frontier state stays
+    in HBM (only its source tiles enter VMEM), so a
     shard fits iff its blocking does — independent of the global V.
     Because :func:`partition_graph` blocks shards with the same
     :func:`choose_csc_blocks` heuristic the replicated layout uses,
@@ -159,8 +159,8 @@ def choose_csc_blocks(n_nodes: int, batch: int = 16, *,
     """Pick ``(block_v, block_e)`` for a :class:`CSCLayout` from the
     VMEM cell budget, 128-aligned on both axes (f32 MXU tiling).
 
-    ``block_e`` is taken as large as possible — longer contiguous DMA
-    bursts amortize the double-buffered edge stream — subject to
+    ``block_e`` is taken as large as possible — longer contiguous
+    copies amortize the double-buffered edge stream — subject to
     leaving room for a contrib/one-hot tile of at least 256 vertex
     rows; ``block_v`` is then the largest 128-multiple whose per-step
     residency (:func:`node_blocked_supported`'s accounting) fits,
@@ -189,9 +189,8 @@ def choose_csc_blocks(n_nodes: int, batch: int = 16, *,
     return best
 
 
-def select_route(n_nodes: int, e_pad: int, batch: int, *, csc=None,
-                 shard=None, use_pallas=None, interpret: bool = True,
-                 block_e: int = DEFAULT_BLOCK_E,
+def select_route(n_nodes: int, batch: int, *, csc=None, shard=None,
+                 use_pallas=None, interpret: bool = True, block_e=None,
                  weighted: bool = False) -> str:
     """The dispatch decision of :func:`frontier_expand`, as a pure
     function of static shapes/flags: one of "flat", "node_blocked",
@@ -244,7 +243,7 @@ def select_route(n_nodes: int, e_pad: int, batch: int, *, csc=None,
             "the flat kernel cannot serve the sharded lane (local output "
             "rows != gathered input rows); use use_pallas=None, False, or "
             "'node_blocked'")
-    flat_ok = pallas_supported(n_nodes, e_pad, block_e, batch)
+    flat_ok = pallas_supported(n_nodes, batch, block_e)
     nb_ok = csc is not None and node_blocked_supported(csc, batch)
     if use_pallas is None:                       # automatic dispatch
         if interpret:
@@ -268,9 +267,8 @@ def select_route(n_nodes: int, e_pad: int, batch: int, *, csc=None,
     # use_pallas=True: the flat kernel
     if not flat_ok:
         raise ValueError(
-            f"flat Pallas kernel forced but (V+1)*B = "
-            f"{(n_nodes + 1) * batch} cells exceed the VMEM budget "
-            f"{_VMEM_CELL_BUDGET}; pass a CSCLayout and "
+            f"flat Pallas kernel forced but V+1 = {n_nodes + 1} rows at "
+            f"B={batch} exceed its VMEM budget; pass a CSCLayout and "
             f"use_pallas='node_blocked', or use_pallas=None to "
             f"auto-dispatch")
     return "flat"
@@ -280,7 +278,7 @@ def select_route(n_nodes: int, e_pad: int, batch: int, *, csc=None,
                                    "skip_inactive"))
 def frontier_expand(src, dst, dist, sigma, level, *, csc=None, shard=None,
                     use_pallas=None, interpret=None,
-                    block_e=DEFAULT_BLOCK_E, skip_inactive=True,
+                    block_e=None, skip_inactive=True,
                     block_active=None):
     """Route one frontier expansion to the right lane (module docstring).
 
@@ -291,12 +289,11 @@ def frontier_expand(src, dst, dist, sigma, level, *, csc=None, shard=None,
     XLA reference lanes reduce over every edge regardless, so the
     bitmap is ignored there.
     """
-    if interpret is None:
-        # default by backend: compile the Pallas kernels on real TPUs,
-        # interpret (and hence auto-route to the XLA ref) elsewhere —
-        # this is what makes the CSC lane reachable from the BFS
-        # drivers, which call this dispatcher without an interpret flag
-        interpret = jax.default_backend() != "tpu"
+    # default by backend: compile the Pallas kernels on real TPUs,
+    # interpret (and hence auto-route to the XLA ref) elsewhere — this is
+    # what makes the kernel lanes reachable from the BFS drivers, which
+    # call this dispatcher without an interpret flag
+    interpret = resolve_interpret(interpret)
     batched = dist.ndim == 2
     batch = dist.shape[1] if batched else 1
     v1 = dist.shape[0]
@@ -306,7 +303,7 @@ def frontier_expand(src, dst, dist, sigma, level, *, csc=None, shard=None,
     # a conservative stand-in for n_nodes in the flat-fit check.  On the
     # SHARDED lanes (``shard=...``) dist instead covers the all-gathered
     # global rows and the output is the shard's local tile stack.
-    route = select_route(v1 - 1, src.shape[0], batch, csc=csc, shard=shard,
+    route = select_route(v1 - 1, batch, csc=csc, shard=shard,
                          use_pallas=use_pallas, interpret=interpret,
                          block_e=block_e)
 
@@ -361,12 +358,11 @@ def frontier_relax(src, dst, weight, tent, active, *, csc=None, shard=None,
     only — forcing a Pallas lane raises the loud forced-lane error at
     trace time.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     batch = tent.shape[1]
-    route = select_route(tent.shape[0] - 1, src.shape[0], batch, csc=csc,
-                         shard=shard, use_pallas=use_pallas,
-                         interpret=interpret, weighted=True)
+    route = select_route(tent.shape[0] - 1, batch, csc=csc, shard=shard,
+                         use_pallas=use_pallas, interpret=interpret,
+                         weighted=True)
     if route == "sharded_ref":
         return frontier_relax_sharded_ref(shard, tent, active)
     return frontier_relax_batched_ref(src, dst, weight, tent, active)

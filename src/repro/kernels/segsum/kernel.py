@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_N = 1024
 DEFAULT_BLOCK_D = 128
 
@@ -54,7 +56,7 @@ def _kernel(ids_ref, seg_ref, w_ref, table_ref, out_ref, *,
 def gather_segment_sum_pallas(ids, seg, w, table, n_segments: int, *,
                               block_n: int = DEFAULT_BLOCK_N,
                               block_d: int = DEFAULT_BLOCK_D,
-                              interpret: bool = True):
+                              interpret: bool | None = None):
     n = ids.shape[0]
     v1, d = table.shape
     block_n = min(block_n, n)
@@ -74,5 +76,5 @@ def gather_segment_sum_pallas(ids, seg, w, table, n_segments: int, *,
         ],
         out_specs=pl.BlockSpec((n_segments, block_d), lambda i, j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((n_segments, d), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids, seg, w.astype(jnp.float32), table).astype(table.dtype)

@@ -22,7 +22,7 @@ _VMEM_SEGMENTS = 4096        # one-hot tile budget
 
 @partial(jax.jit, static_argnames=("n_segments", "use_pallas", "interpret"))
 def gather_segment_sum(ids, seg, w, table, n_segments, *, use_pallas=False,
-                       interpret=True):
+                       interpret=None):
     if use_pallas:
         return gather_segment_sum_pallas(ids, seg, w, table, n_segments,
                                          interpret=interpret)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_V = 16384
 _NEG = -1e30  # python scalar: jnp constants would be captured by the trace
 
@@ -38,12 +40,16 @@ def _kernel(scal_ref, counts_ref, lil_ref, liu_ref, out_ref):
     b = omega / tau + 1.0 / 3.0
     f = (ell_l / tau) * (-a + jnp.sqrt(a * a + 2.0 * btilde * omega / ell_l))
     g = (ell_u / tau) * (b + jnp.sqrt(b * b + 2.0 * btilde * omega / ell_u))
-    out_ref[0, 0] = jnp.maximum(out_ref[0, 0], jnp.max(f))
-    out_ref[0, 1] = jnp.maximum(out_ref[0, 1], jnp.max(g))
+    # [max f, max g] as one (1, 2) vector: Mosaic stores no scalars
+    # to VMEM
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1)
+    out_ref[...] = jnp.maximum(out_ref[...],
+                               jnp.where(col == 0, jnp.max(f), jnp.max(g)))
 
 
 def stopcheck_pallas(counts, tau, log_inv_delta_l, log_inv_delta_u, omega, *,
-                     block_v: int = DEFAULT_BLOCK_V, interpret: bool = True):
+                     block_v: int = DEFAULT_BLOCK_V,
+                     interpret: bool | None = None):
     v = counts.shape[0]
     block_v = min(block_v, v)
     # pad to a block multiple; padding rows get counts=0, ell=tiny -> f=g~0
@@ -70,6 +76,6 @@ def stopcheck_pallas(counts, tau, log_inv_delta_l, log_inv_delta_u, omega, *,
         ],
         out_specs=pl.BlockSpec((1, 2), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 2), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scal, counts, log_inv_delta_l, log_inv_delta_u)
     return out[0]

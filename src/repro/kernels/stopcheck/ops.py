@@ -32,7 +32,7 @@ __all__ = ["stopcheck", "register_stop_rule", "get_stop_rule",
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def stopcheck(counts, tau, log_inv_delta_l, log_inv_delta_u, omega, *,
-              use_pallas=False, interpret=True):
+              use_pallas=False, interpret=None):
     if use_pallas:
         return stopcheck_pallas(counts, tau, log_inv_delta_l,
                                 log_inv_delta_u, omega, interpret=interpret)

@@ -14,18 +14,19 @@ inter-pod tier (the paper's *global* communicator across nodes).
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh_compat
+import jax
 
-__all__ = ["make_mesh_compat", "make_production_mesh",
-           "make_single_device_mesh"]
+__all__ = ["make_production_mesh", "make_single_device_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_single_device_mesh():
     """1-device mesh with the production axis names (tests / laptops)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

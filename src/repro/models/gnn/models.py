@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from ..common import dense_init, gelu, ones_init, rms_norm
 from . import irreps
 from .message_passing import (GraphBatch, gather_src, graph_regression_loss,
@@ -197,7 +196,7 @@ def egnn_forward_partitioned(params, batch: GraphBatch, cfg: EgnnConfig,
     prep = jax.tree.map(lambda _: P(), params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(prep, nspec, P(alla), nspec, espec, espec, espec),
         out_specs=(nspec, nspec), check_vma=False)
     def fwd(params, x_loc, z_loc, pos_loc, src, dst, emask):

@@ -182,11 +182,11 @@ _SPMD_SCRIPT = textwrap.dedent("""
     import numpy as np
     import networkx as nx
     from repro.core import AdaptiveConfig, brandes_numpy, from_edge_list, run_kadabra
-    from repro.launch.mesh import make_mesh_compat
 
     G = nx.connected_watts_strogatz_graph(60, 6, 0.3, seed=0)
     g = from_edge_list(np.array(G.edges()), 60)
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     for agg in ["hierarchical", "flat", "root"]:
         cfg = AdaptiveConfig(eps=0.05, delta=0.1, aggregation=agg)
         res = run_kadabra(g, mesh=mesh, config=cfg)

@@ -99,7 +99,7 @@ def test_persisted_csc_state_shape_identity_over_budget():
     g = grid_graph(126, 126)
     assert (g.n_nodes + 1) * batch > 1_000_000
     gc = with_csc_layout(g, batch=batch)
-    assert not pallas_supported(g.n_nodes, g.e_pad, batch=batch)
+    assert not pallas_supported(g.n_nodes, batch=batch)
     assert node_blocked_supported(gc.csc, batch)
     assert gc.csc.v_pad > g.n_nodes + 1
     rng = np.random.default_rng(11)

@@ -38,7 +38,6 @@ _AGG_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import shard_map, make_mesh_compat
     from repro.core import distributed as dist
     from repro.core.adaptive import _pad_len
 
@@ -56,11 +55,12 @@ _AGG_SCRIPT = textwrap.dedent("""
         (("pod", "data"), (4, 2)),               # thin local tier
     ]
     for axes, shape in meshes:
-        mesh = make_mesh_compat(shape, axes)
+        mesh = jax.make_mesh(
+            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         local_axes, global_axes = dist.sampler_axes(mesh)
         frame_spec = P(axes, None)
 
-        @partial(shard_map, mesh=mesh, in_specs=(frame_spec,),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(frame_spec,),
                  out_specs=(P(), P(), P()), check_vma=False)
         def reduce_all(fr):
             x = fr[0]
@@ -81,9 +81,10 @@ _AGG_SCRIPT = textwrap.dedent("""
         print(f"OK {axes}")
 
     # scalar frames (tau) take the flat path everywhere
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(("pod", "data", "model")),),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(("pod", "data", "model")),),
              out_specs=P(), check_vma=False)
     def tau_sum(t):
         return dist.flat_allreduce(t[0], ("pod", "data", "model"))

@@ -15,12 +15,12 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.launch.dryrun import _compile_once, _lin
-    from repro.launch.mesh import make_mesh_compat
     from repro.models import registry
     from repro.models.common import LoopConfig
     from repro.models.transformer import TransformerConfig
 
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     axes = tuple(mesh.axis_names)
     arch = registry.get("llama3.2-3b")
 

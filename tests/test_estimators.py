@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import distributed as dist
 from repro.core.adaptive import (DEFAULT_SAMPLE_BATCH_SIZE, _pad_len,
                                  resolve_sample_batch_size)
@@ -140,7 +139,7 @@ def legacy_run_spmd(graph, cfg, key, mesh):
     bsz = resolve_sample_batch_size(cfg.sample_batch_size,
                                     graph.n_nodes, vd)
 
-    @partial(shard_map, mesh=mesh, in_specs=(gspec, key_spec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, key_spec),
              out_specs=(rep, rep), check_vma=False)
     def calib_step(g, keys):
         c, t = sample_batch(g, keys[0], cfg.calib_samples_per_device,
@@ -164,7 +163,7 @@ def legacy_run_spmd(graph, cfg, key, mesh):
                    frame_tau, sur_counts, sur_tau, keys):
         pspec = jax.tree.map(lambda _: rep, params)
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(gspec, pspec, rep, rep, frame_spec, rep,
                            frame_spec, rep, key_spec),
                  out_specs=(rep, rep, frame_spec, rep, frame_spec, rep,
@@ -211,7 +210,7 @@ def legacy_run_spmd(graph, cfg, key, mesh):
         done = bool(done_dev)
         epoch += 1
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(frame_spec, rep, frame_spec, rep),
              out_specs=(rep, rep), check_vma=False)
     def flush(frame_counts, frame_tau, sur_counts, sur_tau):
@@ -237,7 +236,7 @@ def legacy_run_sharded(pg, cfg, key, mesh):
     v1 = pg.n_nodes + 1
     want_dist = pg.exchange_budget_auto
 
-    @partial(shard_map, mesh=mesh, in_specs=(gspec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec,),
              out_specs=(rep, P(all_axes)) if want_dist else rep,
              check_vma=False)
     def diam_step(g):
@@ -269,7 +268,7 @@ def legacy_run_sharded(pg, cfg, key, mesh):
     bsz = resolve_sample_batch_size(cfg.sample_batch_size, pg.n_nodes, vd)
     n_cal = cfg.calib_samples_per_device * n_dev
 
-    @partial(shard_map, mesh=mesh, in_specs=(gspec, rep),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, rep),
              out_specs=(rep, rep), check_vma=False)
     def calib_step(g, k):
         c, t = sample_batch(g, k, n_cal, batch_size=bsz, axis=all_axes)
@@ -289,7 +288,7 @@ def legacy_run_sharded(pg, cfg, key, mesh):
                    frame_tau, sur_counts, sur_tau, k):
         pspec = jax.tree.map(lambda _: rep, params)
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(gspec, pspec, rep, rep, rep, rep, rep, rep,
                            rep),
                  out_specs=(rep,) * 9, check_vma=False)
@@ -373,10 +372,10 @@ def test_run_fixed_sampling_bit_matches_frozen_legacy():
 _MESH_BODY = r"""
 from repro.core import AdaptiveConfig, erdos_renyi_graph, partition_graph, \
     run_kadabra
-from repro.launch.mesh import make_mesh_compat
 
 g = erdos_renyi_graph(96, 5.0, seed=5)
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 key = jax.random.PRNGKey(11)
 for agg in ["hierarchical", "flat", "root"]:
     cfg = AdaptiveConfig(eps=0.08, delta=0.1, aggregation=agg, n0_base=400)

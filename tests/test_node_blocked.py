@@ -110,7 +110,7 @@ def test_node_blocked_above_vmem_budget_bit_for_bit():
     batch = 64
     g = grid_graph(126, 126)
     assert (g.n_nodes + 1) * batch > 1_000_000
-    assert not pallas_supported(g.n_nodes, g.e_pad, batch=batch)
+    assert not pallas_supported(g.n_nodes, batch=batch)
     csc = build_csc_layout(g, batch=batch)  # default blocking fits
     assert node_blocked_supported(csc, batch)
     dist, sigma, levels = _bfs_state(g, batch, seed=11)
@@ -119,7 +119,7 @@ def test_node_blocked_above_vmem_budget_bit_for_bit():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     # on hardware (interpret=False) the dispatcher auto-routes this
     # instance to the node-blocked lane — the flat kernel cannot fit
-    assert select_route(g.n_nodes, g.e_pad, batch, csc=csc,
+    assert select_route(g.n_nodes, batch, csc=csc,
                         interpret=False) == "node_blocked"
     # the forced node-blocked lane through the dispatcher agrees too
     forced = frontier_expand(g.src, g.dst, dist, sigma, levels, csc=csc,
@@ -141,21 +141,21 @@ def test_dispatch_route_selection():
     csc = build_csc_layout(g, block_v=64, block_e=128)
     assert g.e_pad % 2048 != 0  # unaligned to the default block_e ...
     # ... yet the flat kernel is supported (it pads the edge stream)
-    assert pallas_supported(g.n_nodes, g.e_pad, batch=4)
+    assert pallas_supported(g.n_nodes, batch=4)
     # hardware auto-routing: flat while it fits, node-blocked above the
     # budget (csc given), ref as the last resort
-    assert select_route(g.n_nodes, g.e_pad, 4,
+    assert select_route(g.n_nodes, 4,
                         interpret=False) == "flat"
-    assert select_route(70_000, g.e_pad, 16, csc=csc,
+    assert select_route(70_000, 16, csc=csc,
                         interpret=False) == "node_blocked"
-    assert select_route(70_000, g.e_pad, 16, csc=None,
+    assert select_route(70_000, 16, csc=None,
                         interpret=False) == "ref"
     # interpret mode: auto never picks an interpreted kernel ...
-    assert select_route(g.n_nodes, g.e_pad, 4, interpret=True) == "ref"
+    assert select_route(g.n_nodes, 4, interpret=True) == "ref"
     # ... but forcing engages it (how the parity tests below run)
-    assert select_route(g.n_nodes, g.e_pad, 4, use_pallas=True,
+    assert select_route(g.n_nodes, 4, use_pallas=True,
                         interpret=True) == "flat"
-    assert select_route(g.n_nodes, g.e_pad, 4, csc=csc,
+    assert select_route(g.n_nodes, 4, csc=csc,
                         use_pallas="node_blocked",
                         interpret=True) == "node_blocked"
 

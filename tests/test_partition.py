@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh_compat, shard_map
 from repro.core import (build_csc_layout, erdos_renyi_graph, grid_graph,
                         partition_graph, vertex_owner)
 from repro.core.bfs import bfs_sssp_batched, bfs_sssp_batched_sharded
@@ -142,13 +141,13 @@ def test_select_route_sharded():
     lcsc = pg.shards.shard(0)
     assert sharded_supported(lcsc, 8)
     kw = dict(csc=None, shard=lcsc)
-    assert select_route(400, 1024, 8, interpret=True, **kw) == "sharded_ref"
-    assert select_route(400, 1024, 8, interpret=False, **kw) == "sharded_nb"
-    assert select_route(400, 1024, 8, use_pallas=False, **kw) == "sharded_ref"
-    assert select_route(400, 1024, 8, use_pallas="node_blocked",
+    assert select_route(400, 8, interpret=True, **kw) == "sharded_ref"
+    assert select_route(400, 8, interpret=False, **kw) == "sharded_nb"
+    assert select_route(400, 8, use_pallas=False, **kw) == "sharded_ref"
+    assert select_route(400, 8, use_pallas="node_blocked",
                         **kw) == "sharded_nb"
     with pytest.raises(ValueError, match="flat"):
-        select_route(400, 1024, 8, use_pallas=True, **kw)
+        select_route(400, 8, use_pallas=True, **kw)
 
 
 def test_sharded_expand_lanes_agree_with_restricted_global():
@@ -324,14 +323,15 @@ def test_sharded_bfs_parity_one_shard():
     g = grid_graph(16, 8)
     pg = partition_graph(g, 1, block_v=32, block_e=128)
     assert pg.exchange_budget > 0          # sparse protocol reachable
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sources = jnp.asarray([0, 64, 127], jnp.int32)
 
     def run_on(pgraph):
         gspec = pgraph.partition_spec(("data",))
 
         @jax.jit
-        @partial(shard_map, mesh=mesh, in_specs=(gspec,),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec,),
                  out_specs=(P("data"), P("data"), P()), check_vma=False)
         def run(pgl):
             r = bfs_sssp_batched_sharded(pgl, sources, axis=("data",))
@@ -359,7 +359,8 @@ def test_run_kadabra_partitioned_requires_mesh():
     from repro.core import run_kadabra
     with pytest.raises(ValueError, match="mesh"):
         run_kadabra(pg)
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     with pytest.raises(ValueError, match="shards"):
         run_kadabra(pg, mesh=mesh)
 
@@ -377,7 +378,6 @@ _MESH8_SCRIPT = textwrap.dedent("""
     import numpy as np
     import networkx as nx
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map, make_mesh_compat
     from repro.core import (AdaptiveConfig, brandes_numpy, erdos_renyi_graph,
                             from_edge_list, partition_graph, run_kadabra,
                             sample_batch)
@@ -388,7 +388,8 @@ _MESH8_SCRIPT = textwrap.dedent("""
     from repro.kernels.frontier import pallas_supported
 
     axes = ("data",)
-    mesh = make_mesh_compat((8,), axes)
+    mesh = jax.make_mesh((8,), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
     # --- batched BFS parity at V ABOVE the single-shard fit predicate ---
     # (grid instance: the staged gather's pair-bucketed layout targets
@@ -396,14 +397,14 @@ _MESH8_SCRIPT = textwrap.dedent("""
     from repro.core import grid_graph
     B = 64
     g = grid_graph(126, 126)
-    assert not pallas_supported(g.n_nodes, g.e_pad, batch=B)
+    assert not pallas_supported(g.n_nodes, batch=B)
     pg = partition_graph(g, 8, batch=B)
     gspec = pg.partition_spec(axes)
     rng = np.random.default_rng(11)
     sources = jnp.asarray(rng.integers(0, g.n_nodes, B), jnp.int32)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(gspec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec,),
              out_specs=(P("data"), P("data"), P()), check_vma=False)
     def run_bfs(pgl):
         r = bfs_sssp_batched_sharded(pgl, sources, axis=axes)
@@ -433,7 +434,8 @@ _MESH8_SCRIPT = textwrap.dedent("""
         # the static exchange_budget, so a spec tree from one budget
         # cannot serve a graph with another
         @jax.jit
-        @partial(shard_map, mesh=mesh, in_specs=(pgraph.partition_spec(axes),),
+        @partial(jax.shard_map, mesh=mesh,
+                 in_specs=(pgraph.partition_spec(axes),),
                  out_specs=(P("data"),) * 4 + (P(), P()), check_vma=False)
         def run(pgl):
             r = bidirectional_bfs_batched_sharded(pgl, ss, tt, axis=axes)
@@ -462,7 +464,7 @@ _MESH8_SCRIPT = textwrap.dedent("""
     print("OK exchange_protocol_parity")
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(gspec2,), out_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec2,), out_specs=P(),
              check_vma=False)
     def run_diam(pgl):
         return estimate_diameter_sharded(pgl, axis=axes).vertex_diameter
@@ -474,7 +476,7 @@ _MESH8_SCRIPT = textwrap.dedent("""
     key = jax.random.PRNGKey(5)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(gspec2, P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec2, P()),
              out_specs=(P(), P()), check_vma=False)
     def run_samp(pgl, k):
         return sample_batch(pgl, k, 19, batch_size=6, axis=axes)
@@ -492,7 +494,8 @@ _MESH8_SCRIPT = textwrap.dedent("""
     pg3 = partition_graph(g3, 8, block_v=8, block_e=128)
     eps = 0.05
     cfg = AdaptiveConfig(eps=eps, delta=0.1, n0_base=400)
-    mesh3 = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
     res = run_kadabra(pg3, mesh=mesh3, config=cfg, key=jax.random.PRNGKey(0))
     exact = brandes_numpy(g3)
     err = np.abs(res.btilde - exact).max()

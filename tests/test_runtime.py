@@ -116,8 +116,8 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     import jax, numpy as np, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint.store import save, restore
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((%d,), ("model",))
+    mesh = jax.make_mesh((%d,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     w = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                        NamedSharding(mesh, P(None, "model")))
     tree = {"w": w}

@@ -173,7 +173,7 @@ def test_three_way_parity_above_flat_fit():
     staged kernel exists for — at the default blocking."""
     batch = 64
     g = grid_graph(126, 126)
-    assert not pallas_supported(g.n_nodes, g.e_pad, batch=batch)
+    assert not pallas_supported(g.n_nodes, batch=batch)
     csc = build_csc_layout(g, batch=batch)
     dist, sigma, levels = _bfs_state(g, batch, seed=7)
     ref = frontier_expand_batched_ref(g.src, g.dst, dist, sigma, levels)

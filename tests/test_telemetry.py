@@ -226,14 +226,14 @@ import numpy as np, jax
 from jax.sharding import Mesh
 from repro.core import AdaptiveConfig, erdos_renyi_graph, partition_graph
 from repro.core.engine import run_adaptive
-from repro.launch.mesh import make_mesh_compat
 from repro.runtime import RingSink, Telemetry
 
 g = erdos_renyi_graph(96, 5.0, seed=5)
 key = jax.random.PRNGKey(11)
 cfg = AdaptiveConfig(eps=0.08, delta=0.1, n0_base=400)
 
-mesh3 = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 3)
 off = run_adaptive(g, ("betweenness",), mesh=mesh3, config=cfg, key=key)
 tel = Telemetry([RingSink()], validate=True)
 on = run_adaptive(g, ("betweenness",), mesh=mesh3, config=cfg, key=key,

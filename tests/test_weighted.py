@@ -46,7 +46,6 @@ import scipy.sparse.csgraph as csg
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh_compat, shard_map
 from repro.core import (build_csc_layout, build_graph, grid_graph,
                         partition_graph, run_adaptive, run_fixed,
                         symmetric_dyadic_weights, with_csc_layout,
@@ -217,12 +216,13 @@ def test_dijkstra_parity_csc(name):
 def test_dijkstra_parity_sharded_1dev(name):
     g = _INSTANCES[name]()
     pg = partition_graph(g, 1)
-    mesh = make_mesh_compat((1,), AXES)
+    mesh = jax.make_mesh((1,), AXES,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(AXES))
     rng = np.random.default_rng(17)
     sources = jnp.asarray(rng.integers(0, g.n_nodes, 8), jnp.int32)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(pg.partition_spec(AXES),),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(pg.partition_spec(AXES),),
              out_specs=(P("data"), P("data"), P(), P(), P()),
              check_vma=False)
     def run(pgl):
@@ -305,9 +305,9 @@ def test_select_route_contract(use_pallas, weighted, expect):
               weighted=weighted)
     if expect is ValueError:
         with pytest.raises(ValueError, match="Pallas"):
-            select_route(g.n_nodes, g.e_pad, 4, **kw)
+            select_route(g.n_nodes, 4, **kw)
     else:
-        assert select_route(g.n_nodes, g.e_pad, 4, **kw) == expect
+        assert select_route(g.n_nodes, 4, **kw) == expect
 
 
 @pytest.mark.parametrize("use_pallas", [True, "node_blocked"])
@@ -399,7 +399,6 @@ _MESH8_WEIGHTED_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh_compat, shard_map
     from repro.core import (grid_graph, partition_graph, run_adaptive,
                             symmetric_dyadic_weights, with_weights)
     from repro.core.bfs import (delta_sssp_batched,
@@ -408,7 +407,8 @@ _MESH8_WEIGHTED_SCRIPT = textwrap.dedent("""
                                     sample_path_weighted_batched_sharded)
 
     axes = ("data",)
-    mesh = make_mesh_compat((8,), axes)
+    mesh = jax.make_mesh((8,), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
     g = with_weights(grid_graph(24, 16),
                      symmetric_dyadic_weights(grid_graph(24, 16), seed=2))
@@ -418,7 +418,7 @@ _MESH8_WEIGHTED_SCRIPT = textwrap.dedent("""
     sources = jnp.asarray(rng.integers(0, g.n_nodes, 16), jnp.int32)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(gspec,),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec,),
              out_specs=(P("data"), P("data"), P(), P(), P()),
              check_vma=False)
     def run_sssp(pgl):
@@ -438,7 +438,7 @@ _MESH8_WEIGHTED_SCRIPT = textwrap.dedent("""
     key = jax.random.PRNGKey(9)
 
     @jax.jit
-    @partial(shard_map, mesh=mesh, in_specs=(gspec, P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, P()),
              out_specs=(P(), P(), P(), P(), P(), P()), check_vma=False)
     def run_draw(pgl, k):
         smp = sample_path_weighted_batched_sharded(pgl, k, 8, axis=axes)
