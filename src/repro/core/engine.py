@@ -152,11 +152,19 @@ class AdaptiveRunResult(NamedTuple):
     converged: bool             # every metric's own rule fired
     vertex_diameter: int
     stats: list                 # list[EngineEpochStats]
-    phase_seconds: dict         # diameter / calibration / sampling
+    # host seconds per phase, each ending on a blocking read: "diameter"
+    # (phase 1, ends on the bound's int()), "calibration" (draws +
+    # stop-rule params, ends on block_until_ready of both), "sampling"
+    # (the epochs, each ending on its stop flags, and the final flush)
+    phase_seconds: dict
     # the sampling phase: concurrent samples per BFS round and the
     # frontier-expansion lane its BFS levels ran on (bfs.frontier_route)
     batch_size: int = 0
     route: str = ""
+    # compiles, cache loads and traces per phase ("diameter",
+    # "calibration", "sampling" as in phase_seconds, "other" the rest of
+    # the call): {phase: {key: value for HOST_COUNTER_KEYS}}
+    host_counters: Optional[dict] = None
 
 
 def _pad_len(v: int, n_dev: int) -> int:
@@ -636,33 +644,42 @@ def _sharded_diameter(pg: PartitionedGraph, mesh, n_sweeps: int):
     return vd, pg
 
 
+def _replicated_phase1(graph: Graph, stream: str, n_sweeps: int):
+    """Phase 1 on a replicated graph: the vertex-diameter bound and the
+    distance cap (0.0 unweighted).  Its program is a named function
+    (``jit_phase1_diameter`` in a device trace), not a ``partial``,
+    which lowers as ``jit__unknown``."""
+    if stream == "weighted":
+        # weighted phase 1: hop-based VD bound for omega PLUS the
+        # weighted-diameter bound distance-normalizing estimators use
+        # as their cap (RunContext.distance_cap)
+        def phase1_weighted_diameter(g):
+            return estimate_diameter_weighted(g, n_sweeps=n_sweeps)
+        wdiam = jax.jit(phase1_weighted_diameter)(graph)
+        return int(wdiam.vertex_diameter), float(wdiam.upper)
+
+    def phase1_diameter(g):
+        return estimate_diameter(g, n_sweeps=n_sweeps)
+    return int(jax.jit(phase1_diameter)(graph).vertex_diameter), 0.0
+
+
 def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
                  stream: str, C: int, offsets):
     ns = SimpleNamespace()
     v_pad = _pad_len(graph.n_nodes, 1)
     v1 = graph.n_nodes + 1
     t0 = time.perf_counter()
-    if stream == "weighted":
-        # weighted phase 1: hop-based VD bound for omega PLUS the
-        # weighted-diameter bound distance-normalizing estimators use
-        # as their cap (RunContext.distance_cap)
-        wdiam = jax.jit(partial(estimate_diameter_weighted,
-                                n_sweeps=cfg.diameter_sweeps))(graph)
-        ns.vd = int(wdiam.vertex_diameter)
-        ns.dist_cap = float(wdiam.upper)
-    else:
-        diam = jax.jit(partial(estimate_diameter,
-                               n_sweeps=cfg.diameter_sweeps))(graph)
-        ns.vd = int(diam.vertex_diameter)
-        ns.dist_cap = 0.0
+    ns.vd, ns.dist_cap = _replicated_phase1(graph, stream,
+                                            cfg.diameter_sweeps)
     ns.t_diam = time.perf_counter() - t0
     ns.graph, ns.v_pad, ns.n_samplers, ns.shardings = graph, v_pad, 1, None
 
     def calibrate(k_cal, bsz, ctx):
-        return jax.jit(partial(
-            draw_fold, n_samples=cfg.calib_samples_per_device,
-            batch_size=bsz, estimators=estimators, ctx=ctx,
-            stream=stream))(graph, k_cal)
+        def calibration_draws(g, k):
+            return draw_fold(g, k, n_samples=cfg.calib_samples_per_device,
+                             batch_size=bsz, estimators=estimators, ctx=ctx,
+                             stream=stream)
+        return jax.jit(calibration_draws)(graph, k_cal)
 
     def make_epoch(params, ctx, n0, bsz):
         @jax.jit
@@ -719,16 +736,8 @@ def _spmd_lane(graph: Graph, mesh: Mesh, cfg: AdaptiveConfig, estimators,
     gspec = jax.tree.map(lambda _: rep, graph)
 
     t0 = time.perf_counter()
-    if stream == "weighted":
-        wdiam = jax.jit(partial(estimate_diameter_weighted,
-                                n_sweeps=cfg.diameter_sweeps))(graph)
-        ns.vd = int(wdiam.vertex_diameter)
-        ns.dist_cap = float(wdiam.upper)
-    else:
-        diam = jax.jit(partial(estimate_diameter,
-                               n_sweeps=cfg.diameter_sweeps))(graph)
-        ns.vd = int(diam.vertex_diameter)
-        ns.dist_cap = 0.0
+    ns.vd, ns.dist_cap = _replicated_phase1(graph, stream,
+                                            cfg.diameter_sweeps)
     ns.t_diam = time.perf_counter() - t0
     ns.graph, ns.v_pad, ns.n_samplers = graph, v_pad, n_dev
     # shardings follow the 10-leaf checkpoint tuple: frames sharded over
@@ -942,8 +951,23 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     so telemetry on is bit-identical to telemetry off on every lane:
     the compiled programs and the key stream are the same; only
     host-side observation differs.
+
+    ``phase_seconds`` and ``host_counters`` split the call into phases:
+    ``diameter`` (phase 1, ending on the host read of the bound; its
+    counters include the lane setup around it), ``calibration`` (calibration draws and stop-rule params,
+    ending on ``jax.block_until_ready`` of both: the epoch program holds
+    the params as constants, so its lowering waits for them anyway) and
+    ``sampling`` (the epochs, each ending on the host read of its stop
+    flags, and the final flush); ``host_counters`` adds ``other``, the
+    rest of the call.  Each phase counts the backend compiles, cache
+    loads and traces it spent (``repro.runtime.telemetry``
+    ``host_counter_delta``); ``run.end`` carries their totals.  While a
+    ``jax.profiler`` trace runs, the spans are trace annotations too.
     """
-    from repro.runtime.telemetry import resolve_telemetry
+    from repro.runtime.telemetry import (host_counter_delta,
+                                         host_counter_totals,
+                                         resolve_telemetry)
+    c_start = host_counter_totals()
     telemetry = resolve_telemetry(telemetry)
     cfg = config if config is not None else AdaptiveConfig()
     overrides = {}
@@ -979,6 +1003,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
                    metrics=[e.name for e in estimators],
                    n_nodes=int(graph.n_nodes), eps=float(cfg.eps),
                    delta=float(cfg.delta))
+    c_diam = host_counter_totals()
     with telemetry.span("phase.diameter"):
         if lane_name == "sharded":
             lane = _sharded_lane(graph, mesh, cfg, estimators, stream, C,
@@ -988,6 +1013,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
         else:
             lane = _spmd_lane(graph, mesh, cfg, estimators, stream, C,
                               offsets)
+    c_diam_end = host_counter_totals()
 
     ctx = RunContext(int(lane.graph.n_nodes), lane.vd, lane.dist_cap)
     bsz = resolve_sample_batch_size(cfg.sample_batch_size, ctx.n_nodes,
@@ -1000,6 +1026,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
 
     # ---- phase 2: calibration + per-estimator stop-rule params ---------
     t0 = time.perf_counter()
+    c_cal = host_counter_totals()
     with telemetry.span("phase.calibration"):
         key, k_cal = jax.random.split(key)
         counts0, tau0 = lane.calibrate(k_cal, bsz, ctx)
@@ -1007,7 +1034,9 @@ def run_adaptive(graph, metrics=("betweenness",), *,
             est.make_params(lane.graph, ctx, cfg.eps, cfg.delta,
                             counts0[off: off + est.n_channels], tau0)
             for est, off in zip(estimators, offsets))
+        jax.block_until_ready((counts0, tau0, params))
     t_cal = time.perf_counter() - t0
+    c_cal_end = host_counter_totals()
 
     # ---- phase 3: the adaptive loop ------------------------------------
     n0 = epoch_length(lane.n_samplers, base=cfg.n0_base,
@@ -1035,6 +1064,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     stats = []
     last_flush = None
     t0 = time.perf_counter()
+    c_samp = host_counter_totals()
     try:
         while not stopped.all() and epoch < cfg.max_epochs:
             with telemetry.span("phase.epoch", epoch=epoch + 1):
@@ -1118,6 +1148,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
         frozen_tau = jnp.where(rem_j, fl_t, frozen_tau)
         stop_epoch = jnp.where(rem_j, jnp.int32(epoch), stop_epoch)
     t_samp = time.perf_counter() - t0
+    c_samp_end = host_counter_totals()
 
     ft_np = np.asarray(frozen_tau)
     se_np = np.asarray(stop_epoch)
@@ -1134,14 +1165,21 @@ def run_adaptive(graph, metrics=("betweenness",), *,
             extras=est.extras(p, ctx)))
     tau_total = (int(last_flush[1]) if last_flush is not None
                  else int(ft_np.max(initial=0)))
+    c_end = host_counter_totals()
+    host_counters = {
+        "diameter": host_counter_delta(c_diam, c_diam_end),
+        "calibration": host_counter_delta(c_cal, c_cal_end),
+        "sampling": host_counter_delta(c_samp, c_samp_end),
+        "other": host_counter_delta(c_start, c_diam, c_diam_end, c_cal,
+                                    c_cal_end, c_samp, c_samp_end, c_end)}
     telemetry.emit("run.end", tau=tau_total, n_epochs=epoch,
                    converged=bool(converged.all()), batch_size=bsz,
-                   route=route)
+                   route=route, **host_counter_delta(c_start, c_end))
     return AdaptiveRunResult(
         tuple(reports), tau_total, epoch, bool(converged.all()),
         ctx.vertex_diameter, stats,
         {"diameter": lane.t_diam, "calibration": t_cal,
-         "sampling": t_samp}, bsz, route)
+         "sampling": t_samp}, bsz, route, host_counters)
 
 
 def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),

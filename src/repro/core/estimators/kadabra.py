@@ -13,8 +13,6 @@ lanes).
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -58,8 +56,10 @@ class BetweennessEstimator(Estimator):
                     calib_counts, calib_tau):
         btilde0 = (calib_counts[0][: ctx.n_nodes]
                    / jnp.maximum(calib_tau.astype(jnp.float32), 1.0))
-        return jax.jit(partial(_params_impl, eps=eps, delta=delta))(
-            ctx.vertex_diameter, btilde0)
+
+        def stop_params(n, b):    # a partial would lower as jit__unknown
+            return _params_impl(n, b, eps=eps, delta=delta)
+        return jax.jit(stop_params)(ctx.vertex_diameter, btilde0)
 
     def accumulate(self, batch: DrawBatch, keep, ctx: RunContext):
         # verbatim the sample_batch fold: masked sum over the round's

@@ -22,8 +22,8 @@ from .supervisor import (EpochTimeoutError, InvariantViolation,
                          ResilientRunResult, RetryPolicy, RunEvent,
                          check_state_invariants, elastic_migrate_state)
 from .telemetry import (JSONLSink, NullSink, NULL_TELEMETRY, RingSink,
-                        Telemetry, chrome_trace, jax_profiler_trace,
-                        resolve_telemetry, write_chrome_trace)
+                        Telemetry, chrome_trace, resolve_telemetry,
+                        write_chrome_trace)
 
 __all__ = [
     "DeviceLoss", "FaultContext", "FaultSchedule", "FaultSpec",
@@ -34,6 +34,5 @@ __all__ = [
     "EVENT_KINDS", "SPAN_NAMES", "SUPERVISOR_EVENT_KINDS", "Event",
     "read_jsonl", "validate_event",
     "JSONLSink", "NullSink", "NULL_TELEMETRY", "RingSink", "Telemetry",
-    "chrome_trace", "jax_profiler_trace", "resolve_telemetry",
-    "write_chrome_trace",
+    "chrome_trace", "resolve_telemetry", "write_chrome_trace",
 ]

@@ -27,10 +27,24 @@ and emission is thread-safe — the async checkpoint publisher emits
 from its background thread onto the same bus, distinguished by the
 event's ``tid``.
 
-Exporters: :func:`chrome_trace` turns a stream into the Chrome/Perfetto
-trace-event JSON (load at ``chrome://tracing`` or ui.perfetto.dev), and
-:func:`jax_profiler_trace` is the optional gate around a run that also
-captures a ``jax.profiler`` device trace into a log directory.
+While a ``jax.profiler`` trace is running, every span is also a
+``jax.profiler.TraceAnnotation`` of the same name and fields, bus on or
+off: the phases then sit on the trace's host plane, on the clock of the
+device operations, and each closes with the compile and trace counters
+it spent (below) as stats.  Every emitted event is likewise left as an
+instant annotation of its kind.  With no trace running and the bus off,
+nothing of this runs.
+
+The compile and trace counter: :func:`host_counter_totals` reads
+per-thread running totals of JAX's backend compiles, persistent-cache
+loads and jaxpr traces and MLIR lowerings, kept by ``jax.monitoring``
+listeners registered once per process on first use;
+:func:`host_counter_delta` turns readings into the counts and seconds
+in between.  ``run_adaptive`` reports them per phase
+(``AdaptiveRunResult.host_counters``).
+
+Exporter: :func:`chrome_trace` turns a stream into the Chrome/Perfetto
+trace-event JSON (load at ``chrome://tracing`` or ui.perfetto.dev).
 """
 from __future__ import annotations
 
@@ -38,14 +52,19 @@ import itertools
 import json
 import threading
 import time
-from contextlib import contextmanager
-from typing import Optional
+
+from jax import monitoring
+from jax.profiler import TraceAnnotation
 
 from .events import Event, to_json, validate_event
 
 __all__ = ["Telemetry", "NULL_TELEMETRY", "RingSink", "JSONLSink",
            "NullSink", "resolve_telemetry", "chrome_trace",
-           "write_chrome_trace", "jax_profiler_trace"]
+           "write_chrome_trace", "HOST_COUNTER_KEYS", "host_counter_totals",
+           "host_counter_delta"]
+
+# A profiler trace is running: spans and events are mirrored into it.
+_profiling = TraceAnnotation.is_enabled
 
 
 class NullSink:
@@ -95,6 +114,93 @@ class JSONLSink:
             if not self._f.closed:
                 self._f.close()
 
+
+# ---------------------------------------------------------------------------
+# The compile and trace counter
+# ---------------------------------------------------------------------------
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_TRACE_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_JAXPR_TRACE = _TRACE_LOWER[0]
+
+# What host_counter_delta reports: backend compiles (count, seconds)
+# net of persistent-cache loads, the loads themselves, and jaxpr traces
+# (count) with the seconds of traces and MLIR lowerings together.
+HOST_COUNTER_KEYS = ("compile_s", "compiles", "cache_load_s", "cache_hits",
+                     "trace_lower_s", "traces")
+
+
+class _Totals(threading.local):
+    """Per-thread running totals, as JAX reports them: a persistent-cache
+    hit passes through the backend-compile event with its load time."""
+
+    def __init__(self):
+        self.backend_s = 0.0
+        self.backend_n = 0
+        self.load_s = 0.0
+        self.hits = 0
+        self.trace_lower_s = 0.0
+        self.traces = 0
+
+
+_totals = _Totals()
+_listening = False
+_listen_lock = threading.Lock()
+
+
+def _on_duration(event: str, duration: float, **_):
+    t = _totals
+    if event == _BACKEND_COMPILE:
+        t.backend_s += duration
+        t.backend_n += 1
+    elif event == _CACHE_RETRIEVAL:
+        t.load_s += duration
+    elif event in _TRACE_LOWER:
+        t.trace_lower_s += duration
+        if event == _JAXPR_TRACE:
+            t.traces += 1
+
+
+def _on_event(event: str, **_):
+    if event == _CACHE_HIT:
+        _totals.hits += 1
+
+
+def host_counter_totals() -> tuple:
+    """This thread's running totals (an opaque reading for
+    :func:`host_counter_delta`); the first call registers the
+    ``jax.monitoring`` listeners, once per process."""
+    global _listening
+    if not _listening:
+        with _listen_lock:
+            if not _listening:
+                monitoring.register_event_duration_secs_listener(
+                    _on_duration)
+                monitoring.register_event_listener(_on_event)
+                _listening = True
+    t = _totals
+    return (t.backend_s, t.backend_n, t.load_s, t.hits, t.trace_lower_s,
+            t.traces)
+
+
+def host_counter_delta(*readings: tuple) -> dict:
+    """The compiles, cache loads and traces spent between readings of
+    :func:`host_counter_totals` taken as (start, end) pairs, summed over
+    the pairs, keyed by :data:`HOST_COUNTER_KEYS`."""
+    pairs = list(zip(readings[0::2], readings[1::2]))
+    bs, bn, ls, hits, tls, tr = (sum(e[i] - b[i] for b, e in pairs)
+                                 for i in range(6))
+    return {"compile_s": bs - ls, "compiles": bn - hits,
+            "cache_load_s": ls, "cache_hits": hits,
+            "trace_lower_s": tls, "traces": tr}
+
+
+# ---------------------------------------------------------------------------
+# Spans
+# ---------------------------------------------------------------------------
 
 class _NullSpan:
     """The reusable context manager disabled spans return."""
@@ -152,6 +258,32 @@ class _SpanCtx:
         return False
 
 
+class _TracedSpan:
+    """A span while a profiler trace runs: a ``TraceAnnotation`` of the
+    span's name and fields, closed with the host counters it spent as
+    stats, around the bus span when the bus is on."""
+
+    __slots__ = ("_ann", "_bus", "_c0")
+
+    def __init__(self, tel: "Telemetry", name: str, fields: dict):
+        self._ann = TraceAnnotation(name, **fields)
+        self._bus = _SpanCtx(tel, name, fields) if tel._enabled else None
+        self._c0 = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._c0 = host_counter_totals()
+        return self._bus.__enter__() if self._bus is not None else None
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._bus is not None:
+            self._bus.__exit__(exc_type, exc, tb)
+        self._ann.set_metadata(
+            **host_counter_delta(self._c0, host_counter_totals()))
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
 class Telemetry:
     """The bus.  ``sinks`` is an iterable of objects with
     ``write(event)``; ``validate=True`` checks every emitted event
@@ -188,7 +320,11 @@ class Telemetry:
             s.write(ev)
 
     def emit(self, kind: str, **fields):
-        """Emit one instant event (kind from the registered taxonomy)."""
+        """Emit one instant event (kind from the registered taxonomy);
+        while a profiler trace runs, also an instant annotation of it."""
+        if _profiling():
+            with TraceAnnotation(kind, **fields):
+                pass
         if not self._enabled:
             return
         stack = self._span_stack()
@@ -198,7 +334,10 @@ class Telemetry:
 
     def span(self, name: str, **fields):
         """Context manager timing a named phase; spans nest per thread
-        (``parent`` ids), and the end event carries the duration."""
+        (``parent`` ids), and the end event carries the duration.  While
+        a profiler trace runs, the span is also a trace annotation."""
+        if _profiling():
+            return _TracedSpan(self, name, fields)
         if not self._enabled:
             return _NULL_SPAN
         return _SpanCtx(self, name, fields)
@@ -295,20 +434,3 @@ def write_chrome_trace(path: str, events) -> str:
     with open(path, "w") as f:
         json.dump(chrome_trace(events), f)
     return str(path)
-
-
-@contextmanager
-def jax_profiler_trace(logdir: Optional[str]):
-    """Optional ``jax.profiler`` gate: with a log directory, the wrapped
-    block runs under ``jax.profiler.start_trace``/``stop_trace`` (view
-    in TensorBoard or Perfetto); with ``None`` it is a no-op — so call
-    sites can thread a config value through unconditionally."""
-    if not logdir:
-        yield
-        return
-    import jax
-    jax.profiler.start_trace(str(logdir))
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

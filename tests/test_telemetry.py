@@ -1,8 +1,11 @@
 """Telemetry bus tests: the two production contracts (off is a true
 no-op, on is bit-identical on all three lanes), the taxonomy validator,
-sinks, spans, the JSONL wire format, the Chrome-trace exporter, and the
-trace_report tool reproducing a run's outcome from the file alone.
+sinks, spans, the JSONL wire format, the Chrome-trace exporter, the
+trace_report tool reproducing a run's outcome from the file alone, and
+the profiler mirror of spans, the per-phase compile and trace counter
+and the names of the phase programs.
 """
+import glob
 import json
 import os
 import subprocess
@@ -12,17 +15,19 @@ import tracemalloc
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.core.adaptive import AdaptiveConfig
 from repro.core.engine import run_adaptive
-from repro.core.graph import build_graph
+from repro.core.graph import build_graph, rmat_graph
 from repro.runtime import (FaultSchedule, FaultSpec, ResilientRunner,
                            RetryPolicy)
 from repro.runtime.events import (EVENT_KINDS, SPAN_NAMES, Event, from_json,
                                   read_jsonl, to_json, validate_event)
-from repro.runtime.telemetry import (JSONLSink, NULL_TELEMETRY, RingSink,
-                                     Telemetry, chrome_trace,
-                                     resolve_telemetry, write_chrome_trace)
+from repro.runtime.telemetry import (HOST_COUNTER_KEYS, JSONLSink,
+                                     NULL_TELEMETRY, RingSink, Telemetry,
+                                     chrome_trace, resolve_telemetry,
+                                     write_chrome_trace)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import trace_report  # noqa: E402
@@ -342,3 +347,117 @@ def test_jsonl_sink_appends_and_closes(tmp_path):
     t2.emit("checkpoint.quarantine", step=2)
     t2.close()
     assert [e.fields["step"] for e in read_jsonl(path)] == [1, 2]
+
+
+# ---------------------------------------------------------------------------
+# The profiler mirror of spans, the compile and trace counter, and the
+# named programs
+# ---------------------------------------------------------------------------
+
+PHASES = ("diameter", "calibration", "sampling", "other")
+
+
+@pytest.fixture(scope="module")
+def rmat9():
+    """A scale-9 R-MAT graph and a config whose runs stop at max_epochs,
+    so every run has a final flush; one warm run fills the in-process
+    caches, so later runs compile only their per-call programs."""
+    g = rmat_graph(9, 8, seed=1)
+    cfg = AdaptiveConfig(eps=0.01, delta=0.1, n0_base=64, max_epochs=2)
+    run_adaptive(g, config=cfg, key=jax.random.PRNGKey(1))
+    return g, cfg
+
+
+def _host_annotations(logdir):
+    path = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in SPAN_NAMES or ev.name in EVENT_KINDS:
+                        out.append((ev.start_ns,
+                                    ev.start_ns + ev.duration_ns, ev.name,
+                                    dict(ev.stats)))
+    return sorted(out)
+
+
+def test_spans_reach_a_profiler_trace_with_telemetry_off(rmat9, tmp_path):
+    g, cfg = rmat9
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = run_adaptive(g, config=cfg, key=jax.random.PRNGKey(2))
+    finally:
+        jax.profiler.stop_trace()
+    assert not res.converged and res.n_epochs == 2
+    anns = _host_annotations(tmp_path)
+    names = [a[2] for a in anns if a[2] in SPAN_NAMES]
+    assert names == ["phase.diameter", "phase.calibration", "phase.epoch",
+                     "phase.epoch", "phase.flush"]
+    spans = [a for a in anns if a[2] in SPAN_NAMES]
+    assert [a[3]["epoch"] for a in spans if a[2] == "phase.epoch"] == [1, 2]
+    # one after another, each inside the call (run.start .. run.end)
+    (start,) = [a[0] for a in anns if a[2] == "run.start"]
+    (end,) = [a for a in anns if a[2] == "run.end"]
+    assert start <= spans[0][0]
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+    assert spans[-1][1] <= end[0]
+    # each span closes with the counters it spent; the call's totals
+    # ride run.end, and the spans' sum stays within them
+    for a in spans:
+        assert set(a[3]) >= set(HOST_COUNTER_KEYS)
+    totals = {k: sum(p[k] for p in res.host_counters.values())
+              for k in HOST_COUNTER_KEYS}
+    assert end[3]["compiles"] == totals["compiles"]
+    assert end[3]["traces"] == totals["traces"]
+    assert sum(a[3]["traces"] for a in spans) <= totals["traces"]
+
+
+def test_host_counters_hold_every_phase(rmat9):
+    g, cfg = rmat9
+    res = run_adaptive(g, config=cfg, key=jax.random.PRNGKey(3))
+    assert set(res.host_counters) == set(PHASES)
+    for c in res.host_counters.values():
+        assert set(c) == set(HOST_COUNTER_KEYS)
+        assert c["compiles"] >= 0 and c["traces"] >= 0
+        assert c["compile_s"] >= 0 and c["trace_lower_s"] >= 0
+    # phase 1 and calibration trace and lower programs of their own
+    assert res.host_counters["diameter"]["traces"] > 0
+    assert res.host_counters["calibration"]["traces"] > 0
+
+
+def test_a_compile_inside_a_phase_is_counted_in_that_phase_only(rmat9):
+    g, cfg = rmat9
+    key = jax.random.PRNGKey(4)
+    base = run_adaptive(g, config=cfg, key=key).host_counters
+
+    def on_epoch(epoch, state):
+        # a function no earlier call has compiled, inside the sampling phase
+        jax.jit(lambda t: t * 3 + epoch)(state[1]).block_until_ready()
+
+    hooked = run_adaptive(g, config=cfg, key=key,
+                          on_epoch=on_epoch).host_counters
+    assert hooked["sampling"]["compiles"] >= \
+        base["sampling"]["compiles"] + 1
+    for phase in ("diameter", "calibration", "other"):
+        assert hooked[phase]["compiles"] == base[phase]["compiles"], phase
+
+
+def test_phase_programs_are_named(rmat9):
+    g, cfg = rmat9
+    modules = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            modules.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        run_adaptive(g, config=cfg, key=jax.random.PRNGKey(5))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    # lowered as modules jit_<name>; a jitted partial is jit(_unknown)
+    assert {"jit(phase1_diameter)", "jit(calibration_draws)",
+            "jit(stop_params)"} <= set(modules)
+    assert not [m for m in modules if "unknown" in m], modules
