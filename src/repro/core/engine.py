@@ -682,14 +682,18 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
         return jax.jit(calibration_draws)(graph, k_cal)
 
     def make_epoch(params, ctx, n0, bsz):
+        # the graph and the stop-rule params are arguments, not closure
+        # constants: the program is then the same at every key on one
+        # graph, so a persistent compile cache can serve it
         @jax.jit
-        def epoch_step(agg_c, agg_t, fr_c, fr_t, sur_c, sur_t, k):
+        def epoch_step(g, params, agg_c, agg_t, fr_c, fr_t, sur_c, sur_t,
+                       k):
             agg_c = agg_c + fr_c
             agg_t = agg_t + fr_t
             # surplus reuse: the masked tail of the previous epoch's
             # last round seeds this epoch's frame (valid i.i.d. samples;
             # tau counts them, so every estimator stays exact)
-            (c, t), (sc, st) = draw_fold(graph, k, n0, batch_size=bsz,
+            (c, t), (sc, st) = draw_fold(g, k, n0, batch_size=bsz,
                                          estimators=estimators, ctx=ctx,
                                          stream=stream,
                                          carry=(sur_c, sur_t),
@@ -700,7 +704,13 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
                                       params, ctx)
             return agg_c, agg_t, new_c, t, sc, st, done, mf, mg
 
-        return lambda state, ke: epoch_step(*state, ke)
+        # jit lowers one program per mix of committed and uncommitted
+        # arguments; the step's outputs are committed once the graph is,
+        # the initial (or a restored) state is not: commit the state to
+        # the graph's device, so every epoch runs the same program
+        dev = graph.src.sharding
+        return lambda state, ke: epoch_step(
+            graph, params, *jax.device_put(state, dev), ke)
 
     def make_flush(ctx):
         # association matches the PR 1-6 final flush exactly:
@@ -955,8 +965,9 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     ``phase_seconds`` and ``host_counters`` split the call into phases:
     ``diameter`` (phase 1, ending on the host read of the bound; its
     counters include the lane setup around it), ``calibration`` (calibration draws and stop-rule params,
-    ending on ``jax.block_until_ready`` of both: the epoch program holds
-    the params as constants, so its lowering waits for them anyway) and
+    ending on ``jax.block_until_ready`` of both, so their device work is
+    timed here and not in the first epoch; the epoch program takes the
+    params as arguments, so it is the same at every key on one graph) and
     ``sampling`` (the epochs, each ending on the host read of its stop
     flags, and the final flush); ``host_counters`` adds ``other``, the
     rest of the call.  Each phase counts the backend compiles, cache
