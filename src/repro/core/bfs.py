@@ -102,6 +102,9 @@ class BFSResult(NamedTuple):
     # per-search exchange-protocol tally (ExchangePlan.epoch_accounting
     # prices it); None on the replicated lanes, which exchange nothing.
     exchange: Optional[jax.Array] = None
+    # (2,) int32 [expansions, streamed_blocks] — the replicated drivers'
+    # work count (:func:`_expand_level`); None on the sharded lanes.
+    steps: Optional[jax.Array] = None
 
 
 def _state_rows(graph: Graph) -> int:
@@ -151,14 +154,18 @@ def _expand_level(graph: Graph, dist, sigma, level, active):
     are left untouched.  The contribution matrix comes from the
     ``repro.kernels.frontier`` dispatcher: the graph's persisted CSC
     layout (if any) rides along, so on TPU hardware the expansion runs
-    the node-blocked kernel with occupancy skipping, and on this
-    container it auto-routes to the bit-identical XLA reference — the
+    the node-blocked kernel with occupancy skipping, and on other
+    backends it auto-routes to the bit-identical XLA reference — the
     state layout is the kernels' native one either way, no transposes,
     no pads.  Both BFS drivers (single-source and bidirectional) share
-    this one expansion.  Returns updated (dist, sigma, n_new (B,)).
+    this one expansion.  Returns updated (dist, sigma, n_new (B,),
+    streamed): ``streamed`` is the number of edge blocks the
+    node-blocked kernel streamed rather than skipped (the dispatcher
+    counts them), 0 on every other route.
     """
-    contrib = frontier_expand(graph.src, graph.dst, dist, sigma, level,
-                              csc=graph.csc)
+    contrib, streamed = frontier_expand(graph.src, graph.dst, dist, sigma,
+                                        level, csc=graph.csc,
+                                        with_streamed=True)
     new = (contrib > 0) & (dist == -1) & active[None, :]
     dist = jnp.where(new, level[None, :] + 1, dist)
     sigma = jnp.where(new, contrib, sigma)
@@ -167,7 +174,13 @@ def _expand_level(graph: Graph, dist, sigma, level, active):
     m = jnp.max(jnp.where(new, sigma, 0.0), axis=0, keepdims=True)
     scale = jnp.where(m > _RESCALE_THRESHOLD, 1.0 / m, 1.0)
     sigma = sigma * scale
-    return dist, sigma, jnp.sum(new.astype(jnp.int32), axis=0)
+    return dist, sigma, jnp.sum(new.astype(jnp.int32), axis=0), streamed
+
+
+def _count_steps(steps, streamed):
+    """One more expansion, and the blocks it streamed, onto the (2,)
+    [expansions, streamed_blocks] work count of a BFS driver."""
+    return steps + jnp.stack([jnp.int32(1), streamed])
 
 
 def bfs_sssp_batched(graph: Graph, sources, *, stop_nodes=None) -> BFSResult:
@@ -179,6 +192,8 @@ def bfs_sssp_batched(graph: Graph, sources, *, stop_nodes=None) -> BFSResult:
     as its own stop node is settled (the whole level is still fully
     expanded, so sigma[stop_nodes[b], b] is final) — in that case
     ``levels`` under-reports the eccentricity (see :class:`BFSResult`).
+    ``steps`` counts the loop's expansions and the edge blocks they
+    streamed (:func:`_expand_level`).
     """
     sources = jnp.asarray(sources, jnp.int32)
     b = sources.shape[0]
@@ -192,25 +207,26 @@ def bfs_sssp_batched(graph: Graph, sources, *, stop_nodes=None) -> BFSResult:
         return go
 
     def cond(state):
-        dist, _sigma, level, n_new = state
+        dist, _sigma, level, n_new, _steps = state
         return jnp.any(go_mask(dist, level, n_new))
 
     def body(state):
-        dist, sigma, level, n_new = state
+        dist, sigma, level, n_new, steps = state
         active = go_mask(dist, level, n_new)
-        dist, sigma, n_new2 = _expand_level(graph, dist, sigma, level, active)
+        dist, sigma, n_new2, streamed = _expand_level(graph, dist, sigma,
+                                                      level, active)
         level = jnp.where(active, level + 1, level)
         n_new = jnp.where(active, n_new2, n_new)
-        return dist, sigma, level, n_new
+        return dist, sigma, level, n_new, _count_steps(steps, streamed)
 
-    dist, sigma, _levels, _ = jax.lax.while_loop(
+    dist, sigma, _levels, _, steps = jax.lax.while_loop(
         cond, body, (dist0, sigma0, jnp.zeros((b,), jnp.int32),
-                     jnp.ones((b,), jnp.int32)))
+                     jnp.ones((b,), jnp.int32), jnp.zeros((2,), jnp.int32)))
     # deepest level actually settled per sample (the loop counter
     # overshoots by one when a search exits on an empty frontier); equals
     # ecc(source) iff the search ran to exhaustion
     settled = jnp.max(jnp.where(dist >= 0, dist, 0), axis=0)
-    return BFSResult(dist, sigma, settled)
+    return BFSResult(dist, sigma, settled, steps=steps)
 
 
 def bfs_sssp(graph: Graph, source, *, stop_node=None) -> BFSResult:
@@ -249,6 +265,9 @@ class BidirResult(NamedTuple):
     # (2,) int32 [levels_exchanged, levels_sparse]; None off the sharded
     # lane — same contract as BFSResult.exchange.
     exchange: Optional[jax.Array] = None
+    # (2,) int32 [expansions, streamed_blocks]; None on the sharded lane
+    # — same contract as BFSResult.steps.
+    steps: Optional[jax.Array] = None
 
 
 def bidirectional_bfs_batched(graph: Graph, s, t, *,
@@ -265,7 +284,8 @@ def bidirectional_bfs_batched(graph: Graph, s, t, *,
     pair); the shared while_loop runs until all B searches are done.  On
     an undirected graph the same edge list serves both directions
     (NetworKit stores graph + transpose; for us symmetry makes them
-    identical).
+    identical).  ``steps`` counts the shared loop's expansions (its trip
+    count) and the edge blocks they streamed (:func:`_expand_level`).
     """
     max_levels = graph.n_nodes if max_levels is None else max_levels
     s = jnp.asarray(s, jnp.int32)
@@ -279,13 +299,13 @@ def bidirectional_bfs_batched(graph: Graph, s, t, *,
         met = jnp.any((dist_s >= 0) & (dist_t >= 0), axis=0)
         return (~met) & alive & (rad_s + rad_t < max_levels)
 
-    # state: dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, alive
+    # state: dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, alive, steps
     def cond(st):
-        dist_s, _, rad_s, dist_t, _, rad_t, alive = st
+        dist_s, _, rad_s, dist_t, _, rad_t, alive, _steps = st
         return jnp.any(active_mask(dist_s, rad_s, dist_t, rad_t, alive))
 
     def body(st):
-        dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, alive = st
+        dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, alive, steps = st
         active = active_mask(dist_s, rad_s, dist_t, rad_t, alive)
         fs = jnp.sum((dist_s == rad_s[None, :]).astype(jnp.int32), axis=0)
         ft = jnp.sum((dist_t == rad_t[None, :]).astype(jnp.int32), axis=0)
@@ -295,8 +315,8 @@ def bidirectional_bfs_batched(graph: Graph, s, t, *,
         exp_dist = jnp.where(pick_s[None, :], dist_s, dist_t)
         exp_sigma = jnp.where(pick_s[None, :], sigma_s, sigma_t)
         exp_level = jnp.where(pick_s, rad_s, rad_t)
-        nd, ns, n_new = _expand_level(graph, exp_dist, exp_sigma, exp_level,
-                                      active)
+        nd, ns, n_new, streamed = _expand_level(graph, exp_dist, exp_sigma,
+                                                exp_level, active)
         upd_s = pick_s & active
         upd_t = (~pick_s) & active
         dist_s = jnp.where(upd_s[None, :], nd, dist_s)
@@ -306,12 +326,13 @@ def bidirectional_bfs_batched(graph: Graph, s, t, *,
         sigma_t = jnp.where(upd_t[None, :], ns, sigma_t)
         rad_t = jnp.where(upd_t, rad_t + 1, rad_t)
         alive = jnp.where(active, n_new > 0, alive)
-        return dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, alive
+        return (dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, alive,
+                _count_steps(steps, streamed))
 
     zeros = jnp.zeros((b,), jnp.int32)
     init = (dist_s0, sigma_s0, zeros, dist_t0, sigma_t0, zeros,
-            jnp.ones((b,), jnp.bool_))
-    dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, _alive = \
+            jnp.ones((b,), jnp.bool_), jnp.zeros((2,), jnp.int32))
+    dist_s, sigma_s, rad_s, dist_t, sigma_t, rad_t, _alive, steps = \
         jax.lax.while_loop(cond, body, init)
 
     both = (dist_s >= 0) & (dist_t >= 0)
@@ -325,7 +346,8 @@ def bidirectional_bfs_batched(graph: Graph, s, t, *,
     # loop exits right after the meeting expansion; clamp for safety.
     split = jnp.clip(d - rad_t, 0, rad_s)
     split = jnp.where(connected, split, 0)
-    return BidirResult(dist_s, dist_t, sigma_s, sigma_t, d, split)
+    return BidirResult(dist_s, dist_t, sigma_s, sigma_t, d, split,
+                       steps=steps)
 
 
 def bidirectional_bfs(graph: Graph, s, t, *,

@@ -143,6 +143,11 @@ class EngineEpochStats(NamedTuple):
     # accounting dict from ExchangePlan.epoch_accounting (None off it)
     samples: int = 0
     exchange: Optional[dict] = None
+    # single lane: the epoch's BFS expansions (one per level of its
+    # rounds' shared search loops) and the edge blocks the node-blocked
+    # kernel streamed in them (0 on other routes); 0 on the other lanes
+    bfs_levels: int = 0
+    nb_steps: int = 0
 
 
 class AdaptiveRunResult(NamedTuple):
@@ -234,7 +239,7 @@ def _default_estimators(estimators) -> tuple:
 def draw_fold(graph, key, n_samples: int, *, estimators, ctx: RunContext,
               stream: str = "bidir", batch_size: int = 1, carry=None,
               return_carry: bool = False, axis=None,
-              with_exchange: bool = False):
+              with_exchange: bool = False, with_steps: bool = False):
     """Take exactly ``n_samples`` new samples, folding ONE shared draw
     stream through every estimator's ``accumulate`` hook.
 
@@ -265,6 +270,11 @@ def draw_fold(graph, key, n_samples: int, *, estimators, ctx: RunContext,
     never the key stream — so the counts/tau computation is the same
     program with or without it (the bit-parity contract above is
     untouched; the counters are dead code until observed).
+
+    ``with_steps`` likewise appends the summed (2,) [expansions,
+    streamed_blocks] work count of the rounds' replicated BFS runs
+    (``repro.core.bfs`` ``BFSResult.steps``; zeros on streams that keep
+    none), after the exchange tally when both are asked for.
     """
     batch_size = max(1, min(int(batch_size), int(n_samples)))
     rounds = -(-n_samples // batch_size)
@@ -309,9 +319,14 @@ def draw_fold(graph, key, n_samples: int, *, estimators, ctx: RunContext,
         else:
             state = (counts, tau)
         out = jnp.sum((ps.valid & keep).astype(jnp.int32))
+        extra = ()
         if with_exchange:
-            return state, (out, ps.exchange)
-        return state, out
+            extra += (ps.exchange,)
+        if with_steps:
+            steps = getattr(ps, "steps", None)
+            extra += (jnp.zeros((2,), jnp.int32) if steps is None
+                      else steps,)
+        return state, ((out,) + extra if extra else out)
 
     if carry is None:
         counts0, tau0 = jnp.zeros((C, v1), jnp.float32), jnp.int32(0)
@@ -324,16 +339,13 @@ def draw_fold(graph, key, n_samples: int, *, estimators, ctx: RunContext,
     keys = jax.random.split(key, rounds)
     offsets = jnp.arange(rounds, dtype=jnp.int32) * batch_size
     state, outs = jax.lax.scan(step, init, (keys, offsets))
-    xch = jnp.sum(outs[1], axis=0) if with_exchange else None
+    tail = (tuple(jnp.sum(o, axis=0) for o in outs[1:])
+            if with_exchange or with_steps else ())
     if return_carry:
         counts, tau, sur_counts, sur_tau = state
-        if with_exchange:
-            return (counts, tau), (sur_counts, sur_tau), xch
-        return (counts, tau), (sur_counts, sur_tau)
+        return ((counts, tau), (sur_counts, sur_tau)) + tail
     counts, tau = state
-    if with_exchange:
-        return counts, tau, xch
-    return counts, tau
+    return (counts, tau) + tail
 
 
 def _check_all(estimators, offsets, agg_counts, agg_tau, params,
@@ -693,24 +705,27 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
             # surplus reuse: the masked tail of the previous epoch's
             # last round seeds this epoch's frame (valid i.i.d. samples;
             # tau counts them, so every estimator stays exact)
-            (c, t), (sc, st) = draw_fold(g, k, n0, batch_size=bsz,
-                                         estimators=estimators, ctx=ctx,
-                                         stream=stream,
-                                         carry=(sur_c, sur_t),
-                                         return_carry=True)
+            (c, t), (sc, st), steps = draw_fold(
+                g, k, n0, batch_size=bsz, estimators=estimators, ctx=ctx,
+                stream=stream, carry=(sur_c, sur_t), return_carry=True,
+                with_steps=True)
             new_c = jnp.zeros(
                 (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
             done, mf, mg = _check_all(estimators, offsets, agg_c, agg_t,
                                       params, ctx)
-            return agg_c, agg_t, new_c, t, sc, st, done, mf, mg
+            return agg_c, agg_t, new_c, t, sc, st, done, mf, mg, steps
 
         # jit lowers one program per mix of committed and uncommitted
         # arguments; the step's outputs are committed once the graph is,
         # the initial (or a restored) state is not: commit the state to
         # the graph's device, so every epoch runs the same program
         dev = graph.src.sharding
-        return lambda state, ke: epoch_step(
-            graph, params, *jax.device_put(state, dev), ke)
+
+        def run(state, ke):
+            out = epoch_step(graph, params, *jax.device_put(state, dev), ke)
+            return out[:9] + (None, out[9])   # no exchange tally; steps
+
+        return run
 
     def make_flush(ctx):
         # association matches the PR 1-6 final flush exactly:
@@ -930,6 +945,17 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     vertex-sharded lane: the mesh samples cooperatively; its device
     count must equal ``pg.n_shards``).
 
+    The single lane's frontier route follows the graph it is handed
+    (``bfs.frontier_route``, reported as ``route``): on a TPU the flat
+    kernel where the whole (V+1, B) state fits VMEM, else the
+    node-blocked kernel where the caller attached a CSC layout
+    (``with_csc_layout``), else the XLA reference; off the TPU always
+    the XLA reference.  The lane attaches no layout itself: on a
+    256 x 256 lattice, the graph class that kernel targets, the XLA
+    route ran a job faster (PERF.md).  Each epoch counts its BFS
+    expansions and the edge blocks the node-blocked kernel streamed
+    (``EngineEpochStats.bfs_levels``/``nb_steps``).
+
     Explicitly passed ``eps``/``delta`` take precedence over ``config``;
     left as ``None`` they fall back to the config's values
     (``AdaptiveConfig`` defaults 0.01 / 0.1).  ``stream=None`` picks
@@ -1078,12 +1104,13 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     c_samp = host_counter_totals()
     try:
         while not stopped.all() and epoch < cfg.max_epochs:
-            with telemetry.span("phase.epoch", epoch=epoch + 1):
+            with telemetry.span("phase.epoch", epoch=epoch + 1) as span:
                 te = time.perf_counter()
                 k, ke = jax.random.split(k)
                 out = epoch_run(state, ke)
                 state, (done, mf, mg) = out[:6], out[6:9]
                 xch = out[9] if len(out) > 9 else None
+                steps = out[10] if len(out) > 10 else None
                 epoch += 1
                 if on_epoch is not None:
                     # supervision point: runs before freeze + save so a
@@ -1120,12 +1147,16 @@ def run_adaptive(graph, metrics=("betweenness",), *,
                 n_samples_epoch = int(state[3]) * lane.n_samplers
                 xacct = (xplan.epoch_accounting(int(xch[0]), int(xch[1]))
                          if xch is not None and xplan is not None else None)
+                levels, streamed = 0, 0
+                if steps is not None:
+                    levels, streamed = (int(x) for x in np.asarray(steps))
+                    span.set(bfs_levels=levels, nb_steps=streamed)
                 e_seconds = time.perf_counter() - te
                 stats.append(EngineEpochStats(
                     epoch, int(state[1]),
                     tuple(float(x) for x in np.asarray(mf)),
                     tuple(float(x) for x in np.asarray(mg)),
-                    e_seconds, n_samples_epoch, xacct))
+                    e_seconds, n_samples_epoch, xacct, levels, streamed))
                 if telemetry:
                     telemetry.emit(
                         "epoch.stats", epoch=epoch, tau=int(state[1]),
@@ -1183,9 +1214,16 @@ def run_adaptive(graph, metrics=("betweenness",), *,
         "sampling": host_counter_delta(c_samp, c_samp_end),
         "other": host_counter_delta(c_start, c_diam, c_diam_end, c_cal,
                                     c_cal_end, c_samp, c_samp_end, c_end)}
+    csc = getattr(lane.graph, "csc", None)
     telemetry.emit("run.end", tau=tau_total, n_epochs=epoch,
                    converged=bool(converged.all()), batch_size=bsz,
-                   route=route, **host_counter_delta(c_start, c_end))
+                   route=route, n_nodes=int(lane.graph.n_nodes),
+                   n_edges=int(lane.graph.n_edges),
+                   block_v=csc.block_v if csc is not None else 0,
+                   block_e=csc.block_e if csc is not None else 0,
+                   bfs_levels=sum(s.bfs_levels for s in stats),
+                   nb_steps=sum(s.nb_steps for s in stats),
+                   **host_counter_delta(c_start, c_end))
     return AdaptiveRunResult(
         tuple(reports), tau_total, epoch, bool(converged.all()),
         ctx.vertex_diameter, stats,

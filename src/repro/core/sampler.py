@@ -66,6 +66,9 @@ class PathSample(NamedTuple):
     # (2,) int32 [levels_exchanged, levels_sparse] from the sharded BFS
     # (telemetry observation; None on the replicated lanes)
     exchange: Optional[jax.Array] = None
+    # (2,) int32 [expansions, streamed_blocks] of the round's replicated
+    # BFS (BFSResult.steps); None on the sharded lanes
+    steps: Optional[jax.Array] = None
 
 
 def sample_pairs(key, n_nodes: int, batch: int):
@@ -83,39 +86,63 @@ def sample_pair(key, n_nodes: int):
     return s[0], t[0]
 
 
+def _gumbel(key, shape):
+    """Standard Gumbel noise of ``shape``."""
+    return -jnp.log(-jnp.log(jax.random.uniform(
+        key, shape, minval=1e-20, maxval=1.0)))
+
+
 def _gumbel_argmax(key, logw, axis=-1):
     """Gumbel-max draw along ``axis``; works on (C,) weight vectors and
     on vertex-major (V+1, B) weight matrices (axis=0: one draw per sample
     column)."""
-    g = -jnp.log(-jnp.log(jax.random.uniform(
-        key, logw.shape, minval=1e-20, maxval=1.0)))
-    return jnp.argmax(logw + g, axis=axis)
+    return jnp.argmax(logw + _gumbel(key, logw.shape), axis=axis)
 
 
 def _sample_predecessor(graph: Graph, key, v, level, dist, sigma):
-    """Draw u ~ sigma[u] * [dist[u] == level-1] among neighbors of v."""
+    """Draw u ~ sigma[u] * [dist[u] == level-1] among neighbors of v.
+
+    Weights are used as they are, however small: path counts are
+    float32, and the BFS scales a whole column down each time a level's
+    largest count passes 1e30 (``repro.core.bfs``), so on a graph with
+    hundreds of levels, such as a lattice, the counts of the early
+    levels end far below 1e-30 and those of the earliest flush to 0.
+    Where every predecessor of ``v`` flushed, u is drawn uniformly among
+    them with the same noise, which the weighted draw then leaves
+    unused: the walk still reaches the source along a shortest path,
+    and the key stream is the same either way."""
     start = graph.indptr[v]
     deg = graph.degree[v]
     n_chunks = (deg + _CHUNK - 1) // _CHUNK
 
     def body(i, carry):
-        wsum, chosen, key = carry
+        wsum, chosen, npred, anyone, key = carry
         key, k_in, k_acc = jax.random.split(key, 3)
         nbr = jax.lax.dynamic_slice(graph.indices, (start + i * _CHUNK,),
                                     (_CHUNK,))
         valid = jnp.arange(_CHUNK) < (deg - i * _CHUNK)
-        w = jnp.where(valid & (dist[nbr] == level - 1), sigma[nbr], 0.0)
+        pred = valid & (dist[nbr] == level - 1)
+        w = jnp.where(pred, sigma[nbr], 0.0)
         wc = jnp.sum(w)
-        logw = jnp.where(w > 0, jnp.log(jnp.maximum(w, 1e-30)), _NEG_INF)
-        cand = nbr[_gumbel_argmax(k_in, logw)]
-        accept_p = jnp.where(wc > 0, wc / jnp.maximum(wsum + wc, 1e-30), 0.0)
-        take = jax.random.uniform(k_acc) < accept_p
-        chosen = jnp.where(take, cand, chosen)
-        return wsum + wc, chosen, key
+        pos = w > 0
+        logw = jnp.where(pos, jnp.log(jnp.where(pos, w, 1.0)), _NEG_INF)
+        g = _gumbel(k_in, logw.shape)
+        cand = nbr[jnp.argmax(logw + g)]
+        u = jax.random.uniform(k_acc)
+        accept_p = jnp.where(wc > 0,
+                             wc / jnp.where(wc > 0, wsum + wc, 1.0), 0.0)
+        chosen = jnp.where(u < accept_p, cand, chosen)
+        # the same draw with every predecessor weighted 1
+        nc = jnp.sum(pred.astype(jnp.int32))
+        anyone = jnp.where(
+            u * (npred + nc).astype(jnp.float32) < nc.astype(jnp.float32),
+            nbr[jnp.argmax(jnp.where(pred, g, _NEG_INF))], anyone)
+        return wsum + wc, chosen, npred + nc, anyone, key
 
-    _, chosen, _ = jax.lax.fori_loop(
-        0, n_chunks, body, (jnp.float32(0.0), jnp.int32(-1), key))
-    return chosen
+    wsum, chosen, _, anyone, _ = jax.lax.fori_loop(
+        0, n_chunks, body, (jnp.float32(0.0), jnp.int32(-1), jnp.int32(0),
+                            jnp.int32(-1), key))
+    return jnp.where(wsum > 0, chosen, anyone)
 
 
 def _walk_to_source(graph: Graph, key, start_node, start_level, dist, sigma,
@@ -201,7 +228,8 @@ def sample_path_batched(graph: Graph, key, batch: int) -> PathSample:
     k_pair, k_meet, k_s, k_t = jax.random.split(key, 4)
     s, t = sample_pairs(k_pair, graph.n_nodes, batch)
     res: BidirResult = bidirectional_bfs_batched(graph, s, t)
-    return _finish_paths(graph, k_meet, k_s, k_t, res, batch)
+    out = _finish_paths(graph, k_meet, k_s, k_t, res, batch)
+    return out._replace(steps=res.steps)
 
 
 def sample_path_batched_sharded(pg: PartitionedGraph, key, batch: int, *,
@@ -260,6 +288,9 @@ class ForwardSample(NamedTuple):
     sources: jax.Array   # (B,) int32 — the drawn s
     # (2,) int32 exchange tally from the sharded BFS; None otherwise
     exchange: Optional[jax.Array] = None
+    # (2,) int32 work count of the replicated BFS (PathSample.steps);
+    # None on the sharded and weighted streams
+    steps: Optional[jax.Array] = None
 
 
 def _finish_forward_paths(graph, k_walk, s, t, dist, sigma,
@@ -306,8 +337,9 @@ def sample_path_forward_batched(graph: Graph, key,
     k_pair, k_walk = jax.random.split(key)
     s, t = sample_pairs(k_pair, graph.n_nodes, batch)
     res = bfs_sssp_batched(graph, s)
-    return _finish_forward_paths(graph, k_walk, s, t, res.dist, res.sigma,
-                                 batch)
+    out = _finish_forward_paths(graph, k_walk, s, t, res.dist, res.sigma,
+                                batch)
+    return out._replace(steps=res.steps)
 
 
 def sample_path_forward_batched_sharded(pg: PartitionedGraph, key,

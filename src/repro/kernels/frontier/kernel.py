@@ -396,7 +396,8 @@ def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
                                         interpret: bool | None = None,
                                         block_active=None,
                                         skip_inactive: bool = True,
-                                        wide_state: bool = False):
+                                        wide_state: bool = False,
+                                        return_streamed: bool = False):
     """Two-level frontier expansion over a node-blocked CSC layout.
 
     ``csc`` is a :class:`repro.core.graph.CSCLayout`; ``dist``/``sigma``
@@ -420,7 +421,9 @@ def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
     contract).  ``block_active=None`` with ``skip_inactive=True``
     computes the bitmap from dist/levels; ``skip_inactive=False``
     forces the all-ones bitmap (every cell runs — the lane the
-    occupancy benchmark compares against).
+    occupancy benchmark compares against).  ``return_streamed`` also
+    returns the number of edge blocks the bitmap lets through, the grid
+    steps that stream a block rather than skip it: ``(out, streamed)``.
     """
     v_rows, batch = dist.shape
     levels = jnp.asarray(levels, jnp.int32).reshape(batch)
@@ -489,6 +492,7 @@ def frontier_expand_node_blocked_pallas(csc, dist, sigma, levels, *,
     )(csc.block_nb, csc.block_sb, csc.block_first, block_active,
       _fetch_map(block_active), levels.reshape(1, batch),
       csc.src.reshape(1, -1), csc.dst.reshape(1, -1), dist, sigma)
-    if wide_state:
-        return out                     # local (csc.v_pad, B) tile stack
-    return out if v_rows == v_pad else out[:v_rows]
+    if not wide_state and v_rows != v_pad:
+        out = out[:v_rows]
+    # wide_state: the local (csc.v_pad, B) tile stack
+    return (out, jnp.sum(block_active)) if return_streamed else out

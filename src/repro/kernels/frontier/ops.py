@@ -275,11 +275,11 @@ def select_route(n_nodes: int, batch: int, *, csc=None, shard=None,
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "block_e",
-                                   "skip_inactive"))
+                                   "skip_inactive", "with_streamed"))
 def frontier_expand(src, dst, dist, sigma, level, *, csc=None, shard=None,
                     use_pallas=None, interpret=None,
                     block_e=None, skip_inactive=True,
-                    block_active=None):
+                    block_active=None, with_streamed=False):
     """Route one frontier expansion to the right lane (module docstring).
 
     ``block_active`` (optional, (n_edge_blocks,) int32) is a
@@ -288,6 +288,10 @@ def frontier_expand(src, dst, dist, sigma, level, *, csc=None, shard=None,
     the exact one (or skip nothing under ``skip_inactive=False``).  The
     XLA reference lanes reduce over every edge regardless, so the
     bitmap is ignored there.
+
+    ``with_streamed`` returns ``(contrib, streamed)``: ``streamed`` is
+    the int32 count of edge blocks the node-blocked kernels streamed
+    rather than skipped (their bitmap's ones), 0 on the other lanes.
     """
     # default by backend: compile the Pallas kernels on real TPUs,
     # interpret (and hence auto-route to the XLA ref) elsewhere — this is
@@ -307,37 +311,43 @@ def frontier_expand(src, dst, dist, sigma, level, *, csc=None, shard=None,
                          use_pallas=use_pallas, interpret=interpret,
                          block_e=block_e)
 
+    streamed = jnp.int32(0)
     if route in ("sharded_nb", "sharded_ref"):
         d2 = dist if batched else dist[:, None]
         s2 = sigma if batched else sigma[:, None]
         lv = jnp.asarray(level, jnp.int32).reshape(batch)
         if route == "sharded_nb":
-            out = frontier_expand_node_blocked_pallas(
+            out, streamed = frontier_expand_node_blocked_pallas(
                 shard, d2, s2, lv, interpret=interpret,
                 skip_inactive=skip_inactive, block_active=block_active,
-                wide_state=True)
+                wide_state=True, return_streamed=True)
         else:
             out = frontier_expand_sharded_ref(shard, d2, s2, lv)
-        return out if batched else out[:, 0]
-    if route == "node_blocked":
+        out = out if batched else out[:, 0]
+    elif route == "node_blocked":
         d2 = dist if batched else dist[:, None]
         s2 = sigma if batched else sigma[:, None]
         lv = (jnp.asarray(level, jnp.int32).reshape(batch) if batched
               else jnp.asarray(level, jnp.int32).reshape(1))
-        out = frontier_expand_node_blocked_pallas(
+        out, streamed = frontier_expand_node_blocked_pallas(
             csc, d2, s2, lv, interpret=interpret,
-            skip_inactive=skip_inactive, block_active=block_active)
-        return out if batched else out[:, 0]
-    if route == "flat":
+            skip_inactive=skip_inactive, block_active=block_active,
+            return_streamed=True)
+        out = out if batched else out[:, 0]
+    elif route == "flat":
         if batched:
-            return frontier_expand_batched_pallas(
+            out = frontier_expand_batched_pallas(
                 src, dst, dist, sigma, level, block_e=block_e,
                 interpret=interpret)
-        return frontier_expand_pallas(src, dst, dist, sigma, level,
-                                      block_e=block_e, interpret=interpret)
-    if batched:
-        return frontier_expand_batched_ref(src, dst, dist, sigma, level)
-    return frontier_expand_ref(src, dst, dist, sigma, level)
+        else:
+            out = frontier_expand_pallas(src, dst, dist, sigma, level,
+                                         block_e=block_e,
+                                         interpret=interpret)
+    elif batched:
+        out = frontier_expand_batched_ref(src, dst, dist, sigma, level)
+    else:
+        out = frontier_expand_ref(src, dst, dist, sigma, level)
+    return (out, streamed) if with_streamed else out
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret"))

@@ -208,20 +208,24 @@ class _NullSpan:
     __slots__ = ()
 
     def __enter__(self):
-        return None
+        return self
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **stats):
+        pass
 
 
 _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    """One live span: emits begin on enter, end (with seconds, and an
-    ``error`` field when exiting on an exception) on exit."""
+    """One live span: emits begin on enter, end (with seconds, the
+    stats :meth:`set` left, and an ``error`` field when exiting on an
+    exception) on exit."""
 
-    __slots__ = ("_tel", "name", "fields", "span_id", "_t0")
+    __slots__ = ("_tel", "name", "fields", "span_id", "_t0", "_stats")
 
     def __init__(self, tel: "Telemetry", name: str, fields: dict):
         self._tel = tel
@@ -229,6 +233,11 @@ class _SpanCtx:
         self.fields = fields
         self.span_id = None
         self._t0 = 0.0
+        self._stats = {}
+
+    def set(self, **stats):
+        """Numbers the phase counted, for its end event."""
+        self._stats.update(stats)
 
     def __enter__(self):
         tel = self._tel
@@ -249,7 +258,7 @@ class _SpanCtx:
         if stack and stack[-1] == self.span_id:
             stack.pop()
         t1 = tel._clock()
-        fields = {"name": self.name, "seconds": t1 - self._t0}
+        fields = {**self._stats, "name": self.name, "seconds": t1 - self._t0}
         if exc_type is not None:
             fields["error"] = exc_type.__name__
         parent = stack[-1] if stack else None
@@ -260,26 +269,36 @@ class _SpanCtx:
 
 class _TracedSpan:
     """A span while a profiler trace runs: a ``TraceAnnotation`` of the
-    span's name and fields, closed with the host counters it spent as
-    stats, around the bus span when the bus is on."""
+    span's name and fields, closed with the host counters it spent and
+    the stats :meth:`set` left, around the bus span when the bus is on."""
 
-    __slots__ = ("_ann", "_bus", "_c0")
+    __slots__ = ("_ann", "_bus", "_c0", "_stats")
 
     def __init__(self, tel: "Telemetry", name: str, fields: dict):
         self._ann = TraceAnnotation(name, **fields)
         self._bus = _SpanCtx(tel, name, fields) if tel._enabled else None
         self._c0 = None
+        self._stats = {}
+
+    def set(self, **stats):
+        """Numbers the phase counted, for its annotation's stats."""
+        self._stats.update(stats)
+        if self._bus is not None:
+            self._bus.set(**stats)
 
     def __enter__(self):
         self._ann.__enter__()
         self._c0 = host_counter_totals()
-        return self._bus.__enter__() if self._bus is not None else None
+        if self._bus is not None:
+            self._bus.__enter__()
+        return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._bus is not None:
             self._bus.__exit__(exc_type, exc, tb)
         self._ann.set_metadata(
-            **host_counter_delta(self._c0, host_counter_totals()))
+            **host_counter_delta(self._c0, host_counter_totals()),
+            **self._stats)
         self._ann.__exit__(exc_type, exc, tb)
         return False
 
@@ -335,7 +354,9 @@ class Telemetry:
     def span(self, name: str, **fields):
         """Context manager timing a named phase; spans nest per thread
         (``parent`` ids), and the end event carries the duration.  While
-        a profiler trace runs, the span is also a trace annotation."""
+        a profiler trace runs, the span is also a trace annotation.  The
+        context's ``set(**stats)`` adds numbers the phase counted to its
+        end event and its annotation (a no-op when both are off)."""
         if _profiling():
             return _TracedSpan(self, name, fields)
         if not self._enabled:

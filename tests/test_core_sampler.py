@@ -58,6 +58,32 @@ def test_sample_counts_path_length():
     assert float(jnp.sum(ps.contrib)) == pytest.approx(int(ps.length) - 1)
 
 
+def test_long_lattice_paths_keep_every_inside_vertex():
+    """Past a few hundred levels a lattice's path counts span more than
+    float32's range: the early levels' counts end below 1e-30 or flush to
+    0.  Every walk still reaches its source, so a sample of two vertices
+    d apart holds d - 1 inside vertices."""
+    from repro.core import grid_graph
+    from repro.core.bfs import bidirectional_bfs_batched
+    from repro.core.sampler import _finish_paths
+
+    g = grid_graph(80, 300)
+    n = g.n_nodes
+    s = jnp.asarray([0, 0, 299, 40 * 300], jnp.int32)
+    t = jnp.asarray([n - 1, n - 150, n - 300, 40 * 300 + 299], jnp.int32)
+
+    @jax.jit
+    def inside(key):
+        res = bidirectional_bfs_batched(g, s, t)
+        ps = _finish_paths(g, *jax.random.split(key, 3), res, 4)
+        return res.d, ps.contrib.sum(axis=1)
+
+    for k in range(3):
+        d, got = inside(jax.random.PRNGKey(k))
+        assert int(d.max()) > 300
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(d) - 1)
+
+
 def test_omega_monotonic():
     w1 = float(compute_omega(10, 0.05, 0.1))
     w2 = float(compute_omega(10, 0.01, 0.1))

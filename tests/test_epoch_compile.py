@@ -33,9 +33,11 @@ jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 from repro.core.adaptive import AdaptiveConfig
 from repro.core.engine import run_adaptive
 from repro.core.graph import rmat_graph
+from repro.runtime import RingSink, Telemetry
 g = rmat_graph(9, 8, seed=1)
 if sys.argv[2] == "committed":
     g = jax.device_put(g, jax.devices()[0])
+# the second call with the bus on: it reads the epochs' work counters
 cfg = AdaptiveConfig(eps=0.01, delta=0.1, n0_base=64, max_epochs=2)
 lowered = []
 
@@ -47,14 +49,18 @@ jax.monitoring.register_event_duration_secs_listener(listen)
 out = []
 for k in (1, 2):
     del lowered[:]
-    res = run_adaptive(g, config=cfg, key=jax.random.PRNGKey(k))
+    tel = (Telemetry([RingSink()], validate=True)
+           if k == 2 and sys.argv[2] == "telemetry" else None)
+    res = run_adaptive(g, config=cfg, key=jax.random.PRNGKey(k),
+                       telemetry=tel)
     out.append(dict(res.host_counters,
-                    epoch_lowerings=lowered.count("jit(epoch_step)")))
+                    epoch_lowerings=lowered.count("jit(epoch_step)"),
+                    bfs_levels=[s.bfs_levels for s in res.stats]))
 print(json.dumps(out))
 """
 
 
-@pytest.mark.parametrize("placement", ["default", "committed"])
+@pytest.mark.parametrize("placement", ["default", "committed", "telemetry"])
 def test_second_key_loads_the_epoch_program_from_the_cache(tmp_path,
                                                            placement):
     """Two calls on one graph at different keys, in a fresh interpreter
@@ -62,7 +68,9 @@ def test_second_key_loads_the_epoch_program_from_the_cache(tmp_path,
     sampling phase compiles nothing and loads from the cache.  Each call
     lowers the epoch step once: a graph committed to its device
     (``jax.device_put``) commits the epochs' outputs, which must not
-    make the second epoch a second program."""
+    make the second epoch a second program.  With the telemetry bus on
+    in the second call only, that call still loads the first call's
+    program: the work counters are computed either way."""
     env = dict(os.environ)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["PYTHONPATH"] = os.pathsep.join(
@@ -77,6 +85,7 @@ def test_second_key_loads_the_epoch_program_from_the_cache(tmp_path,
     assert first["epoch_lowerings"] == second["epoch_lowerings"] == 1
     assert second["sampling"]["compiles"] == 0, second
     assert second["sampling"]["cache_hits"] >= 1, second
+    assert all(n > 0 for n in first["bfs_levels"] + second["bfs_levels"])
 
 
 @pytest.fixture(scope="module")
