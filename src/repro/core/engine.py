@@ -63,6 +63,7 @@ from .estimators.base import DrawBatch, Estimator, MetricReport, RunContext
 from .bfs import frontier_route
 from .graph import Graph
 from .partition import PartitionedGraph, exchange_plan
+from .programs import ProgramTally, program
 from .sampler import (sample_path_batched, sample_path_batched_sharded,
                       sample_path_forward_batched,
                       sample_path_forward_batched_sharded,
@@ -656,27 +657,82 @@ def _sharded_diameter(pg: PartitionedGraph, mesh, n_sweeps: int):
     return vd, pg
 
 
-def _replicated_phase1(graph: Graph, stream: str, n_sweeps: int):
-    """Phase 1 on a replicated graph: the vertex-diameter bound and the
-    distance cap (0.0 unweighted).  Its program is a named function
-    (``jit_phase1_diameter`` in a device trace), not a ``partial``,
-    which lowers as ``jit__unknown``."""
-    if stream == "weighted":
+# The single lane's programs: builders for ``repro.core.programs``, so
+# each is traced and lowered once per static configuration and reused by
+# every later call.  Every array (the graph, the keys, the stop-rule
+# params, the state) is an argument; the draw function is a static
+# value, read where the lane runs, so a program built from another draw
+# function is another program.
+
+def _phase1_program(weighted: bool, n_sweeps: int):
+    """Phase 1 on a replicated graph: the vertex-diameter bound, and on
+    a weighted graph the weighted-diameter bound too.  Its program is a
+    named function (``jit_phase1_diameter`` in a device trace), not a
+    ``partial``, which lowers as ``jit__unknown``."""
+    if weighted:
         # weighted phase 1: hop-based VD bound for omega PLUS the
         # weighted-diameter bound distance-normalizing estimators use
         # as their cap (RunContext.distance_cap)
         def phase1_weighted_diameter(g):
             return estimate_diameter_weighted(g, n_sweeps=n_sweeps)
-        wdiam = jax.jit(phase1_weighted_diameter)(graph)
-        return int(wdiam.vertex_diameter), float(wdiam.upper)
+        return phase1_weighted_diameter
 
     def phase1_diameter(g):
         return estimate_diameter(g, n_sweeps=n_sweeps)
-    return int(jax.jit(phase1_diameter)(graph).vertex_diameter), 0.0
+    return phase1_diameter
+
+
+def _calibration_program(draw, estimators, stream: str, ctx: RunContext,
+                         n_samples: int, bsz: int):
+    def calibration_draws(g, k):
+        return draw(g, k, n_samples=n_samples, batch_size=bsz,
+                    estimators=estimators, ctx=ctx, stream=stream)
+    return calibration_draws
+
+
+def _epoch_program(draw, estimators, stream: str, ctx: RunContext, n0: int,
+                   bsz: int):
+    C = total_channels(estimators)
+    offsets = _channel_offsets(estimators)
+    v_pad = _pad_len(ctx.n_nodes, 1)
+
+    def epoch_step(g, params, agg_c, agg_t, fr_c, fr_t, sur_c, sur_t, k):
+        agg_c = agg_c + fr_c
+        agg_t = agg_t + fr_t
+        # surplus reuse: the masked tail of the previous epoch's last
+        # round seeds this epoch's frame (valid i.i.d. samples; tau
+        # counts them, so every estimator stays exact)
+        (c, t), (sc, st), steps = draw(
+            g, k, n0, batch_size=bsz, estimators=estimators, ctx=ctx,
+            stream=stream, carry=(sur_c, sur_t), return_carry=True,
+            with_steps=True)
+        new_c = jnp.zeros(
+            (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
+        done, mf, mg = _check_all(estimators, offsets, agg_c, agg_t,
+                                  params, ctx)
+        return agg_c, agg_t, new_c, t, sc, st, done, mf, mg, steps
+    return epoch_step
+
+
+def _flush_program():
+    # replicated frames; the association of the single-estimator flush
+    # exactly: (agg + frame) first, then the surplus tail onto [: V+1]
+    def flush(agg_c, agg_t, fr_c, fr_t, sur_c, sur_t):
+        c = (agg_c + fr_c).at[:, : sur_c.shape[1]].add(sur_c)
+        return c, agg_t + fr_t + sur_t
+    return flush
+
+
+def _replicated_phase1(graph: Graph, stream: str, n_sweeps: int):
+    """Phase 1 on a replicated graph: the vertex-diameter bound and the
+    distance cap (0.0 unweighted)."""
+    weighted = stream == "weighted"
+    est = program(_phase1_program, weighted, n_sweeps)(graph)
+    return int(est.vertex_diameter), float(est.upper) if weighted else 0.0
 
 
 def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
-                 stream: str, C: int, offsets):
+                 stream: str, C: int):
     ns = SimpleNamespace()
     v_pad = _pad_len(graph.n_nodes, 1)
     v1 = graph.n_nodes + 1
@@ -687,34 +743,12 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
     ns.graph, ns.v_pad, ns.n_samplers, ns.shardings = graph, v_pad, 1, None
 
     def calibrate(k_cal, bsz, ctx):
-        def calibration_draws(g, k):
-            return draw_fold(g, k, n_samples=cfg.calib_samples_per_device,
-                             batch_size=bsz, estimators=estimators, ctx=ctx,
-                             stream=stream)
-        return jax.jit(calibration_draws)(graph, k_cal)
+        return program(_calibration_program, draw_fold, estimators, stream,
+                       ctx, cfg.calib_samples_per_device, bsz)(graph, k_cal)
 
     def make_epoch(params, ctx, n0, bsz):
-        # the graph and the stop-rule params are arguments, not closure
-        # constants: the program is then the same at every key on one
-        # graph, so a persistent compile cache can serve it
-        @jax.jit
-        def epoch_step(g, params, agg_c, agg_t, fr_c, fr_t, sur_c, sur_t,
-                       k):
-            agg_c = agg_c + fr_c
-            agg_t = agg_t + fr_t
-            # surplus reuse: the masked tail of the previous epoch's
-            # last round seeds this epoch's frame (valid i.i.d. samples;
-            # tau counts them, so every estimator stays exact)
-            (c, t), (sc, st), steps = draw_fold(
-                g, k, n0, batch_size=bsz, estimators=estimators, ctx=ctx,
-                stream=stream, carry=(sur_c, sur_t), return_carry=True,
-                with_steps=True)
-            new_c = jnp.zeros(
-                (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
-            done, mf, mg = _check_all(estimators, offsets, agg_c, agg_t,
-                                      params, ctx)
-            return agg_c, agg_t, new_c, t, sc, st, done, mf, mg, steps
-
+        epoch_step = program(_epoch_program, draw_fold, estimators, stream,
+                             ctx, n0, bsz)
         # jit lowers one program per mix of committed and uncommitted
         # arguments; the step's outputs are committed once the graph is,
         # the initial (or a restored) state is not: commit the state to
@@ -728,14 +762,7 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
         return run
 
     def make_flush(ctx):
-        # association matches the PR 1-6 final flush exactly:
-        # (agg + frame) first, then the surplus tail onto [: V+1]
-        @jax.jit
-        def flush(agg_c, agg_t, fr_c, fr_t, sur_c, sur_t):
-            c = (agg_c + fr_c).at[:, :v1].add(sur_c)
-            return c, agg_t + fr_t + sur_t
-
-        return lambda state: flush(*state)
+        return lambda state: program(_flush_program)(*state)
 
     def init_state(ctx):
         return (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
@@ -748,7 +775,7 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
 
 
 def _spmd_lane(graph: Graph, mesh: Mesh, cfg: AdaptiveConfig, estimators,
-               stream: str, C: int, offsets):
+               stream: str, C: int):
     ns = SimpleNamespace()
     all_axes = tuple(mesh.axis_names)
     n_dev = int(np.prod(mesh.devices.shape))
@@ -835,7 +862,7 @@ def _spmd_lane(graph: Graph, mesh: Mesh, cfg: AdaptiveConfig, estimators,
 
 
 def _sharded_lane(pg: PartitionedGraph, mesh: Mesh, cfg: AdaptiveConfig,
-                  estimators, stream: str, C: int, offsets):
+                  estimators, stream: str, C: int):
     ns = SimpleNamespace()
     all_axes = tuple(mesh.axis_names)
     n_dev = int(np.prod(mesh.devices.shape))
@@ -899,13 +926,7 @@ def _sharded_lane(pg: PartitionedGraph, mesh: Mesh, cfg: AdaptiveConfig,
         return lambda state, ke: epoch_jit(pg, params, *state, ke)
 
     def make_flush(ctx):
-        # frames are replicated: plain adds, PR 1-6 association
-        @jax.jit
-        def flush(agg_c, agg_t, fr_c, fr_t, sur_c, sur_t):
-            c = (agg_c + fr_c).at[:, :v1].add(sur_c)
-            return c, agg_t + fr_t + sur_t
-
-        return lambda state: flush(*state)
+        return lambda state: program(_flush_program)(*state)
 
     def init_state(ctx):
         return (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
@@ -990,245 +1011,262 @@ def run_adaptive(graph, metrics=("betweenness",), *,
 
     ``phase_seconds`` and ``host_counters`` split the call into phases:
     ``diameter`` (phase 1, ending on the host read of the bound; its
-    counters include the lane setup around it), ``calibration`` (calibration draws and stop-rule params,
-    ending on ``jax.block_until_ready`` of both, so their device work is
-    timed here and not in the first epoch; the epoch program takes the
-    params as arguments, so it is the same at every key on one graph) and
-    ``sampling`` (the epochs, each ending on the host read of its stop
-    flags, and the final flush); ``host_counters`` adds ``other``, the
-    rest of the call.  Each phase counts the backend compiles, cache
-    loads and traces it spent (``repro.runtime.telemetry``
-    ``host_counter_delta``); ``run.end`` carries their totals.  While a
-    ``jax.profiler`` trace runs, the spans are trace annotations too.
+    counters include the lane setup around it), ``calibration``
+    (calibration draws and stop-rule params, ending on
+    ``jax.block_until_ready`` of both, so their device work is timed
+    here and not in the first epoch) and ``sampling`` (the epochs, each
+    ending on the host read of its stop flags, and the final flush);
+    ``host_counters`` adds ``other``, the rest of the call.  Each phase
+    counts the backend compiles, cache loads and traces it spent
+    (``repro.runtime.telemetry`` ``host_counter_delta``); ``run.end``
+    carries their totals.  While a ``jax.profiler`` trace runs, the
+    spans are trace annotations too.
+
+    The single lane's programs (phase 1, the calibration draws, the
+    stop-rule params, the epoch step, the flush) are built once per
+    static configuration and kept (``repro.core.programs``): the graph,
+    the keys, the stop-rule params and the state are their arguments.  A
+    repeat call with the same graph shape and configuration, at any key
+    and on any graph of that shape, reuses them and traces and lowers
+    nothing.  ``run.end`` carries ``programs_built`` and
+    ``programs_reused``: of the cached programs the call ran, how many
+    were built in it and how many came from the cache.  The SPMD and
+    sharded lanes still build their calibration and epoch programs in
+    every call; those are not counted.
     """
     from repro.runtime.telemetry import (host_counter_delta,
                                          host_counter_totals,
                                          resolve_telemetry)
     c_start = host_counter_totals()
-    telemetry = resolve_telemetry(telemetry)
-    cfg = config if config is not None else AdaptiveConfig()
-    overrides = {}
-    if eps is not None:
-        overrides["eps"] = eps
-    if delta is not None:
-        overrides["delta"] = delta
-    if overrides:
-        cfg = dataclasses.replace(cfg, **overrides)
-    if key is None:
-        key = jax.random.PRNGKey(0)
-    estimators = resolve_estimators(metrics)
-    stream = resolve_stream(estimators, stream)
-    C = total_channels(estimators)
-    offsets = _channel_offsets(estimators)
-    E = len(estimators)
-    # which metric owns each channel row (frozen-snapshot row masks)
-    row_metric = np.concatenate(
-        [np.full(est.n_channels, i) for i, est in enumerate(estimators)])
+    with ProgramTally() as used:
+        telemetry = resolve_telemetry(telemetry)
+        cfg = config if config is not None else AdaptiveConfig()
+        overrides = {}
+        if eps is not None:
+            overrides["eps"] = eps
+        if delta is not None:
+            overrides["delta"] = delta
+        if overrides:
+            cfg = dataclasses.replace(cfg, **overrides)
+        if key is None:
+            key = jax.random.PRNGKey(0)
+        estimators = resolve_estimators(metrics)
+        stream = resolve_stream(estimators, stream)
+        C = total_channels(estimators)
+        offsets = _channel_offsets(estimators)
+        E = len(estimators)
+        # which metric owns each channel row (frozen-snapshot row masks)
+        row_metric = np.concatenate(
+            [np.full(est.n_channels, i) for i, est in enumerate(estimators)])
 
-    # ---- lane selection + phase 1 (diameter) ---------------------------
-    if isinstance(graph, PartitionedGraph):
-        if mesh is None:
-            raise ValueError(
-                "a PartitionedGraph needs the mesh its shards map onto "
-                "(mesh=...); use a plain Graph for the single-device lane")
-        lane_name = "sharded"
-    elif mesh is None or int(np.prod(mesh.devices.shape)) == 1:
-        lane_name = "single"
-    else:
-        lane_name = "spmd"
-    telemetry.emit("run.start", lane=lane_name,
-                   metrics=[e.name for e in estimators],
-                   n_nodes=int(graph.n_nodes), eps=float(cfg.eps),
-                   delta=float(cfg.delta))
-    c_diam = host_counter_totals()
-    with telemetry.span("phase.diameter"):
-        if lane_name == "sharded":
-            lane = _sharded_lane(graph, mesh, cfg, estimators, stream, C,
-                                 offsets)
-        elif lane_name == "single":
-            lane = _single_lane(graph, cfg, estimators, stream, C, offsets)
+        # ---- lane selection + phase 1 (diameter) ---------------------------
+        if isinstance(graph, PartitionedGraph):
+            if mesh is None:
+                raise ValueError(
+                    "a PartitionedGraph needs the mesh its shards map onto "
+                    "(mesh=...); use a plain Graph for the single-device lane")
+            lane_name = "sharded"
+        elif mesh is None or int(np.prod(mesh.devices.shape)) == 1:
+            lane_name = "single"
         else:
-            lane = _spmd_lane(graph, mesh, cfg, estimators, stream, C,
-                              offsets)
-    c_diam_end = host_counter_totals()
+            lane_name = "spmd"
+        telemetry.emit("run.start", lane=lane_name,
+                       metrics=[e.name for e in estimators],
+                       n_nodes=int(graph.n_nodes), eps=float(cfg.eps),
+                       delta=float(cfg.delta))
+        c_diam = host_counter_totals()
+        with telemetry.span("phase.diameter"):
+            if lane_name == "sharded":
+                lane = _sharded_lane(graph, mesh, cfg, estimators, stream,
+                                     C)
+            elif lane_name == "single":
+                lane = _single_lane(graph, cfg, estimators, stream, C)
+            else:
+                lane = _spmd_lane(graph, mesh, cfg, estimators, stream, C)
+        c_diam_end = host_counter_totals()
 
-    ctx = RunContext(int(lane.graph.n_nodes), lane.vd, lane.dist_cap)
-    bsz = resolve_sample_batch_size(cfg.sample_batch_size, ctx.n_nodes,
-                                    ctx.vertex_diameter)
-    route = frontier_route(lane.graph, bsz, weighted=stream == "weighted")
-    # the static per-level price list for the sharded lane's exchange
-    # tally (host-side observation only)
-    xplan = (exchange_plan(lane.graph, bsz)
-             if isinstance(lane.graph, PartitionedGraph) else None)
+        ctx = RunContext(int(lane.graph.n_nodes), lane.vd, lane.dist_cap)
+        bsz = resolve_sample_batch_size(cfg.sample_batch_size, ctx.n_nodes,
+                                        ctx.vertex_diameter)
+        route = frontier_route(lane.graph, bsz, weighted=stream == "weighted")
+        # the static per-level price list for the sharded lane's exchange
+        # tally (host-side observation only)
+        xplan = (exchange_plan(lane.graph, bsz)
+                 if isinstance(lane.graph, PartitionedGraph) else None)
 
-    # ---- phase 2: calibration + per-estimator stop-rule params ---------
-    t0 = time.perf_counter()
-    c_cal = host_counter_totals()
-    with telemetry.span("phase.calibration"):
-        key, k_cal = jax.random.split(key)
-        counts0, tau0 = lane.calibrate(k_cal, bsz, ctx)
-        params = tuple(
-            est.make_params(lane.graph, ctx, cfg.eps, cfg.delta,
-                            counts0[off: off + est.n_channels], tau0)
-            for est, off in zip(estimators, offsets))
-        jax.block_until_ready((counts0, tau0, params))
-    t_cal = time.perf_counter() - t0
-    c_cal_end = host_counter_totals()
+        # ---- phase 2: calibration + per-estimator stop-rule params ---------
+        t0 = time.perf_counter()
+        c_cal = host_counter_totals()
+        with telemetry.span("phase.calibration"):
+            key, k_cal = jax.random.split(key)
+            counts0, tau0 = lane.calibrate(k_cal, bsz, ctx)
+            params = tuple(
+                est.make_params(lane.graph, ctx, cfg.eps, cfg.delta,
+                                counts0[off: off + est.n_channels], tau0)
+                for est, off in zip(estimators, offsets))
+            jax.block_until_ready((counts0, tau0, params))
+        t_cal = time.perf_counter() - t0
+        c_cal_end = host_counter_totals()
 
-    # ---- phase 3: the adaptive loop ------------------------------------
-    n0 = epoch_length(lane.n_samplers, base=cfg.n0_base,
-                      exponent=cfg.n0_exponent)
-    epoch_run = lane.make_epoch(params, ctx, n0, bsz)
-    flush = lane.make_flush(ctx)
+        # ---- phase 3: the adaptive loop ------------------------------------
+        n0 = epoch_length(lane.n_samplers, base=cfg.n0_base,
+                          exponent=cfg.n0_exponent)
+        epoch_run = lane.make_epoch(params, ctx, n0, bsz)
+        flush = lane.make_flush(ctx)
 
-    state = lane.init_state(ctx)
-    frozen_c = jnp.zeros((C, lane.v_pad), jnp.float32)
-    frozen_tau = jnp.zeros((E,), jnp.int32)
-    stop_epoch = jnp.full((E,), -1, jnp.int32)
-    k = key
-    epoch = 0
-    ckpt = None
-    if checkpoint_dir:
-        schema = frame_schema_id(est.schema for est in estimators)
-        ckpt = _EngineCheckpointer(checkpoint_dir, checkpoint_every,
-                                   schema, shardings=lane.shardings,
-                                   telemetry=telemetry)
-        full, epoch, _done = ckpt.restore_state(
-            state + (frozen_c, frozen_tau, stop_epoch, k))
-        state = full[:6]
-        frozen_c, frozen_tau, stop_epoch, k = full[6:]
-    stopped = np.asarray(stop_epoch) >= 0
-    stats = []
-    last_flush = None
-    t0 = time.perf_counter()
-    c_samp = host_counter_totals()
-    try:
-        while not stopped.all() and epoch < cfg.max_epochs:
-            with telemetry.span("phase.epoch", epoch=epoch + 1) as span:
-                te = time.perf_counter()
-                k, ke = jax.random.split(k)
-                out = epoch_run(state, ke)
-                state, (done, mf, mg) = out[:6], out[6:9]
-                xch = out[9] if len(out) > 9 else None
-                steps = out[10] if len(out) > 10 else None
-                epoch += 1
-                if on_epoch is not None:
-                    # supervision point: runs before freeze + save so a
-                    # refused (or replaced) epoch never reaches a snapshot
-                    # or the checkpoint store.  Pending async publishes are
-                    # flushed first: the hook (and any disk fault it
-                    # injects) must observe a settled on-disk state, and a
-                    # swallowed publish error surfaces at the epoch after
-                    # its save, not at the end of the run
+        state = lane.init_state(ctx)
+        frozen_c = jnp.zeros((C, lane.v_pad), jnp.float32)
+        frozen_tau = jnp.zeros((E,), jnp.int32)
+        stop_epoch = jnp.full((E,), -1, jnp.int32)
+        k = key
+        epoch = 0
+        ckpt = None
+        if checkpoint_dir:
+            schema = frame_schema_id(est.schema for est in estimators)
+            ckpt = _EngineCheckpointer(checkpoint_dir, checkpoint_every,
+                                       schema, shardings=lane.shardings,
+                                       telemetry=telemetry)
+            full, epoch, _done = ckpt.restore_state(
+                state + (frozen_c, frozen_tau, stop_epoch, k))
+            state = full[:6]
+            frozen_c, frozen_tau, stop_epoch, k = full[6:]
+        stopped = np.asarray(stop_epoch) >= 0
+        stats = []
+        last_flush = None
+        t0 = time.perf_counter()
+        c_samp = host_counter_totals()
+        try:
+            while not stopped.all() and epoch < cfg.max_epochs:
+                with telemetry.span("phase.epoch", epoch=epoch + 1) as span:
+                    te = time.perf_counter()
+                    k, ke = jax.random.split(k)
+                    out = epoch_run(state, ke)
+                    state, (done, mf, mg) = out[:6], out[6:9]
+                    xch = out[9] if len(out) > 9 else None
+                    steps = out[10] if len(out) > 10 else None
+                    epoch += 1
+                    if on_epoch is not None:
+                        # supervision point: runs before freeze + save so a
+                        # refused (or replaced) epoch never reaches a snapshot
+                        # or the checkpoint store.  Pending async publishes are
+                        # flushed first: the hook (and any disk fault it
+                        # injects) must observe a settled on-disk state, and a
+                        # swallowed publish error surfaces at the epoch after
+                        # its save, not at the end of the run
+                        if ckpt is not None:
+                            ckpt.wait()
+                        replacement = on_epoch(epoch, state)
+                        if replacement is not None:
+                            state = tuple(replacement)
+                    newly = np.asarray(done) & ~stopped
+                    if newly.any():
+                        # freeze the newly stopped metrics' deciding snapshot:
+                        # the flush of THIS epoch's state — identical to what
+                        # each metric's single-run result would be at the same
+                        # seed (f/g are non-monotone, so re-reading a later
+                        # snapshot would not reproduce the single-run decision)
+                        last_flush = flush(state)
+                        fl_c, fl_t = last_flush
+                        rows = jnp.asarray(
+                            np.isin(row_metric, np.nonzero(newly)[0]))
+                        newly_j = jnp.asarray(newly)
+                        frozen_c = jnp.where(rows[:, None], fl_c, frozen_c)
+                        frozen_tau = jnp.where(newly_j, fl_t, frozen_tau)
+                        stop_epoch = jnp.where(newly_j, jnp.int32(epoch),
+                                               stop_epoch)
+                        stopped = stopped | newly
+                    # host-side publication of the epoch's counters, at the
+                    # on_epoch boundary where the state is already materialized
+                    n_samples_epoch = int(state[3]) * lane.n_samplers
+                    xacct = (
+                        xplan.epoch_accounting(int(xch[0]), int(xch[1]))
+                        if xch is not None and xplan is not None else None)
+                    levels, streamed = 0, 0
+                    if steps is not None:
+                        levels, streamed = (int(x) for x in np.asarray(steps))
+                        span.set(bfs_levels=levels, nb_steps=streamed)
+                    e_seconds = time.perf_counter() - te
+                    stats.append(EngineEpochStats(
+                        epoch, int(state[1]),
+                        tuple(float(x) for x in np.asarray(mf)),
+                        tuple(float(x) for x in np.asarray(mg)),
+                        e_seconds, n_samples_epoch, xacct, levels, streamed))
+                    if telemetry:
+                        telemetry.emit(
+                            "epoch.stats", epoch=epoch, tau=int(state[1]),
+                            samples=n_samples_epoch, seconds=e_seconds,
+                            max_f=[float(x) for x in np.asarray(mf)],
+                            max_g=[float(x) for x in np.asarray(mg)])
+                        if xacct is not None:
+                            telemetry.emit("exchange.epoch", epoch=epoch,
+                                           **xacct)
                     if ckpt is not None:
-                        ckpt.wait()
-                    replacement = on_epoch(epoch, state)
-                    if replacement is not None:
-                        state = tuple(replacement)
-                newly = np.asarray(done) & ~stopped
-                if newly.any():
-                    # freeze the newly stopped metrics' deciding snapshot:
-                    # the flush of THIS epoch's state — identical to what
-                    # each metric's single-run result would be at the same
-                    # seed (f/g are non-monotone, so re-reading a later
-                    # snapshot would not reproduce the single-run decision)
-                    last_flush = flush(state)
-                    fl_c, fl_t = last_flush
-                    rows = jnp.asarray(
-                        np.isin(row_metric, np.nonzero(newly)[0]))
-                    newly_j = jnp.asarray(newly)
-                    frozen_c = jnp.where(rows[:, None], fl_c, frozen_c)
-                    frozen_tau = jnp.where(newly_j, fl_t, frozen_tau)
-                    stop_epoch = jnp.where(newly_j, jnp.int32(epoch),
-                                           stop_epoch)
-                    stopped = stopped | newly
-                # host-side publication of the epoch's counters, at the
-                # on_epoch boundary where the state is already materialized
-                n_samples_epoch = int(state[3]) * lane.n_samplers
-                xacct = (xplan.epoch_accounting(int(xch[0]), int(xch[1]))
-                         if xch is not None and xplan is not None else None)
-                levels, streamed = 0, 0
-                if steps is not None:
-                    levels, streamed = (int(x) for x in np.asarray(steps))
-                    span.set(bfs_levels=levels, nb_steps=streamed)
-                e_seconds = time.perf_counter() - te
-                stats.append(EngineEpochStats(
-                    epoch, int(state[1]),
-                    tuple(float(x) for x in np.asarray(mf)),
-                    tuple(float(x) for x in np.asarray(mg)),
-                    e_seconds, n_samples_epoch, xacct, levels, streamed))
-                if telemetry:
-                    telemetry.emit(
-                        "epoch.stats", epoch=epoch, tau=int(state[1]),
-                        samples=n_samples_epoch, seconds=e_seconds,
-                        max_f=[float(x) for x in np.asarray(mf)],
-                        max_g=[float(x) for x in np.asarray(mg)])
-                    if xacct is not None:
-                        telemetry.emit("exchange.epoch", epoch=epoch, **xacct)
-                if ckpt is not None:
-                    ckpt.save_state(
-                        epoch, state + (frozen_c, frozen_tau, stop_epoch, k),
-                        done=bool(stopped.all()))
-    finally:
-        # flush pending async publishes even when the loop aborts (an
-        # on_epoch supervisor raising) — earlier good epochs must land,
-        # and a swallowed publish error must surface here, not vanish
-        if ckpt is not None:
-            ckpt.wait()
-    converged = stopped.copy()
-    if not stopped.all():
-        # max_epochs freeze of whatever never converged (reported with
-        # converged=False; NOT recorded in stop_epoch's checkpoint state,
-        # so a resume with a higher max_epochs keeps sampling)
-        with telemetry.span("phase.flush"):
-            last_flush = flush(state)
-        fl_c, fl_t = last_flush
-        remaining = ~stopped
-        rows = jnp.asarray(np.isin(row_metric, np.nonzero(remaining)[0]))
-        rem_j = jnp.asarray(remaining)
-        frozen_c = jnp.where(rows[:, None], fl_c, frozen_c)
-        frozen_tau = jnp.where(rem_j, fl_t, frozen_tau)
-        stop_epoch = jnp.where(rem_j, jnp.int32(epoch), stop_epoch)
-    t_samp = time.perf_counter() - t0
-    c_samp_end = host_counter_totals()
+                        ckpt.save_state(
+                            epoch,
+                            state + (frozen_c, frozen_tau, stop_epoch, k),
+                            done=bool(stopped.all()))
+        finally:
+            # flush pending async publishes even when the loop aborts (an
+            # on_epoch supervisor raising) — earlier good epochs must land,
+            # and a swallowed publish error must surface here, not vanish
+            if ckpt is not None:
+                ckpt.wait()
+        converged = stopped.copy()
+        if not stopped.all():
+            # max_epochs freeze of whatever never converged (reported with
+            # converged=False; NOT recorded in stop_epoch's checkpoint state,
+            # so a resume with a higher max_epochs keeps sampling)
+            with telemetry.span("phase.flush"):
+                last_flush = flush(state)
+            fl_c, fl_t = last_flush
+            remaining = ~stopped
+            rows = jnp.asarray(np.isin(row_metric, np.nonzero(remaining)[0]))
+            rem_j = jnp.asarray(remaining)
+            frozen_c = jnp.where(rows[:, None], fl_c, frozen_c)
+            frozen_tau = jnp.where(rem_j, fl_t, frozen_tau)
+            stop_epoch = jnp.where(rem_j, jnp.int32(epoch), stop_epoch)
+        t_samp = time.perf_counter() - t0
+        c_samp_end = host_counter_totals()
 
-    ft_np = np.asarray(frozen_tau)
-    se_np = np.asarray(stop_epoch)
-    reports = []
-    for i, (est, off, p) in enumerate(zip(estimators, offsets, params)):
-        sl = frozen_c[off: off + est.n_channels]
-        reports.append(MetricReport(
-            name=est.name,
-            scores=est.finalize(sl, int(ft_np[i]), p, ctx),
-            tau=int(ft_np[i]),
-            converged=bool(converged[i]),
-            omega=float(getattr(p, "omega", np.nan)),
-            stop_epoch=int(se_np[i]),
-            extras=est.extras(p, ctx)))
-    tau_total = (int(last_flush[1]) if last_flush is not None
-                 else int(ft_np.max(initial=0)))
-    c_end = host_counter_totals()
-    host_counters = {
-        "diameter": host_counter_delta(c_diam, c_diam_end),
-        "calibration": host_counter_delta(c_cal, c_cal_end),
-        "sampling": host_counter_delta(c_samp, c_samp_end),
-        "other": host_counter_delta(c_start, c_diam, c_diam_end, c_cal,
-                                    c_cal_end, c_samp, c_samp_end, c_end)}
-    csc = getattr(lane.graph, "csc", None)
-    telemetry.emit("run.end", tau=tau_total, n_epochs=epoch,
-                   converged=bool(converged.all()), batch_size=bsz,
-                   route=route, n_nodes=int(lane.graph.n_nodes),
-                   n_edges=int(lane.graph.n_edges),
-                   block_v=csc.block_v if csc is not None else 0,
-                   block_e=csc.block_e if csc is not None else 0,
-                   bfs_levels=sum(s.bfs_levels for s in stats),
-                   nb_steps=sum(s.nb_steps for s in stats),
-                   **host_counter_delta(c_start, c_end))
-    return AdaptiveRunResult(
-        tuple(reports), tau_total, epoch, bool(converged.all()),
-        ctx.vertex_diameter, stats,
-        {"diameter": lane.t_diam, "calibration": t_cal,
-         "sampling": t_samp}, bsz, route, host_counters)
+        ft_np = np.asarray(frozen_tau)
+        se_np = np.asarray(stop_epoch)
+        reports = []
+        for i, (est, off, p) in enumerate(zip(estimators, offsets, params)):
+            sl = frozen_c[off: off + est.n_channels]
+            reports.append(MetricReport(
+                name=est.name,
+                scores=est.finalize(sl, int(ft_np[i]), p, ctx),
+                tau=int(ft_np[i]),
+                converged=bool(converged[i]),
+                omega=float(getattr(p, "omega", np.nan)),
+                stop_epoch=int(se_np[i]),
+                extras=est.extras(p, ctx)))
+        tau_total = (int(last_flush[1]) if last_flush is not None
+                     else int(ft_np.max(initial=0)))
+        c_end = host_counter_totals()
+        host_counters = {
+            "diameter": host_counter_delta(c_diam, c_diam_end),
+            "calibration": host_counter_delta(c_cal, c_cal_end),
+            "sampling": host_counter_delta(c_samp, c_samp_end),
+            "other": host_counter_delta(c_start, c_diam, c_diam_end, c_cal,
+                                        c_cal_end, c_samp, c_samp_end, c_end)}
+        csc = getattr(lane.graph, "csc", None)
+        telemetry.emit("run.end", tau=tau_total, n_epochs=epoch,
+                       converged=bool(converged.all()), batch_size=bsz,
+                       route=route, n_nodes=int(lane.graph.n_nodes),
+                       n_edges=int(lane.graph.n_edges),
+                       block_v=csc.block_v if csc is not None else 0,
+                       block_e=csc.block_e if csc is not None else 0,
+                       bfs_levels=sum(s.bfs_levels for s in stats),
+                       nb_steps=sum(s.nb_steps for s in stats),
+                       programs_built=used.built,
+                       programs_reused=used.reused,
+                       **host_counter_delta(c_start, c_end))
+        return AdaptiveRunResult(
+            tuple(reports), tau_total, epoch, bool(converged.all()),
+            ctx.vertex_diameter, stats,
+            {"diameter": lane.t_diam, "calibration": t_cal,
+             "sampling": t_samp}, bsz, route, host_counters)
 
 
 def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
@@ -1295,16 +1333,8 @@ def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
 
         counts, tau = jax.jit(fixed_step)(graph, key)
     else:
-        dcap = 0.0
-        if needs_vd and stream == "weighted":
-            wdiam = jax.jit(partial(estimate_diameter_weighted,
-                                    n_sweeps=2))(graph)
-            vd, dcap = int(wdiam.vertex_diameter), float(wdiam.upper)
-        elif needs_vd:
-            vd = int(jax.jit(partial(estimate_diameter, n_sweeps=2))(
-                graph).vertex_diameter)
-        else:
-            vd = 0
+        vd, dcap = (_replicated_phase1(graph, stream, 2) if needs_vd
+                    else (0, 0.0))
         ctx = RunContext(int(graph.n_nodes), vd, dcap)
         n_dev = 1 if mesh is None else int(np.prod(mesh.devices.shape))
         if n_dev == 1:

@@ -125,6 +125,15 @@ class Estimator:
     needs_diameter: bool = False  # make_params/accumulate read ctx.vd
     stop_rule: str = "bernstein"  # registered kernel in kernels.stopcheck
 
+    # compared and hashed by value (type and instance fields): a run's
+    # programs are cached by the estimators they fold
+    # (repro.core.programs), and every call resolves fresh instances
+    def __eq__(self, other):
+        return type(self) is type(other) and vars(self) == vars(other)
+
+    def __hash__(self):
+        return hash((type(self), tuple(sorted(vars(self).items()))))
+
     @property
     def schema(self) -> FrameSchema:
         return FrameSchema(self.name, tuple(self.channels))

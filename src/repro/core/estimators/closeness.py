@@ -30,11 +30,11 @@ heterogeneous-schema paths of engine/checkpoint for free).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kadabra import KadabraParams, calibrate_deltas
+from repro.core.programs import program
 from repro.kernels.stopcheck.ops import get_stop_rule
 
 from .base import DrawBatch, Estimator, RunContext
@@ -60,6 +60,12 @@ def _params_impl(n_nodes, btilde0, *, eps: float,
     omega = hoeffding_omega(n_nodes, eps, delta)
     lil, liu, _tau_star = calibrate_deltas(btilde0, eps, delta, omega)
     return KadabraParams(eps, delta, omega, lil, liu)
+
+
+def _stop_params(eps: float, delta: float):
+    def stop_params(n, b):    # a partial would lower as jit__unknown
+        return _params_impl(n, b, eps=eps, delta=delta)
+    return stop_params
 
 
 class DistanceEstimator(Estimator):
@@ -88,9 +94,7 @@ class DistanceEstimator(Estimator):
         btilde0 = (calib_counts[0][: ctx.n_nodes]
                    / jnp.maximum(calib_tau.astype(jnp.float32), 1.0))
 
-        def stop_params(n, b):    # a partial would lower as jit__unknown
-            return _params_impl(n, b, eps=eps, delta=delta)
-        return jax.jit(stop_params)(ctx.n_nodes, btilde0)
+        return program(_stop_params, eps, delta)(ctx.n_nodes, btilde0)
 
     def accumulate(self, batch: DrawBatch, keep, ctx: RunContext):
         obs = self._obs(batch, ctx)                   # (C, V+1, B)

@@ -13,12 +13,12 @@ lanes).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kadabra import (KadabraParams, calibrate_deltas,
                                 compute_omega)
+from repro.core.programs import program
 from repro.kernels.stopcheck.ops import get_stop_rule
 
 from .base import DrawBatch, Estimator, RunContext
@@ -33,6 +33,12 @@ def _params_impl(vd, btilde0, *, eps: float, delta: float) -> KadabraParams:
     omega = compute_omega(vd, eps, delta)
     lil, liu, _tau_star = calibrate_deltas(btilde0, eps, delta, omega)
     return KadabraParams(eps, delta, omega, lil, liu)
+
+
+def _stop_params(eps: float, delta: float):
+    def stop_params(n, b):    # a partial would lower as jit__unknown
+        return _params_impl(n, b, eps=eps, delta=delta)
+    return stop_params
 
 
 class BetweennessEstimator(Estimator):
@@ -57,9 +63,7 @@ class BetweennessEstimator(Estimator):
         btilde0 = (calib_counts[0][: ctx.n_nodes]
                    / jnp.maximum(calib_tau.astype(jnp.float32), 1.0))
 
-        def stop_params(n, b):    # a partial would lower as jit__unknown
-            return _params_impl(n, b, eps=eps, delta=delta)
-        return jax.jit(stop_params)(ctx.vertex_diameter, btilde0)
+        return program(_stop_params, eps, delta)(ctx.vertex_diameter, btilde0)
 
     def accumulate(self, batch: DrawBatch, keep, ctx: RunContext):
         # verbatim the sample_batch fold: masked sum over the round's

@@ -2,10 +2,11 @@
 
 ``run_adaptive`` passes the graph and the calibration's stop-rule
 params into the jitted epoch step as arguments, so a second call at
-another key lowers the same program and the persistent compilation
-cache serves it instead of a backend compile.  The pins hold the
-single lane's results at a fixed key, so a change to what the program
-holds as constants cannot move them unnoticed.
+another key runs the same program: in one process it reuses the built
+program and lowers nothing, and in a new process the persistent
+compilation cache serves it instead of a backend compile.  The pins
+hold the single lane's results at a fixed key, so a change to what the
+program holds as constants cannot move them unnoticed.
 """
 import hashlib
 import json
@@ -24,7 +25,8 @@ from repro.core.graph import rmat_graph, symmetric_dyadic_weights, with_weights
 
 SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
 
-_TWO_KEYS = """
+# one process: run_adaptive at each of argv[3]'s keys, in order
+_CALLS = """
 import json, sys
 import jax
 jax.config.update("jax_compilation_cache_dir", sys.argv[1])
@@ -37,7 +39,7 @@ from repro.runtime import RingSink, Telemetry
 g = rmat_graph(9, 8, seed=1)
 if sys.argv[2] == "committed":
     g = jax.device_put(g, jax.devices()[0])
-# the second call with the bus on: it reads the epochs' work counters
+# the call at key 2 with the bus on: it reads the epochs' work counters
 cfg = AdaptiveConfig(eps=0.01, delta=0.1, n0_base=64, max_epochs=2)
 lowered = []
 
@@ -47,45 +49,57 @@ def listen(event, duration, **kw):
 
 jax.monitoring.register_event_duration_secs_listener(listen)
 out = []
-for k in (1, 2):
+for k in map(int, sys.argv[3].split(",")):
     del lowered[:]
     tel = (Telemetry([RingSink()], validate=True)
            if k == 2 and sys.argv[2] == "telemetry" else None)
     res = run_adaptive(g, config=cfg, key=jax.random.PRNGKey(k),
                        telemetry=tel)
-    out.append(dict(res.host_counters,
+    out.append(dict(res.host_counters, lowerings=len(lowered),
                     epoch_lowerings=lowered.count("jit(epoch_step)"),
                     bfs_levels=[s.bfs_levels for s in res.stats]))
 print(json.dumps(out))
 """
 
 
-@pytest.mark.parametrize("placement", ["default", "committed", "telemetry"])
-def test_second_key_loads_the_epoch_program_from_the_cache(tmp_path,
-                                                           placement):
-    """Two calls on one graph at different keys, in a fresh interpreter
-    so the cache settings stay out of this process: the second call's
-    sampling phase compiles nothing and loads from the cache.  Each call
-    lowers the epoch step once: a graph committed to its device
-    (``jax.device_put``) commits the epochs' outputs, which must not
-    make the second epoch a second program.  With the telemetry bus on
-    in the second call only, that call still loads the first call's
-    program: the work counters are computed either way."""
+def _calls(cache_dir, placement, keys):
     env = dict(os.environ)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["PYTHONPATH"] = os.pathsep.join(
         [str(SRC)] + [p for p in [env.get("PYTHONPATH")] if p])
     out = subprocess.run(
-        [sys.executable, "-c", _TWO_KEYS, str(tmp_path / "xla_cache"),
-         placement],
+        [sys.executable, "-c", _CALLS, str(cache_dir), placement, keys],
         env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
-    first, second = json.loads(out.stdout.strip().splitlines()[-1])
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("placement", ["default", "committed", "telemetry"])
+def test_second_key_loads_the_epoch_program_from_the_cache(tmp_path,
+                                                           placement):
+    """Calls on one graph at different keys, in fresh interpreters so
+    the cache settings stay out of this process.  In the first process
+    the call at the second key reuses the first call's programs and
+    lowers nothing.  A second process that shares the cache directory
+    lowers the epoch step once and loads it from the cache: its sampling
+    phase compiles nothing.  Each process lowers the epoch step once: a
+    graph committed to its device (``jax.device_put``) commits the
+    epochs' outputs, which must not make the second epoch a second
+    program.  With the telemetry bus on in the calls at the second key
+    only, they still reuse or load the first call's program: the work
+    counters are computed either way."""
+    cache = tmp_path / "xla_cache"
+    first, again = _calls(cache, placement, "1,2")
+    (second,) = _calls(cache, placement, "2")
     assert first["sampling"]["compiles"] >= 1       # the cache starts empty
     assert first["epoch_lowerings"] == second["epoch_lowerings"] == 1
+    assert again["lowerings"] == 0, again
+    assert all(again[p]["compiles"] == 0 for p in
+               ("diameter", "calibration", "sampling", "other")), again
     assert second["sampling"]["compiles"] == 0, second
     assert second["sampling"]["cache_hits"] >= 1, second
-    assert all(n > 0 for n in first["bfs_levels"] + second["bfs_levels"])
+    assert all(n > 0 for n in first["bfs_levels"] + again["bfs_levels"]
+               + second["bfs_levels"])
 
 
 @pytest.fixture(scope="module")
