@@ -14,8 +14,8 @@ What remains here is the historical public surface:
     tests/test_estimators.py);
   * :func:`run_fixed_sampling` — the non-adaptive baseline, routed
     through the same engine;
-  * re-exports (``AdaptiveConfig``, ``make_epoch_step_*``, ``_pad_len``,
-    …) so PR 1-6 call sites and the dry-run keep importing from here.
+  * the engine's configuration surface (``AdaptiveConfig``,
+    ``DEFAULT_SAMPLE_BATCH_SIZE``, ``resolve_sample_batch_size``).
 
 For closeness/harmonic — or several metrics amortized over one BFS
 stream — call ``repro.core.engine.run_adaptive`` directly.
@@ -27,12 +27,9 @@ from typing import NamedTuple, Optional
 import numpy as np
 from jax.sharding import Mesh
 
-# re-exports: PR 1-6 call sites (tests, dry-run, benchmarks) import the
-# engine's building blocks from this module — keep that surface stable
-from .engine import (DEFAULT_SAMPLE_BATCH_SIZE, AdaptiveConfig,  # noqa: F401
-                     AdaptiveRunResult, _pad_len, make_agg_fn,
-                     make_epoch_step_sharded, make_epoch_step_spmd,
-                     resolve_sample_batch_size, run_adaptive, run_fixed)
+from .engine import (DEFAULT_SAMPLE_BATCH_SIZE, AdaptiveConfig,
+                     AdaptiveRunResult, resolve_sample_batch_size,
+                     run_adaptive, run_fixed)
 
 __all__ = ["DEFAULT_SAMPLE_BATCH_SIZE", "AdaptiveConfig",
            "BetweennessResult", "EpochStats", "resolve_sample_batch_size",

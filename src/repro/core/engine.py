@@ -46,8 +46,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
-from types import SimpleNamespace
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,7 +61,8 @@ from .estimators import get_estimator
 from .estimators.base import DrawBatch, Estimator, MetricReport, RunContext
 from .bfs import frontier_route
 from .graph import Graph
-from .partition import PartitionedGraph, exchange_plan
+from .partition import (PartitionedGraph, auto_exchange_budget, exchange_plan,
+                        max_active_source_chunks)
 from .programs import ProgramTally, program
 from .sampler import (sample_path_batched, sample_path_batched_sharded,
                       sample_path_forward_batched,
@@ -228,11 +228,6 @@ def _channel_offsets(estimators) -> tuple:
     return tuple(offs)
 
 
-def _default_estimators(estimators) -> tuple:
-    return (resolve_estimators("betweenness") if estimators is None
-            else tuple(estimators))
-
-
 # ---------------------------------------------------------------------------
 # The shared draw-and-fold (generalized sampler.sample_batch)
 # ---------------------------------------------------------------------------
@@ -388,10 +383,9 @@ def _agg_channels(agg_fn, x):
 # Epoch steps (exposed for the multi-pod dry-run's HLO accounting)
 # ---------------------------------------------------------------------------
 
-def make_epoch_step_spmd(mesh, aggregation: str, n_nodes: int, v_pad: int,
-                         n0: int, batch_size: int = 1, estimators=None,
-                         stream: str = "bidir", vertex_diameter: int = 0,
-                         distance_cap: float = 0.0):
+def make_epoch_step_spmd(draw, mesh, aggregation: str, estimators,
+                         stream: str, ctx: RunContext, n0: int,
+                         batch_size: int):
     """One jit-able SPMD epoch (paper Alg. 2): aggregate the previous
     frame (collectives) while sampling the next one — ceil(n0 /
     batch_size) batched BFS rounds per device — then evaluate every
@@ -399,14 +393,11 @@ def make_epoch_step_spmd(mesh, aggregation: str, n_nodes: int, v_pad: int,
     level so the multi-pod dry-run can .lower()/.compile() it on the
     production mesh and extract its roofline terms (DESIGN.md §Perf).
 
-    ``estimators=None`` defaults to the single betweenness plugin (the
-    PR 1-6 step); frames are channel-stacked either way.  Each device's
-    masked surplus tail is carried into its next epoch's frame instead
-    of dropped.  ``vertex_diameter`` feeds RunContext for estimators
-    whose accumulate reads the diameter cap (closeness); betweenness /
-    harmonic ignore it.  ``distance_cap`` (weighted stream only) is the
-    phase-1 weighted-diameter bound those estimators prefer over the
-    hop-count vertex diameter.
+    ``draw`` is the draw-and-fold (:func:`draw_fold`).  Frames are
+    channel-stacked; each device's masked surplus tail is carried into
+    its next epoch's frame instead of dropped.  ``ctx`` carries what
+    the estimators read: V, and the phase-1 bounds that
+    distance-normalizing estimators (closeness) use as their cap.
 
     Signature of the returned fn:
       (graph, params: tuple (one per estimator),
@@ -416,10 +407,9 @@ def make_epoch_step_spmd(mesh, aggregation: str, n_nodes: int, v_pad: int,
       -> (agg_counts, agg_tau, new_frame, new_tau, new_sur_counts,
           new_sur_tau, done (E,), max_f (E,), max_g (E,))
     """
-    estimators = _default_estimators(estimators)
     offsets = _channel_offsets(estimators)
     C = total_channels(estimators)
-    ctx = RunContext(int(n_nodes), int(vertex_diameter), float(distance_cap))
+    v_pad = _pad_len(ctx.n_nodes, mesh.devices.size)
     all_axes = tuple(mesh.axis_names)
     agg_fn = make_agg_fn(mesh, aggregation)
     rep = P()
@@ -448,12 +438,11 @@ def make_epoch_step_spmd(mesh, aggregation: str, n_nodes: int, v_pad: int,
             #    this round's tail comes back as the next carry (the
             #    surplus sample count is the same on every device, so
             #    new_sur_tau stays a replicated scalar)
-            (c, t), (sc, st) = draw_fold(g, keys[0], n0,
-                                         estimators=estimators, ctx=ctx,
-                                         stream=stream,
-                                         batch_size=batch_size,
-                                         carry=(sur_counts[0], sur_tau),
-                                         return_carry=True)
+            (c, t), (sc, st) = draw(g, keys[0], n0, estimators=estimators,
+                                    ctx=ctx, stream=stream,
+                                    batch_size=batch_size,
+                                    carry=(sur_counts[0], sur_tau),
+                                    return_carry=True)
             new_counts = jnp.zeros(
                 (1, C, v_pad), jnp.float32).at[0, :, : c.shape[1]].set(c)
             new_sur = sc[None]
@@ -471,12 +460,8 @@ def make_epoch_step_spmd(mesh, aggregation: str, n_nodes: int, v_pad: int,
     return epoch_step
 
 
-def make_epoch_step_sharded(mesh, n_nodes: int, v_pad: int, n0: int,
-                            batch_size: int = 1, estimators=None,
-                            stream: str = "bidir",
-                            vertex_diameter: int = 0,
-                            distance_cap: float = 0.0,
-                            with_exchange: bool = False):
+def make_epoch_step_sharded(draw, mesh, estimators, stream: str,
+                            ctx: RunContext, n0: int, batch_size: int):
     """One jit-able COOPERATIVE epoch on a :class:`PartitionedGraph`.
 
     The graph is sharded over the whole mesh, so the mesh advances one
@@ -487,31 +472,27 @@ def make_epoch_step_sharded(mesh, n_nodes: int, v_pad: int, n0: int,
     construction and folds into the aggregate without any reduction
     collective.  ``n0`` is samples per epoch for the WHOLE mesh
     (``epoch_length(1)``: the cooperative mesh is one fast sampler).
-    ``estimators``/``stream``/``vertex_diameter`` as in
-    :func:`make_epoch_step_spmd`.
+    The other arguments are as in :func:`make_epoch_step_spmd`.
 
     Signature of the returned fn (all frames replicated):
       (pg, params tuple, agg_counts (C, V_pad), agg_tau (),
        frame_counts (C, V_pad), frame_tau (), sur_counts (C, V+1),
        sur_tau (), key (2,) replicated)
       -> (agg_counts, agg_tau, new_frame, new_tau, new_sur_counts,
-          new_sur_tau, done (E,), max_f (E,), max_g (E,))
+          new_sur_tau, done (E,), max_f (E,), max_g (E,), exchange (2,))
 
-    ``with_exchange=True`` appends a 10th replicated output: the
-    epoch's summed (2,) [levels_exchanged, levels_sparse] frontier-
-    exchange tally (``ExchangePlan.epoch_accounting`` prices it into
-    telemetry).  The default 9-output signature is unchanged — the
-    dry-run's HLO accounting keeps lowering the exact production step.
+    The 10th output is the epoch's summed [levels_exchanged,
+    levels_sparse] frontier-exchange tally
+    (``ExchangePlan.epoch_accounting`` prices it into telemetry).
 
     Exposed at module level so the multi-pod dry-run can
     .lower()/.compile() it on the production mesh and read the
     per-level frontier-exchange volume off its optimized HLO
     (DESIGN.md §Partitioning).
     """
-    estimators = _default_estimators(estimators)
     offsets = _channel_offsets(estimators)
     C = total_channels(estimators)
-    ctx = RunContext(int(n_nodes), int(vertex_diameter), float(distance_cap))
+    v_pad = _pad_len(ctx.n_nodes, mesh.devices.size)
     all_axes = tuple(mesh.axis_names)
     rep = P()
 
@@ -522,7 +503,7 @@ def make_epoch_step_sharded(mesh, n_nodes: int, v_pad: int, n0: int,
 
         @partial(jax.shard_map, mesh=mesh,
                  in_specs=(gspec, pspec, rep, rep, rep, rep, rep, rep, rep),
-                 out_specs=(rep,) * (10 if with_exchange else 9),
+                 out_specs=(rep,) * 10,
                  check_vma=False)
         def _step(g, params, agg_counts, agg_tau, frame_counts, frame_tau,
                   sur_counts, sur_tau, k):
@@ -531,23 +512,17 @@ def make_epoch_step_sharded(mesh, n_nodes: int, v_pad: int, n0: int,
             agg_tau = agg_tau + frame_tau
             # 2. cooperatively sample the next frame over the sharded
             #    graph; the previous surplus tail seeds it
-            df = draw_fold(g, k, n0, estimators=estimators,
-                           ctx=ctx, stream=stream,
-                           batch_size=batch_size,
-                           carry=(sur_counts, sur_tau),
-                           return_carry=True, axis=all_axes,
-                           with_exchange=with_exchange)
-            (c, t), (sc, st) = df[0], df[1]
+            (c, t), (sc, st), xch = draw(
+                g, k, n0, estimators=estimators, ctx=ctx, stream=stream,
+                batch_size=batch_size, carry=(sur_counts, sur_tau),
+                return_carry=True, axis=all_axes, with_exchange=True)
             new_counts = jnp.zeros(
                 (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
             # 3. stop rules on the consistent snapshot
             done, mf, mg = _check_all(estimators, offsets, agg_counts,
                                       agg_tau, params, ctx)
-            out = (agg_counts, agg_tau, new_counts, t, sc, st,
-                   done, mf, mg)
-            if with_exchange:
-                out = out + (df[2],)
-            return out
+            return (agg_counts, agg_tau, new_counts, t, sc, st,
+                    done, mf, mg, xch)
 
         return _step(g, params, agg_counts, agg_tau, frame_counts,
                      frame_tau, sur_counts, sur_tau, k)
@@ -614,61 +589,19 @@ class _EngineCheckpointer:
 
 
 # ---------------------------------------------------------------------------
-# Lane builders (phase 1 + the lane-specific jitted steps)
+# Programs: phase 1, the draws, the flush (the epoch steps are above)
 # ---------------------------------------------------------------------------
-
-def _sharded_diameter(pg: PartitionedGraph, mesh, n_sweeps: int):
-    """Cooperative double-sweep diameter on the partitioned graph; with
-    ``exchange_budget="auto"`` the sweeps double as the budget's
-    occupancy sample — the returned pg carries the resolved static
-    budget, so every later phase compiles against it."""
-    all_axes = tuple(mesh.axis_names)
-    rep = P()
-    gspec = pg.partition_spec(all_axes)
-    want_dist = pg.exchange_budget_auto
-
-    @partial(jax.shard_map, mesh=mesh, in_specs=(gspec,),
-             out_specs=(rep, P(all_axes)) if want_dist else rep,
-             check_vma=False)
-    def diam_step(g):
-        est = estimate_diameter_sharded(g, n_sweeps=n_sweeps,
-                                        axis=all_axes,
-                                        return_dist=want_dist)
-        if want_dist:
-            est, d = est
-            return est.vertex_diameter, d
-        return est.vertex_diameter
-
-    if want_dist:
-        from .partition import auto_exchange_budget, max_active_source_chunks
-        vd_dev, dist_dev = jax.jit(diam_step)(pg)
-        vd = int(vd_dev)
-        dist_np = np.asarray(dist_dev)             # (v_pad, n_sweep_seeds)
-        occupancies = []
-        for lvl in range(int(dist_np.max(initial=-1)) + 1):
-            rows = (dist_np == lvl).any(axis=1)
-            if rows.any():
-                occupancies.append(max_active_source_chunks(pg, rows))
-        pg = dataclasses.replace(
-            pg, exchange_budget=auto_exchange_budget(pg, occupancies),
-            exchange_budget_auto=False)
-    else:
-        vd = int(jax.jit(diam_step)(pg))
-    return vd, pg
-
-
-# The single lane's programs: builders for ``repro.core.programs``, so
-# each is traced and lowered once per static configuration and reused by
-# every later call.  Every array (the graph, the keys, the stop-rule
-# params, the state) is an argument; the draw function is a static
-# value, read where the lane runs, so a program built from another draw
-# function is another program.
+#
+# Every program of every lane is a builder for ``repro.core.programs``
+# (its docstring has the contract).  The mesh programs build their
+# shard_map specs from the arguments inside the traced function.  The
+# draw function is a static value, read where the lane runs.  Each
+# builder returns a named function, whose name a device trace shows
+# (``jit_phase1_diameter``); a ``partial`` lowers as ``jit__unknown``.
 
 def _phase1_program(weighted: bool, n_sweeps: int):
     """Phase 1 on a replicated graph: the vertex-diameter bound, and on
-    a weighted graph the weighted-diameter bound too.  Its program is a
-    named function (``jit_phase1_diameter`` in a device trace), not a
-    ``partial``, which lowers as ``jit__unknown``."""
+    a weighted graph the weighted-diameter bound too."""
     if weighted:
         # weighted phase 1: hop-based VD bound for omega PLUS the
         # weighted-diameter bound distance-normalizing estimators use
@@ -682,12 +615,127 @@ def _phase1_program(weighted: bool, n_sweeps: int):
     return phase1_diameter
 
 
+def _sharded_phase1_program(mesh, weighted: bool, n_sweeps: int):
+    """Phase 1 on a partitioned graph, the mesh sweeping cooperatively:
+    the BFS sweeps' vertex-diameter bound and the second sweep's
+    distances (the occupancy sample of ``exchange_budget="auto"``), or
+    on a weighted graph the vertex- and weighted-diameter bounds."""
+    all_axes = tuple(mesh.axis_names)
+    rep = P()
+
+    def sharded_phase1(g):
+        @partial(jax.shard_map, mesh=mesh,
+                 in_specs=(g.partition_spec(all_axes),),
+                 out_specs=(rep, rep if weighted else P(all_axes)),
+                 check_vma=False)
+        def sweep(g):
+            if weighted:
+                est = estimate_diameter_weighted_sharded(
+                    g, n_sweeps=n_sweeps, axis=all_axes)
+                return est.vertex_diameter, est.upper
+            est, d = estimate_diameter_sharded(
+                g, n_sweeps=n_sweeps, axis=all_axes, return_dist=True)
+            return est.vertex_diameter, d
+
+        return sweep(g)
+    return sharded_phase1
+
+
+def _phase1(graph, mesh, stream: str, n_sweeps: int):
+    """Phase 1 on any lane's graph -> (vertex diameter, distance cap,
+    graph).  The cap is the weighted-diameter bound on the 'weighted'
+    stream, 0.0 on the others.
+
+    On a partitioned graph the BFS double sweep always runs first: with
+    ``exchange_budget="auto"`` it doubles as the budget's occupancy
+    sample, and the returned graph carries the resolved static budget,
+    so every later program compiles against it.  The weighted sweep
+    then runs on that graph."""
+    weighted = stream == "weighted"
+    if not isinstance(graph, PartitionedGraph):
+        est = program(_phase1_program, weighted, n_sweeps)(graph)
+        return (int(est.vertex_diameter),
+                float(est.upper) if weighted else 0.0, graph)
+    vd, dist_dev = program(_sharded_phase1_program, mesh, False,
+                           n_sweeps)(graph)
+    vd = int(vd)
+    if graph.exchange_budget_auto:
+        dist_np = np.asarray(dist_dev)             # (v_pad, n_sweep_seeds)
+        occupancies = []
+        for lvl in range(int(dist_np.max(initial=-1)) + 1):
+            rows = (dist_np == lvl).any(axis=1)
+            if rows.any():
+                occupancies.append(max_active_source_chunks(graph, rows))
+        graph = dataclasses.replace(
+            graph, exchange_budget=auto_exchange_budget(graph, occupancies),
+            exchange_budget_auto=False)
+    if not weighted:
+        return vd, 0.0, graph
+    vd_w, cap_w = program(_sharded_phase1_program, mesh, True,
+                          n_sweeps)(graph)
+    return int(vd_w), float(cap_w), graph
+
+
 def _calibration_program(draw, estimators, stream: str, ctx: RunContext,
                          n_samples: int, bsz: int):
+    """The single lane's draws: (C, V+1) counts and tau of
+    ``n_samples`` samples at one key."""
     def calibration_draws(g, k):
         return draw(g, k, n_samples=n_samples, batch_size=bsz,
                     estimators=estimators, ctx=ctx, stream=stream)
     return calibration_draws
+
+
+def _spmd_draw_program(draw, mesh, estimators, stream: str,
+                       ctx: RunContext, n_samples: int, bsz: int):
+    """The SPMD lane's draws: ``n_samples`` on every device, each at its
+    own key (pleasingly parallel), then one blocking reduce
+    (MPI_Reduce) of the (C, v_pad) counts and tau."""
+    all_axes = tuple(mesh.axis_names)
+    C = total_channels(estimators)
+    v_pad = _pad_len(ctx.n_nodes, mesh.devices.size)
+    rep = P()
+
+    def spmd_draws(g, keys):
+        @partial(jax.shard_map, mesh=mesh,
+                 in_specs=(jax.tree.map(lambda _: rep, g), P(all_axes)),
+                 out_specs=(rep, rep), check_vma=False)
+        def per_device(g, keys):
+            c, t = draw(g, keys[0], n_samples, batch_size=bsz,
+                        estimators=estimators, ctx=ctx, stream=stream)
+            cp = jnp.zeros(
+                (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
+            return (dist.flat_allreduce(cp, all_axes),
+                    dist.flat_allreduce(t, all_axes))
+
+        return per_device(g, keys)
+    return spmd_draws
+
+
+def _sharded_draw_program(draw, mesh, estimators, stream: str,
+                          ctx: RunContext, n_samples: int, bsz: int):
+    """The sharded lane's draws: ``n_samples`` drawn by the whole mesh
+    cooperatively at one replicated key -> (C, v_pad) counts and tau,
+    replicated by construction."""
+    all_axes = tuple(mesh.axis_names)
+    C = total_channels(estimators)
+    v_pad = _pad_len(ctx.n_nodes, mesh.devices.size)
+    rep = P()
+
+    def sharded_draws(g, k):
+        @partial(jax.shard_map, mesh=mesh,
+                 in_specs=(g.partition_spec(all_axes), rep),
+                 out_specs=(rep, rep), check_vma=False)
+        def cooperative(g, k):
+            c, t = draw(g, k, n_samples, batch_size=bsz,
+                        estimators=estimators, ctx=ctx, stream=stream,
+                        axis=all_axes)
+            cp = jnp.zeros(
+                (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
+            return cp, t
+
+        return cooperative(g, k)
+    return sharded_draws
 
 
 def _epoch_program(draw, estimators, stream: str, ctx: RunContext, n0: int,
@@ -723,28 +771,118 @@ def _flush_program():
     return flush
 
 
-def _replicated_phase1(graph: Graph, stream: str, n_sweeps: int):
-    """Phase 1 on a replicated graph: the vertex-diameter bound and the
-    distance cap (0.0 unweighted)."""
-    weighted = stream == "weighted"
-    est = program(_phase1_program, weighted, n_sweeps)(graph)
-    return int(est.vertex_diameter), float(est.upper) if weighted else 0.0
+def _spmd_flush_program(mesh, aggregation: str):
+    # per-device frame + its surplus tail, then one reduction onto the
+    # aggregate — the PR 1-6 flush association, channel-stacked
+    all_axes = tuple(mesh.axis_names)
+    agg_fn = make_agg_fn(mesh, aggregation)
+    rep, frame_spec = P(), P(all_axes, None, None)
+
+    def spmd_flush(agg_c, agg_t, fr_c, fr_t, sur_c, sur_t):
+        @partial(jax.shard_map, mesh=mesh,
+                 in_specs=(frame_spec, rep, frame_spec, rep),
+                 out_specs=(rep, rep), check_vma=False)
+        def reduce(fr_c, fr_t, sur_c, sur_t):
+            c = fr_c[0].at[:, : sur_c.shape[2]].add(sur_c[0])
+            return (_agg_channels(agg_fn, c),
+                    dist.flat_allreduce(fr_t + sur_t, all_axes))
+
+        inc_c, inc_t = reduce(fr_c, fr_t, sur_c, sur_t)
+        return agg_c + inc_c, agg_t + inc_t
+    return spmd_flush
 
 
-def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
-                 stream: str, C: int):
-    ns = SimpleNamespace()
+# ---------------------------------------------------------------------------
+# The lanes
+# ---------------------------------------------------------------------------
+
+class EpochOut(NamedTuple):
+    """One epoch on any lane: the 6-leaf state, every estimator's stop
+    flag and f/g margins, and the lane's work tallies — the sharded
+    lane's [levels_exchanged, levels_sparse] frontier exchange and the
+    single lane's [expansions, streamed_blocks] BFS work, None on a
+    lane that keeps none."""
+    agg_counts: jax.Array
+    agg_tau: jax.Array
+    frame_counts: jax.Array
+    frame_tau: jax.Array
+    sur_counts: jax.Array
+    sur_tau: jax.Array
+    done: jax.Array
+    max_f: jax.Array
+    max_g: jax.Array
+    exchange: Optional[jax.Array] = None
+    steps: Optional[jax.Array] = None
+
+    @property
+    def state(self) -> tuple:
+        return tuple(self[:6])
+
+
+@dataclasses.dataclass
+class Lane:
+    """One execution lane, as ``run_adaptive`` and ``run_fixed`` drive it.
+
+    - ``draw(key, n, bsz, ctx) -> (counts, tau)``: ``n`` samples per
+      sampler (per device on the SPMD lane, for the whole mesh on the
+      sharded lane); counts (C, V+1) on the single lane, (C, v_pad) on
+      the mesh lanes.  Calibration runs it.
+    - ``make_epoch(params, ctx, n0, bsz)`` -> ``run(state, key)`` ->
+      :class:`EpochOut`.
+    - ``flush(state) -> (counts, tau)``: aggregate, frame and surplus.
+    - ``init_state()``: the zero 6-leaf state.
+    - ``shardings`` places the 10-leaf checkpoint tuple on a restore
+      (None: default placement).
+    """
+    v_pad: int
+    n_samplers: int         # independent samplers (the epoch schedule's)
+    shardings: Optional[tuple]
+    draw: Callable
+    make_epoch: Callable
+    flush: Callable
+    init_state: Callable
+
+
+def _zero_state(C: int, v_pad: int, v1: int) -> tuple:
+    return (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
+            jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
+            jnp.zeros((C, v1), jnp.float32), jnp.int32(0))
+
+
+def _lane_name(graph, mesh) -> str:
+    """Which lane runs ``graph`` on ``mesh``: 'single', 'spmd' or
+    'sharded'."""
+    if isinstance(graph, PartitionedGraph):
+        if mesh is None:
+            raise ValueError(
+                "a PartitionedGraph needs the mesh its shards map onto "
+                "(mesh=...); use a plain Graph for the single-device lane")
+        if graph.n_shards != mesh.devices.size:
+            raise ValueError(
+                f"PartitionedGraph carries {graph.n_shards} shards but the "
+                f"mesh has {mesh.devices.size} devices; rebuild with "
+                f"partition_graph(graph, {mesh.devices.size})")
+        return "sharded"
+    if mesh is None or mesh.devices.size == 1:
+        return "single"
+    return "spmd"
+
+
+def _lane(name: str, graph, mesh, estimators, stream: str,
+          aggregation: str) -> Lane:
+    if name == "single":
+        return _single_lane(graph, estimators, stream)
+    if name == "spmd":
+        return _spmd_lane(graph, mesh, estimators, stream, aggregation)
+    return _sharded_lane(graph, mesh, estimators, stream)
+
+
+def _single_lane(graph: Graph, estimators, stream: str) -> Lane:
     v_pad = _pad_len(graph.n_nodes, 1)
-    v1 = graph.n_nodes + 1
-    t0 = time.perf_counter()
-    ns.vd, ns.dist_cap = _replicated_phase1(graph, stream,
-                                            cfg.diameter_sweeps)
-    ns.t_diam = time.perf_counter() - t0
-    ns.graph, ns.v_pad, ns.n_samplers, ns.shardings = graph, v_pad, 1, None
 
-    def calibrate(k_cal, bsz, ctx):
+    def draw(key, n, bsz, ctx):
         return program(_calibration_program, draw_fold, estimators, stream,
-                       ctx, cfg.calib_samples_per_device, bsz)(graph, k_cal)
+                       ctx, n, bsz)(graph, key)
 
     def make_epoch(params, ctx, n0, bsz):
         epoch_step = program(_epoch_program, draw_fold, estimators, stream,
@@ -757,185 +895,84 @@ def _single_lane(graph: Graph, cfg: AdaptiveConfig, estimators,
 
         def run(state, ke):
             out = epoch_step(graph, params, *jax.device_put(state, dev), ke)
-            return out[:9] + (None, out[9])   # no exchange tally; steps
+            return EpochOut(*out[:9], steps=out[9])
 
         return run
 
-    def make_flush(ctx):
-        return lambda state: program(_flush_program)(*state)
-
-    def init_state(ctx):
-        return (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
-                jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
-                jnp.zeros((C, v1), jnp.float32), jnp.int32(0))
-
-    ns.calibrate, ns.make_epoch = calibrate, make_epoch
-    ns.make_flush, ns.init_state = make_flush, init_state
-    return ns
+    return Lane(v_pad, 1, None, draw, make_epoch,
+                lambda state: program(_flush_program)(*state),
+                partial(_zero_state, total_channels(estimators), v_pad,
+                        graph.n_nodes + 1))
 
 
-def _spmd_lane(graph: Graph, mesh: Mesh, cfg: AdaptiveConfig, estimators,
-               stream: str, C: int):
-    ns = SimpleNamespace()
+def _spmd_lane(graph: Graph, mesh: Mesh, estimators, stream: str,
+               aggregation: str) -> Lane:
     all_axes = tuple(mesh.axis_names)
-    n_dev = int(np.prod(mesh.devices.shape))
+    n_dev = mesh.devices.size
+    C = total_channels(estimators)
     v_pad = _pad_len(graph.n_nodes, n_dev)
     v1 = graph.n_nodes + 1
-    agg_fn = make_agg_fn(mesh, cfg.aggregation)
-    rep = P()
-    frame_spec = P(all_axes, None, None)
-    key_spec = P(all_axes)
-    gspec = jax.tree.map(lambda _: rep, graph)
-
-    t0 = time.perf_counter()
-    ns.vd, ns.dist_cap = _replicated_phase1(graph, stream,
-                                            cfg.diameter_sweeps)
-    ns.t_diam = time.perf_counter() - t0
-    ns.graph, ns.v_pad, ns.n_samplers = graph, v_pad, n_dev
+    rep = NamedSharding(mesh, P())
+    frames = NamedSharding(mesh, P(all_axes, None, None))
+    key_sharding = NamedSharding(mesh, P(all_axes))
     # shardings follow the 10-leaf checkpoint tuple: frames sharded over
     # the device axis, everything else (incl. frozen snapshots) replicated
-    ns.shardings = tuple(NamedSharding(mesh, s) for s in (
-        rep, rep, frame_spec, rep, frame_spec, rep, rep, rep, rep, rep))
+    shardings = (rep, rep, frames, rep, frames, rep, rep, rep, rep, rep)
 
-    def calibrate(k_cal, bsz, ctx):
-        # pleasingly parallel sampling + blocking reduce (MPI_Reduce)
-        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, key_spec),
-                 out_specs=(rep, rep), check_vma=False)
-        def calib_step(g, keys):
-            c, t = draw_fold(g, keys[0], cfg.calib_samples_per_device,
-                             batch_size=bsz, estimators=estimators,
-                             ctx=ctx, stream=stream)
-            cp = jnp.zeros(
-                (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
-            return (dist.flat_allreduce(cp, all_axes),
-                    dist.flat_allreduce(t, all_axes))
-
-        dev_keys = jax.random.split(k_cal, n_dev)
-        return jax.jit(calib_step)(graph, dev_keys)
+    def draw(key, n, bsz, ctx):
+        return program(_spmd_draw_program, draw_fold, mesh, estimators,
+                       stream, ctx, n, bsz)(
+            graph, jax.random.split(key, n_dev))
 
     def make_epoch(params, ctx, n0, bsz):
-        epoch_jit = jax.jit(make_epoch_step_spmd(
-            mesh, cfg.aggregation, graph.n_nodes, v_pad, n0,
-            batch_size=bsz, estimators=estimators, stream=stream,
-            vertex_diameter=ctx.vertex_diameter,
-            distance_cap=ctx.distance_cap))
+        epoch_step = program(make_epoch_step_spmd, draw_fold, mesh,
+                             aggregation, estimators, stream, ctx, n0, bsz)
 
         def run(state, ke):
+            # the state committed, as on the single lane
             dev_keys = jax.device_put(jax.random.split(ke, n_dev),
-                                      NamedSharding(mesh, key_spec))
-            return epoch_jit(graph, params, *state, dev_keys)
+                                      key_sharding)
+            return EpochOut(*epoch_step(
+                graph, params, *jax.device_put(state, shardings[:6]),
+                dev_keys))
 
         return run
 
-    def make_flush(ctx):
-        # per-device frame + its surplus tail, then one reduction —
-        # the PR 1-6 flush association, channel-stacked
-        @partial(jax.shard_map, mesh=mesh,
-                 in_specs=(frame_spec, rep, frame_spec, rep),
-                 out_specs=(rep, rep), check_vma=False)
-        def _flush(fr_c, fr_t, sur_c, sur_t):
-            c = fr_c[0].at[:, :v1].add(sur_c[0])
-            return (_agg_channels(agg_fn, c),
-                    dist.flat_allreduce(fr_t + sur_t, all_axes))
+    def init_state():
+        return jax.device_put(
+            (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
+             jnp.zeros((n_dev, C, v_pad), jnp.float32), jnp.int32(0),
+             jnp.zeros((n_dev, C, v1), jnp.float32), jnp.int32(0)),
+            shardings[:6])
 
-        fj = jax.jit(_flush)
-
-        def flush(state):
-            agg_c, agg_t, fr_c, fr_t, sur_c, sur_t = state
-            inc_c, inc_t = fj(fr_c, fr_t, sur_c, sur_t)
-            return agg_c + inc_c, agg_t + inc_t
-
-        return flush
-
-    def init_state(ctx):
-        return (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
-                jax.device_put(jnp.zeros((n_dev, C, v_pad), jnp.float32),
-                               NamedSharding(mesh, frame_spec)),
-                jnp.int32(0),
-                jax.device_put(jnp.zeros((n_dev, C, v1), jnp.float32),
-                               NamedSharding(mesh, frame_spec)),
-                jnp.int32(0))
-
-    ns.calibrate, ns.make_epoch = calibrate, make_epoch
-    ns.make_flush, ns.init_state = make_flush, init_state
-    return ns
+    return Lane(v_pad, n_dev, shardings, draw, make_epoch,
+                lambda state: program(_spmd_flush_program, mesh,
+                                      aggregation)(*state),
+                init_state)
 
 
-def _sharded_lane(pg: PartitionedGraph, mesh: Mesh, cfg: AdaptiveConfig,
-                  estimators, stream: str, C: int):
-    ns = SimpleNamespace()
-    all_axes = tuple(mesh.axis_names)
-    n_dev = int(np.prod(mesh.devices.shape))
-    if pg.n_shards != n_dev:
-        raise ValueError(
-            f"PartitionedGraph carries {pg.n_shards} shards but the mesh "
-            f"has {n_dev} devices; rebuild with partition_graph(graph, "
-            f"{n_dev})")
-    rep = P()
-    v_pad = _pad_len(pg.n_nodes, n_dev)
-    v1 = pg.n_nodes + 1
+def _sharded_lane(pg: PartitionedGraph, mesh: Mesh, estimators,
+                  stream: str) -> Lane:
+    v_pad = _pad_len(pg.n_nodes, mesh.devices.size)
 
-    t0 = time.perf_counter()
-    # the BFS double sweep always runs first: with exchange_budget="auto"
-    # it doubles as the budget's occupancy sample, and the weighted lane
-    # compiles against the resolved static budget like every other phase
-    ns.vd, pg = _sharded_diameter(pg, mesh, cfg.diameter_sweeps)
-    ns.dist_cap = 0.0
-    if stream == "weighted":
-        @partial(jax.shard_map, mesh=mesh,
-                 in_specs=(pg.partition_spec(all_axes),),
-                 out_specs=(rep, rep), check_vma=False)
-        def wdiam_step(g):
-            est = estimate_diameter_weighted_sharded(
-                g, n_sweeps=cfg.diameter_sweeps, axis=all_axes)
-            return est.vertex_diameter, est.upper
-        vd_w, cap_w = jax.jit(wdiam_step)(pg)
-        ns.vd, ns.dist_cap = int(vd_w), float(cap_w)
-    ns.t_diam = time.perf_counter() - t0
-    gspec = pg.partition_spec(all_axes)
-    # the cooperative mesh is ONE fast sampler: paper's shared-memory
-    # epoch schedule, not the per-device one
-    ns.graph, ns.v_pad, ns.n_samplers, ns.shardings = pg, v_pad, 1, None
-
-    def calibrate(k_cal, bsz, ctx):
-        # calib_samples_per_device keeps its meaning across lanes: the
-        # mesh cooperatively draws what n_dev independent devices would
-        n_cal = cfg.calib_samples_per_device * n_dev
-
-        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, rep),
-                 out_specs=(rep, rep), check_vma=False)
-        def calib_step(g, k):
-            c, t = draw_fold(g, k, n_cal, batch_size=bsz,
-                             estimators=estimators, ctx=ctx,
-                             stream=stream, axis=all_axes)
-            cp = jnp.zeros(
-                (C, v_pad), jnp.float32).at[:, : c.shape[1]].set(c)
-            return cp, t
-
-        return jax.jit(calib_step)(pg, k_cal)
+    def draw(key, n, bsz, ctx):
+        return program(_sharded_draw_program, draw_fold, mesh, estimators,
+                       stream, ctx, n, bsz)(pg, key)
 
     def make_epoch(params, ctx, n0, bsz):
-        # the engine's own step carries the exchange tally (10th
-        # output) so run_adaptive can price it into telemetry; the
-        # dry-run keeps lowering the default 9-output step
-        epoch_jit = jax.jit(make_epoch_step_sharded(
-            mesh, pg.n_nodes, v_pad, n0, batch_size=bsz,
-            estimators=estimators, stream=stream,
-            vertex_diameter=ctx.vertex_diameter,
-            distance_cap=ctx.distance_cap, with_exchange=True))
-        return lambda state, ke: epoch_jit(pg, params, *state, ke)
+        epoch_step = program(make_epoch_step_sharded, draw_fold, mesh,
+                             estimators, stream, ctx, n0, bsz)
+        # the state committed, as on the single lane
+        rep = NamedSharding(mesh, P())
+        return lambda state, ke: EpochOut(
+            *epoch_step(pg, params, *jax.device_put(state, rep), ke))
 
-    def make_flush(ctx):
-        return lambda state: program(_flush_program)(*state)
-
-    def init_state(ctx):
-        return (jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
-                jnp.zeros((C, v_pad), jnp.float32), jnp.int32(0),
-                jnp.zeros((C, v1), jnp.float32), jnp.int32(0))
-
-    ns.calibrate, ns.make_epoch = calibrate, make_epoch
-    ns.make_flush, ns.init_state = make_flush, init_state
-    return ns
+    # the cooperative mesh is ONE fast sampler: paper's shared-memory
+    # epoch schedule, not the per-device one
+    return Lane(v_pad, 1, None, draw, make_epoch,
+                lambda state: program(_flush_program)(*state),
+                partial(_zero_state, total_channels(estimators), v_pad,
+                        pg.n_nodes + 1))
 
 
 # ---------------------------------------------------------------------------
@@ -1022,17 +1059,15 @@ def run_adaptive(graph, metrics=("betweenness",), *,
     carries their totals.  While a ``jax.profiler`` trace runs, the
     spans are trace annotations too.
 
-    The single lane's programs (phase 1, the calibration draws, the
-    stop-rule params, the epoch step, the flush) are built once per
-    static configuration and kept (``repro.core.programs``): the graph,
-    the keys, the stop-rule params and the state are their arguments.  A
-    repeat call with the same graph shape and configuration, at any key
-    and on any graph of that shape, reuses them and traces and lowers
-    nothing.  ``run.end`` carries ``programs_built`` and
-    ``programs_reused``: of the cached programs the call ran, how many
-    were built in it and how many came from the cache.  The SPMD and
-    sharded lanes still build their calibration and epoch programs in
-    every call; those are not counted.
+    Every lane's programs (phase 1, the calibration draws, the stop-rule
+    params, the epoch step, the flush) are built once per static
+    configuration and kept (``repro.core.programs``): the graph, the
+    keys, the stop-rule params and the state are their arguments.  A
+    repeat call with the same graph shape, mesh and configuration, at
+    any key and on any graph of that shape, reuses them and traces and
+    lowers nothing.  ``run.end`` carries ``programs_built`` and
+    ``programs_reused``: of the programs the call ran, how many were
+    built in it and how many came from the cache.
     """
     from repro.runtime.telemetry import (host_counter_delta,
                                          host_counter_totals,
@@ -1060,48 +1095,44 @@ def run_adaptive(graph, metrics=("betweenness",), *,
             [np.full(est.n_channels, i) for i, est in enumerate(estimators)])
 
         # ---- lane selection + phase 1 (diameter) ---------------------------
-        if isinstance(graph, PartitionedGraph):
-            if mesh is None:
-                raise ValueError(
-                    "a PartitionedGraph needs the mesh its shards map onto "
-                    "(mesh=...); use a plain Graph for the single-device lane")
-            lane_name = "sharded"
-        elif mesh is None or int(np.prod(mesh.devices.shape)) == 1:
-            lane_name = "single"
-        else:
-            lane_name = "spmd"
+        lane_name = _lane_name(graph, mesh)
         telemetry.emit("run.start", lane=lane_name,
                        metrics=[e.name for e in estimators],
                        n_nodes=int(graph.n_nodes), eps=float(cfg.eps),
                        delta=float(cfg.delta))
         c_diam = host_counter_totals()
         with telemetry.span("phase.diameter"):
-            if lane_name == "sharded":
-                lane = _sharded_lane(graph, mesh, cfg, estimators, stream,
-                                     C)
-            elif lane_name == "single":
-                lane = _single_lane(graph, cfg, estimators, stream, C)
-            else:
-                lane = _spmd_lane(graph, mesh, cfg, estimators, stream, C)
+            t0 = time.perf_counter()
+            vd, dist_cap, graph = _phase1(graph, mesh, stream,
+                                          cfg.diameter_sweeps)
+            t_diam = time.perf_counter() - t0
+            lane = _lane(lane_name, graph, mesh, estimators, stream,
+                         cfg.aggregation)
         c_diam_end = host_counter_totals()
 
-        ctx = RunContext(int(lane.graph.n_nodes), lane.vd, lane.dist_cap)
+        ctx = RunContext(int(graph.n_nodes), vd, dist_cap)
         bsz = resolve_sample_batch_size(cfg.sample_batch_size, ctx.n_nodes,
                                         ctx.vertex_diameter)
-        route = frontier_route(lane.graph, bsz, weighted=stream == "weighted")
+        route = frontier_route(graph, bsz, weighted=stream == "weighted")
         # the static per-level price list for the sharded lane's exchange
         # tally (host-side observation only)
-        xplan = (exchange_plan(lane.graph, bsz)
-                 if isinstance(lane.graph, PartitionedGraph) else None)
+        xplan = (exchange_plan(graph, bsz)
+                 if isinstance(graph, PartitionedGraph) else None)
 
         # ---- phase 2: calibration + per-estimator stop-rule params ---------
         t0 = time.perf_counter()
         c_cal = host_counter_totals()
         with telemetry.span("phase.calibration"):
             key, k_cal = jax.random.split(key)
-            counts0, tau0 = lane.calibrate(k_cal, bsz, ctx)
+            # calib_samples_per_device keeps its meaning on every lane:
+            # the cooperative mesh (one sampler) draws what its devices
+            # would draw independently
+            n_dev = 1 if mesh is None else mesh.devices.size
+            counts0, tau0 = lane.draw(
+                k_cal, cfg.calib_samples_per_device * n_dev // lane.n_samplers,
+                bsz, ctx)
             params = tuple(
-                est.make_params(lane.graph, ctx, cfg.eps, cfg.delta,
+                est.make_params(graph, ctx, cfg.eps, cfg.delta,
                                 counts0[off: off + est.n_channels], tau0)
                 for est, off in zip(estimators, offsets))
             jax.block_until_ready((counts0, tau0, params))
@@ -1112,9 +1143,9 @@ def run_adaptive(graph, metrics=("betweenness",), *,
         n0 = epoch_length(lane.n_samplers, base=cfg.n0_base,
                           exponent=cfg.n0_exponent)
         epoch_run = lane.make_epoch(params, ctx, n0, bsz)
-        flush = lane.make_flush(ctx)
+        flush = lane.flush
 
-        state = lane.init_state(ctx)
+        state = lane.init_state()
         frozen_c = jnp.zeros((C, lane.v_pad), jnp.float32)
         frozen_tau = jnp.zeros((E,), jnp.int32)
         stop_epoch = jnp.full((E,), -1, jnp.int32)
@@ -1140,10 +1171,9 @@ def run_adaptive(graph, metrics=("betweenness",), *,
                 with telemetry.span("phase.epoch", epoch=epoch + 1) as span:
                     te = time.perf_counter()
                     k, ke = jax.random.split(k)
-                    out = epoch_run(state, ke)
-                    state, (done, mf, mg) = out[:6], out[6:9]
-                    xch = out[9] if len(out) > 9 else None
-                    steps = out[10] if len(out) > 10 else None
+                    # any sequence in EpochOut's order will do
+                    out = EpochOut._make(epoch_run(state, ke))
+                    state = out.state
                     epoch += 1
                     if on_epoch is not None:
                         # supervision point: runs before freeze + save so a
@@ -1158,7 +1188,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
                         replacement = on_epoch(epoch, state)
                         if replacement is not None:
                             state = tuple(replacement)
-                    newly = np.asarray(done) & ~stopped
+                    newly = np.asarray(out.done) & ~stopped
                     if newly.any():
                         # freeze the newly stopped metrics' deciding snapshot:
                         # the flush of THIS epoch's state — identical to what
@@ -1178,25 +1208,26 @@ def run_adaptive(graph, metrics=("betweenness",), *,
                     # host-side publication of the epoch's counters, at the
                     # on_epoch boundary where the state is already materialized
                     n_samples_epoch = int(state[3]) * lane.n_samplers
+                    xch = out.exchange
                     xacct = (
                         xplan.epoch_accounting(int(xch[0]), int(xch[1]))
                         if xch is not None and xplan is not None else None)
                     levels, streamed = 0, 0
-                    if steps is not None:
-                        levels, streamed = (int(x) for x in np.asarray(steps))
+                    if out.steps is not None:
+                        levels, streamed = (int(x)
+                                            for x in np.asarray(out.steps))
                         span.set(bfs_levels=levels, nb_steps=streamed)
                     e_seconds = time.perf_counter() - te
+                    max_f = [float(x) for x in np.asarray(out.max_f)]
+                    max_g = [float(x) for x in np.asarray(out.max_g)]
                     stats.append(EngineEpochStats(
-                        epoch, int(state[1]),
-                        tuple(float(x) for x in np.asarray(mf)),
-                        tuple(float(x) for x in np.asarray(mg)),
+                        epoch, int(state[1]), tuple(max_f), tuple(max_g),
                         e_seconds, n_samples_epoch, xacct, levels, streamed))
                     if telemetry:
                         telemetry.emit(
                             "epoch.stats", epoch=epoch, tau=int(state[1]),
                             samples=n_samples_epoch, seconds=e_seconds,
-                            max_f=[float(x) for x in np.asarray(mf)],
-                            max_g=[float(x) for x in np.asarray(mg)])
+                            max_f=max_f, max_g=max_g)
                         if xacct is not None:
                             telemetry.emit("exchange.epoch", epoch=epoch,
                                            **xacct)
@@ -1250,11 +1281,11 @@ def run_adaptive(graph, metrics=("betweenness",), *,
             "sampling": host_counter_delta(c_samp, c_samp_end),
             "other": host_counter_delta(c_start, c_diam, c_diam_end, c_cal,
                                         c_cal_end, c_samp, c_samp_end, c_end)}
-        csc = getattr(lane.graph, "csc", None)
+        csc = getattr(graph, "csc", None)
         telemetry.emit("run.end", tau=tau_total, n_epochs=epoch,
                        converged=bool(converged.all()), batch_size=bsz,
-                       route=route, n_nodes=int(lane.graph.n_nodes),
-                       n_edges=int(lane.graph.n_edges),
+                       route=route, n_nodes=int(graph.n_nodes),
+                       n_edges=int(graph.n_edges),
                        block_v=csc.block_v if csc is not None else 0,
                        block_e=csc.block_e if csc is not None else 0,
                        bfs_levels=sum(s.bfs_levels for s in stats),
@@ -1265,7 +1296,7 @@ def run_adaptive(graph, metrics=("betweenness",), *,
         return AdaptiveRunResult(
             tuple(reports), tau_total, epoch, bool(converged.all()),
             ctx.vertex_diameter, stats,
-            {"diameter": lane.t_diam, "calibration": t_cal,
+            {"diameter": t_diam, "calibration": t_cal,
              "sampling": t_samp}, bsz, route, host_counters)
 
 
@@ -1294,74 +1325,30 @@ def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
         key = jax.random.PRNGKey(0)
     if batch_size is None:
         batch_size = DEFAULT_SAMPLE_BATCH_SIZE
-    C = total_channels(estimators)
     offsets = _channel_offsets(estimators)
+    lane_name = _lane_name(graph, mesh)
     # the diameter only feeds accumulate-side normalization (closeness's
     # cap); pure path-count / inverse-distance runs skip phase 1 — the
-    # PR 1-6 fixed baseline's exact behavior (and bit-stream)
+    # PR 1-6 fixed baseline's exact behavior (and bit-stream) — except
+    # on a partitioned graph, where the BFS sweep resolves the exchange
+    # budget
     needs_vd = (stream in ("forward", "weighted")
                 and any(e.needs_diameter for e in estimators))
-
-    if isinstance(graph, PartitionedGraph):
-        if mesh is None:
-            raise ValueError(
-                "a PartitionedGraph needs the mesh its shards map onto "
-                "(mesh=...); use a plain Graph for the single-device lane")
-        all_axes = tuple(mesh.axis_names)
-        vd, graph = _sharded_diameter(graph, mesh, 2)
-        rep = P()
-        dcap = 0.0
-        if stream == "weighted" and needs_vd:
-            @partial(jax.shard_map, mesh=mesh,
-                     in_specs=(graph.partition_spec(all_axes),),
-                     out_specs=(rep, rep), check_vma=False)
-            def wdiam_step(g):
-                est = estimate_diameter_weighted_sharded(g, n_sweeps=2,
-                                                         axis=all_axes)
-                return est.vertex_diameter, est.upper
-            vd_w, cap_w = jax.jit(wdiam_step)(graph)
-            vd, dcap = int(vd_w), float(cap_w)
-        ctx = RunContext(int(graph.n_nodes), vd if needs_vd else 0, dcap)
-        gspec = graph.partition_spec(all_axes)
-
-        @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, rep),
-                 out_specs=(rep, rep), check_vma=False)
-        def fixed_step(g, k):
-            return draw_fold(g, k, n_samples, estimators=estimators,
-                             ctx=ctx, stream=stream,
-                             batch_size=batch_size, axis=all_axes)
-
-        counts, tau = jax.jit(fixed_step)(graph, key)
-    else:
-        vd, dcap = (_replicated_phase1(graph, stream, 2) if needs_vd
-                    else (0, 0.0))
-        ctx = RunContext(int(graph.n_nodes), vd, dcap)
-        n_dev = 1 if mesh is None else int(np.prod(mesh.devices.shape))
-        if n_dev == 1:
-            counts, tau = jax.jit(partial(
-                draw_fold, n_samples=n_samples, batch_size=batch_size,
-                estimators=estimators, ctx=ctx, stream=stream))(graph, key)
-        else:
-            # per-device independent draws + one blocking reduce; the
-            # total is n_samples rounded up to a device multiple (tau
-            # reports the true count, so the estimates stay exact)
-            all_axes = tuple(mesh.axis_names)
-            per_dev = -(-n_samples // n_dev)
-            rep = P()
-            key_spec = P(all_axes)
-            gspec = jax.tree.map(lambda _: rep, graph)
-
-            @partial(jax.shard_map, mesh=mesh, in_specs=(gspec, key_spec),
-                     out_specs=(rep, rep), check_vma=False)
-            def fixed_step(g, keys):
-                c, t = draw_fold(g, keys[0], per_dev,
-                                 estimators=estimators, ctx=ctx,
-                                 stream=stream, batch_size=batch_size)
-                return (dist.flat_allreduce(c, all_axes),
-                        dist.flat_allreduce(t, all_axes))
-
-            dev_keys = jax.random.split(key, n_dev)
-            counts, tau = jax.jit(fixed_step)(graph, dev_keys)
+    ctx = RunContext(int(graph.n_nodes), 0, 0.0)
+    if needs_vd or lane_name == "sharded":
+        # the weighted sweep only where its bounds are used
+        vd, dcap, graph = _phase1(graph, mesh,
+                                  stream if needs_vd else "bidir", 2)
+        if needs_vd:
+            ctx = RunContext(int(graph.n_nodes), vd, dcap)
+    # only the epochs aggregate: the draws reduce with one flat psum
+    lane = _lane(lane_name, graph, mesh, estimators, stream,
+                 AdaptiveConfig.aggregation)
+    # n samples in all, rounded up to a whole number per sampler (tau
+    # counts what was drawn, so the estimates stay exact)
+    counts, tau = lane.draw(key, -(-n_samples // lane.n_samplers),
+                            batch_size, ctx)
+    counts = counts[:, : graph.n_nodes + 1]
 
     reports = []
     for est, off in zip(estimators, offsets):

@@ -3,16 +3,19 @@
 A function wrapped in ``jax.jit`` afresh on every call never hits jit's
 in-memory cache: each call traces and lowers it again, even where a
 persistent compilation cache spares the backend compile.  ``program``
-keeps one jitted function per builder and static values, so a repeat
-``run_adaptive`` call with the same graph shape and configuration
-reuses its programs and traces and lowers nothing.
+keeps one jitted function per builder and static values.  Every program
+of every lane of ``repro.core.engine`` is built here, so a repeat
+``run_adaptive`` or ``run_fixed`` call with the same graph shape, mesh
+and configuration reuses its programs and traces and lowers nothing.
 
 The contract of a builder: ``build(*static)`` returns the function to
 jit, every value it closes over is one of ``static`` (hashable, compared
-by value: ints, floats, strings, ``RunContext``, estimators, functions),
-and every array is an argument of the returned function.  An array held
-in a closure would be a constant of the cached program, and a second
-graph of the same shape would get the first graph's results.
+by value: ints, floats, strings, ``RunContext``, estimators, functions,
+meshes), and every array is an argument of the returned function.  An
+array held in a closure would be a constant of the cached program, and
+a second graph of the same shape would get the first graph's results.
+A ``shard_map`` inside a program takes its specs from the arguments'
+structure, inside the traced function.
 
 ``ProgramTally`` counts, for one run, which programs it called and
 whether each was built in the run or came from the cache.

@@ -609,8 +609,9 @@ def run_betweenness(mesh_name: str, aggregation: str,
     shard bytes vs the replicated-layout equivalent (the
     O(E) -> O(E / n_dev) claim, measured)."""
     import jax.numpy as jnp
-    from repro.core.adaptive import make_epoch_step_spmd, _pad_len
+    from repro.core.engine import _pad_len, draw_fold, make_epoch_step_spmd
     from repro.core.estimators import get_estimator
+    from repro.core.estimators.base import RunContext
     from repro.core.kadabra import KadabraParams
     from repro.core.graph import Graph
     from repro.models.common import active_mesh
@@ -630,7 +631,7 @@ def run_betweenness(mesh_name: str, aggregation: str,
     n_chan = sum(e.n_channels for e in ests)
     # representative R-MAT vertex diameter — static input of the epoch
     # step (closeness' distance cap); any small int lowers the same HLO
-    vdiam = 12
+    ctx = RunContext(v, 12)
 
     sds = jax.ShapeDtypeStruct
     # every shipped estimator parameterizes the shared Bernstein rule
@@ -657,7 +658,7 @@ def run_betweenness(mesh_name: str, aggregation: str,
 
     exchange = None
     if partitioned:
-        from repro.core.adaptive import make_epoch_step_sharded
+        from repro.core.engine import make_epoch_step_sharded
         from repro.core.partition import (abstract_partitioned_graph,
                                           exchange_plan)
         from repro.kernels.frontier.ops import choose_csc_blocks
@@ -691,10 +692,8 @@ def run_betweenness(mesh_name: str, aggregation: str,
                     "occupancy fits exchange_budget_blocks on every "
                     "shard, level_bytes_dense_protocol otherwise",
         }
-        step = make_epoch_step_sharded(mesh, v, v_pad, n0,
-                                       batch_size=batch_size,
-                                       estimators=ests, stream=stream,
-                                       vertex_diameter=vdiam)
+        step = make_epoch_step_sharded(draw_fold, mesh, ests, stream, ctx,
+                                       n0, batch_size)
         args = (pg, params,
                 sds((n_chan, v_pad), jnp.float32), sds((), jnp.int32),
                 sds((n_chan, v_pad), jnp.float32), sds((), jnp.int32),
@@ -707,10 +706,8 @@ def run_betweenness(mesh_name: str, aggregation: str,
             src=sds((e_pad,), jnp.int32), dst=sds((e_pad,), jnp.int32),
             degree=sds((v,), jnp.int32), n_nodes=v, n_edges=e_dir,
             max_degree=100_000)
-        step = make_epoch_step_spmd(mesh, aggregation, v, v_pad, n0,
-                                    batch_size=batch_size,
-                                    estimators=ests, stream=stream,
-                                    vertex_diameter=vdiam)
+        step = make_epoch_step_spmd(draw_fold, mesh, aggregation, ests,
+                                    stream, ctx, n0, batch_size)
         args = (graph, params,
                 sds((n_chan, v_pad), jnp.float32), sds((), jnp.int32),
                 sds((n_dev, n_chan, v_pad), jnp.float32),

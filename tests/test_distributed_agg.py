@@ -10,7 +10,7 @@ import subprocess
 import sys
 import textwrap
 
-from repro.core.adaptive import _pad_len
+from repro.core.engine import _pad_len
 
 
 def test_pad_len_divisibility_contract():
@@ -39,7 +39,7 @@ _AGG_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import distributed as dist
-    from repro.core.adaptive import _pad_len
+    from repro.core.engine import _pad_len
 
     n_dev = 8
     v_pad = _pad_len(997, n_dev)          # awkward V, padded contract

@@ -38,9 +38,10 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import distributed as dist
-from repro.core.adaptive import (DEFAULT_SAMPLE_BATCH_SIZE, _pad_len,
+from repro.core.adaptive import (DEFAULT_SAMPLE_BATCH_SIZE,
                                  resolve_sample_batch_size)
 from repro.core.diameter import estimate_diameter, estimate_diameter_sharded
+from repro.core.engine import _pad_len
 from repro.core.epoch import StateFrame, epoch_length, zero_frame
 from repro.core.kadabra import (KadabraParams, calibrate_deltas, check_stop,
                                 compute_omega)
@@ -413,6 +414,67 @@ def test_spmd_and_sharded_lanes_bit_match_frozen_legacy_8dev():
     assert out.stdout.count("OK") == 4
 
 
+_FIXED_MESH_SCRIPT = r"""
+import os
+import sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax
+import numpy as np
+from repro.core import erdos_renyi_graph, partition_graph, run_fixed
+from repro.core.engine import draw_fold
+from repro.core.estimators import get_estimator
+from repro.core.estimators.base import RunContext
+
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
+g = erdos_renyi_graph(96, 5.0, seed=5)
+key = jax.random.PRNGKey(4)
+n, bsz = 100, 8
+ests = (get_estimator("betweenness"),)
+ctx = RunContext(g.n_nodes, 0)
+
+
+def drawn(k, m):
+    c, t = jax.jit(lambda k: draw_fold(g, k, m, estimators=ests, ctx=ctx,
+                                       stream="bidir", batch_size=bsz))(k)
+    return np.asarray(c), int(t)
+
+
+if sys.argv[1] == "spmd":
+    # every device draws ceil(n / 8) at its own key, then one sum (the
+    # counts are whole numbers, so any order of the sum is exact)
+    parts = [drawn(k, -(-n // 8)) for k in jax.random.split(key, 8)]
+    counts = sum(c for c, _ in parts)
+    tau = sum(t for _, t in parts)
+    graph = g
+else:
+    # the mesh draws n cooperatively at the one key: the draw of one
+    # device holding the whole graph
+    counts, tau = drawn(key, n)
+    graph = partition_graph(g, 8)
+(rep,) = run_fixed(graph, n, key=key, mesh=mesh, batch_size=bsz)
+assert rep.tau == tau, (rep.tau, tau)
+np.testing.assert_array_equal(rep.scores, counts[0, : g.n_nodes] / tau)
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("lane", ["spmd", "sharded"])
+def test_run_fixed_mesh_lanes_match_draw_fold_oracle_8dev(lane):
+    """run_fixed draws through the lane's own draw program: on the SPMD
+    lane the per-device draws of ceil(n / n_dev) samples summed, on the
+    sharded lane the n samples one device would draw."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", _FIXED_MESH_SCRIPT, lane],
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, \
+        f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
+    assert out.stdout.count("OK") == 1
+
+
 # ---------------------------------------------------------------------------
 # Closeness / harmonic vs dense oracles
 # ---------------------------------------------------------------------------
@@ -565,7 +627,7 @@ def test_pre_refactor_checkpoint_fails_loudly(tmp_path):
     import json
     import jax.numpy as jnp
     from repro.checkpoint.store import CheckpointSchemaError, save
-    from repro.core.adaptive import _pad_len
+    from repro.core.engine import _pad_len
     g = erdos_renyi_graph(80, 5.0, seed=0)
     v_pad = _pad_len(g.n_nodes, 1)
     # the legacy 7-leaf tuple, exactly as the old _EpochCheckpointer

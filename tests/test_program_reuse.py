@@ -1,14 +1,22 @@
-"""The single lane's programs are built once per static configuration
-and reused by every later ``run_adaptive`` call (``repro.core.programs``).
+"""Every lane's programs are built once per static configuration and
+reused by every later ``run_adaptive`` call (``repro.core.programs``).
 
-A repeat call on one graph at a new key lowers nothing and compiles
-nothing, and gives what the same key gives first in a cleared cache.
-Two graphs of one shape with different edges share every program and
-each still gets its own results, in either order: no array is held in
-a cached program.  Each case runs on the ``bidir`` stream (betweenness)
-and on the ``forward`` stream (closeness and harmonic).
+On the single lane, a repeat call on one graph at a new key lowers
+nothing and compiles nothing, and gives what the same key gives first
+in a cleared cache.  Two graphs of one shape with different edges share
+every program and each still gets its own results, in either order: no
+array is held in a cached program.  Each case runs on the ``bidir``
+stream (betweenness) and on the ``forward`` stream (closeness and
+harmonic).  On the SPMD and sharded lanes (8 fake devices, in a
+subprocess) a repeat call builds nothing and gives the first call's
+results.
 """
 import contextlib
+import json
+import os
+import pathlib
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -132,3 +140,61 @@ def test_two_graphs_of_one_shape_get_their_own_results(graphs, alone, case,
     assert got_i[0] == alone[case, i]
     assert got_j[0] == alone[case, j]
     assert got_i[0] != got_j[0]
+
+
+_MESH_CALLS = """
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax
+import numpy as np
+from repro.core import (AdaptiveConfig, erdos_renyi_graph, partition_graph,
+                        run_adaptive)
+from repro.runtime import RingSink, Telemetry
+
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
+g = erdos_renyi_graph(96, 5.0, seed=5)
+if sys.argv[1] == "sharded":
+    g = partition_graph(g, 8)
+cfg = AdaptiveConfig(eps=0.08, delta=0.1, n0_base=200, max_epochs=3)
+lowered = []
+
+def listen(event, duration, **kw):
+    if event == "%s":
+        lowered.append(kw.get("fun_name"))
+
+jax.monitoring.register_event_duration_secs_listener(listen)
+out = []
+for _ in range(2):
+    del lowered[:]
+    sink = RingSink()
+    res = run_adaptive(g, config=cfg, key=jax.random.PRNGKey(3), mesh=mesh,
+                       telemetry=Telemetry([sink]))
+    (end,) = [ev.fields for ev in sink.events if ev.kind == "run.end"]
+    out.append(dict(built=end["programs_built"],
+                    reused=end["programs_reused"], epochs=res.n_epochs,
+                    lowerings=len(lowered),
+                    epoch_lowerings=lowered.count("jit(epoch_step)"),
+                    tau=res.tau,
+                    scores=np.asarray(res.reports[0].scores).tobytes().hex()))
+print(json.dumps(out))
+""" % LOWERING
+
+
+@pytest.mark.parametrize("lane", ["spmd", "sharded"])
+def test_a_repeat_call_on_a_mesh_lane_builds_nothing_8dev(lane):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1]
+                            / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", _MESH_CALLS, lane], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    first, second = json.loads(out.stdout.strip().splitlines()[-1])
+    assert first["built"] >= 5 and first["reused"] == 0
+    # one epoch program for all epochs: the lane commits the state
+    assert first["epochs"] >= 2 and first["epoch_lowerings"] == 1
+    assert second["built"] == 0 and second["reused"] == first["built"]
+    assert second["lowerings"] == 0
+    assert (second["tau"], second["scores"]) == (first["tau"],
+                                                 first["scores"])

@@ -358,9 +358,11 @@ def test_resilient_runner_bit_identical_under_faults(tmp_path):
     the last good checkpoint and its final estimate is bit-identical
     to an uninterrupted run at the same seed."""
     g = _small_graph(v=120, e=480)
-    cfg = AdaptiveConfig(eps=0.05, delta=0.1, max_epochs=12)
+    # short epochs: the clean run takes 5, so every fault below fires
+    cfg = AdaptiveConfig(eps=0.05, delta=0.1, n0_base=250, max_epochs=12)
     base = run_kadabra(g, config=cfg, key=jax.random.PRNGKey(7),
                        checkpoint_dir=str(tmp_path / "clean"))
+    assert base.n_epochs > 3
     # one fault epoch each: a raising fault aborts the hook, so faults
     # sharing an epoch with it would be consumed without applying
     sched = FaultSchedule([FaultSpec("kill", 1), FaultSpec("nan", 2),
