@@ -92,8 +92,8 @@ class BFSResult(NamedTuple):
     search ran to frontier exhaustion; with ``stop_nodes`` the search
     exits as soon as its stop node settles, so levels = dist(source,
     stop_node) — a *lower bound* on the eccentricity, not the
-    eccentricity itself.  Diameter estimation (``estimate_diameter``)
-    therefore always runs its sweeps without stop nodes.
+    eccentricity itself.  The sharded diameter sweep
+    (``estimate_diameter_sharded``) therefore runs without stop nodes.
     """
     dist: jax.Array    # (rows, B) | (rows,) int32; -1 unreached, -3 sink/pad
     sigma: jax.Array   # (rows, B) | (rows,) float32; rescaled path counts

@@ -4,7 +4,7 @@ KADABRA only needs an *upper bound* on the vertex diameter VD(G) (the
 number of vertices on the longest shortest path) to compute the static
 sample-size cap omega.  The paper uses the sequential iFUB-style algorithm
 of Borassi et al. [6]; here we use the classic double-sweep scheme built on
-the same edge-centric BFS as the sampler:
+batched BFS:
 
   * BFS from a seed vertex -> farthest vertex u      (ecc(seed))
   * BFS from u             -> farthest vertex v      (lower bound = d(u,v))
@@ -12,34 +12,37 @@ the same edge-centric BFS as the sampler:
 
 Double sweep is known to be exact on most real-world complex networks and
 the upper bound only loosens omega (never the guarantee).  All K seed
-chains run as ONE ``bfs_sssp_batched`` call per sweep phase (K seeds
-batched, then their K far-vertices batched), so phase 1 — the paper's
-Fig. 2b scalability bottleneck, which it runs as sequential scalar BFS —
-uses the same batched vertex-major relaxation lane as the sampling phase
-and streams the edge list once per level for all chains.  On a graph
-with a persisted CSC layout the sweeps inherit the CSC-aware driver
-wholesale: the (csc.v_pad, K) state is allocated padded up front and
-every level runs the node-blocked/occupancy-skipping dispatcher lane
-with zero per-call pads or slices (the ``[: graph.n_nodes]`` cut below
-happens once per sweep, on the *result*, exactly like the sink-row cut).
-Every BFS runs *without* stop nodes, so ``BFSResult.levels`` really is
-the eccentricity (with an early stop it would only be a lower bound —
-see the BFSResult contract).
+chains run as ONE batched BFS per sweep phase (K seeds batched, then
+their K far-vertices batched), so phase 1 — the paper's Fig. 2b
+scalability bottleneck, which it runs as sequential scalar BFS —
+streams the edge list once per level for all chains.
+
+The replicated unweighted sweeps need distances only, so they run their
+own driver, :func:`bfs_dist_pull`, and not the sampler's path-counting
+``bfs_sssp_batched``: a level *pulls* over the CSR rows.  It gathers
+each edge slot's source-is-frontier bit, takes an exact int32 prefix
+sum over the edge slots, and reads every vertex's count of frontier
+neighbours as the difference of that sum at its two row pointers — no
+scatter, no float and no path counts.  Its cost does not depend on an
+attached CSC layout, so a graph with one takes it too.  The sharded and
+weighted sweeps keep the drivers of their lanes.  Every BFS runs to
+frontier exhaustion, so its levels are the eccentricity (with an early
+stop they would only be a lower bound — see ``BFSResult``).
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from .bfs import (bfs_sssp_batched, bfs_sssp_batched_sharded,
-                  delta_sssp_batched, delta_sssp_batched_sharded)
+from .bfs import (bfs_sssp_batched_sharded, delta_sssp_batched,
+                  delta_sssp_batched_sharded)
 from .graph import Graph
 from .partition import PartitionedGraph, axis_tuple
 
-__all__ = ["DiameterEstimate", "WeightedDiameterEstimate",
-           "estimate_diameter", "estimate_diameter_sharded",
+__all__ = ["DiameterEstimate", "PullBFS", "WeightedDiameterEstimate",
+           "bfs_dist_pull", "estimate_diameter", "estimate_diameter_sharded",
            "estimate_diameter_weighted",
            "estimate_diameter_weighted_sharded"]
 
@@ -48,15 +51,64 @@ class DiameterEstimate(NamedTuple):
     lower: jax.Array        # () int32 — best shortest-path length found
     upper: jax.Array        # () int32 — valid upper bound on the diameter
     vertex_diameter: jax.Array  # () int32 — upper bound on VD = upper + 1
+    # () int32 — BFS levels the sweeps expanded, summed over both sweeps;
+    # None on the sharded lane, whose sweeps do not count them
+    levels: Optional[jax.Array] = None
+
+
+class PullBFS(NamedTuple):
+    """K distance-only BFS of :func:`bfs_dist_pull`."""
+    dist: jax.Array    # (K, V) int32, chain-major; -1 unreached
+    levels: jax.Array  # (K,) int32 — eccentricity of each source
+    trips: jax.Array   # () int32 — levels expanded: max(levels) + 1
+
+
+def bfs_dist_pull(graph: Graph, sources) -> PullBFS:
+    """K full BFS from ``sources`` (K,), distances only.
+
+    The K chains are rows of one (K, V) state.  One level for all of
+    them, with ``front`` 0/1 and 0 in the sink column (where the padded
+    edge slots point)::
+
+        hit = front[:, indices]                     # (K, E_pad) gather
+        C   = [0, cumsum(hit)]                      # exact in int32
+        cnt[k, v] = C[k, indptr[v+1]] - C[k, indptr[v]]
+
+    ``cnt`` counts v's frontier neighbours: ``indices`` holds both
+    directions of every edge, sorted by source, so v's row lists its
+    neighbours.  The loop runs until every chain's frontier is empty,
+    so ``levels`` is each source's eccentricity and ``trips`` counts the
+    empty last level too, as ``bfs_sssp_batched``'s ``steps`` does.
+    """
+    sources = jnp.asarray(sources, jnp.int32)
+    k = sources.shape[0]
+    dist0 = jnp.full((k, graph.n_nodes), -1,
+                     jnp.int32).at[jnp.arange(k), sources].set(0)
+
+    def body(state):
+        dist, level, _ = state
+        front = jnp.pad((dist == level).astype(jnp.int32), ((0, 0), (0, 1)))
+        hit = front[:, graph.indices]
+        c = jnp.pad(jnp.cumsum(hit, axis=1), ((0, 0), (1, 0)))
+        at_rows = c[:, graph.indptr]
+        cnt = at_rows[:, 1:] - at_rows[:, :-1]
+        new = (cnt > 0) & (dist == -1)
+        return jnp.where(new, level + 1, dist), level + 1, jnp.any(new)
+
+    # one trip per level, so the last level is the trip count
+    dist, trips, _ = jax.lax.while_loop(
+        lambda state: state[2], body,
+        (dist0, jnp.int32(0), jnp.bool_(True)))
+    return PullBFS(dist, jnp.max(dist, axis=1), trips)
 
 
 def _sweep_batched(graph: Graph, seeds):
-    """One batched sweep: K seeds -> (ecc (K,), farthest vertex (K,))."""
-    res = bfs_sssp_batched(graph, seeds)
+    """One batched sweep: K seeds -> (ecc (K,), farthest vertex (K,),
+    levels expanded ())."""
+    res = bfs_dist_pull(graph, seeds)
     # farthest *reached* vertex per chain (ties broken towards lower id)
-    far = jnp.argmax(jnp.where(res.dist >= 0, res.dist,
-                               -1)[: graph.n_nodes, :], axis=0)
-    return res.levels, far.astype(jnp.int32)
+    far = jnp.argmax(res.dist, axis=1)
+    return res.levels, far.astype(jnp.int32), res.trips
 
 
 def estimate_diameter(graph: Graph, key=None, n_sweeps: int = 2) -> DiameterEstimate:
@@ -64,19 +116,20 @@ def estimate_diameter(graph: Graph, key=None, n_sweeps: int = 2) -> DiameterEsti
 
     ``n_sweeps - 1`` independent chains (minimum one) run concurrently:
     each phase is a single batched BFS over all chains' frontiers.
+    ``levels`` counts the BFS levels both sweeps expanded.
     """
     if key is None:
         key = jax.random.PRNGKey(0)
     seeds = jax.random.randint(key, (max(1, n_sweeps - 1),), 0, graph.n_nodes)
 
-    ecc0, far0 = _sweep_batched(graph, seeds)
-    ecc1, _far1 = _sweep_batched(graph, far0)
+    ecc0, far0, trips0 = _sweep_batched(graph, seeds)
+    ecc1, _far1, trips1 = _sweep_batched(graph, far0)
     lowers = ecc1                       # d(far0, far1) realized by BFS
     uppers = 2 * jnp.minimum(ecc0, ecc1)
     uppers = jnp.maximum(uppers, lowers)  # keep each interval consistent
     lower = jnp.max(lowers)
     upper = jnp.maximum(jnp.min(uppers), lower)
-    return DiameterEstimate(lower, upper, upper + 1)
+    return DiameterEstimate(lower, upper, upper + 1, trips0 + trips1)
 
 
 # ---------------------------------------------------------------------------

@@ -643,8 +643,11 @@ def _sharded_phase1_program(mesh, weighted: bool, n_sweeps: int):
 
 def _phase1(graph, mesh, stream: str, n_sweeps: int):
     """Phase 1 on any lane's graph -> (vertex diameter, distance cap,
-    graph).  The cap is the weighted-diameter bound on the 'weighted'
-    stream, 0.0 on the others.
+    graph, stats).  The cap is the weighted-diameter bound on the
+    'weighted' stream, 0.0 on the others.  ``stats`` is what the
+    ``phase.diameter`` span carries: on a replicated graph's BFS sweeps
+    (``bfs_dist_pull``) ``phase1_route`` "pull" and ``phase1_levels``,
+    the levels both sweeps expanded; empty on the other sweeps.
 
     On a partitioned graph the BFS double sweep always runs first: with
     ``exchange_budget="auto"`` it doubles as the budget's occupancy
@@ -654,8 +657,11 @@ def _phase1(graph, mesh, stream: str, n_sweeps: int):
     weighted = stream == "weighted"
     if not isinstance(graph, PartitionedGraph):
         est = program(_phase1_program, weighted, n_sweeps)(graph)
-        return (int(est.vertex_diameter),
-                float(est.upper) if weighted else 0.0, graph)
+        if weighted:
+            return int(est.vertex_diameter), float(est.upper), graph, {}
+        vd, levels = jax.device_get((est.vertex_diameter, est.levels))
+        return (int(vd), 0.0, graph,
+                {"phase1_route": "pull", "phase1_levels": int(levels)})
     vd, dist_dev = program(_sharded_phase1_program, mesh, False,
                            n_sweeps)(graph)
     vd = int(vd)
@@ -670,10 +676,10 @@ def _phase1(graph, mesh, stream: str, n_sweeps: int):
             graph, exchange_budget=auto_exchange_budget(graph, occupancies),
             exchange_budget_auto=False)
     if not weighted:
-        return vd, 0.0, graph
+        return vd, 0.0, graph, {}
     vd_w, cap_w = program(_sharded_phase1_program, mesh, True,
                           n_sweeps)(graph)
-    return int(vd_w), float(cap_w), graph
+    return int(vd_w), float(cap_w), graph, {}
 
 
 def _calibration_program(draw, estimators, stream: str, ctx: RunContext,
@@ -1101,10 +1107,11 @@ def run_adaptive(graph, metrics=("betweenness",), *,
                        n_nodes=int(graph.n_nodes), eps=float(cfg.eps),
                        delta=float(cfg.delta))
         c_diam = host_counter_totals()
-        with telemetry.span("phase.diameter"):
+        with telemetry.span("phase.diameter") as span:
             t0 = time.perf_counter()
-            vd, dist_cap, graph = _phase1(graph, mesh, stream,
-                                          cfg.diameter_sweeps)
+            vd, dist_cap, graph, phase1_stats = _phase1(
+                graph, mesh, stream, cfg.diameter_sweeps)
+            span.set(**phase1_stats)
             t_diam = time.perf_counter() - t0
             lane = _lane(lane_name, graph, mesh, estimators, stream,
                          cfg.aggregation)
@@ -1337,8 +1344,8 @@ def run_fixed(graph, n_samples: int, *, metrics=("betweenness",),
     ctx = RunContext(int(graph.n_nodes), 0, 0.0)
     if needs_vd or lane_name == "sharded":
         # the weighted sweep only where its bounds are used
-        vd, dcap, graph = _phase1(graph, mesh,
-                                  stream if needs_vd else "bidir", 2)
+        vd, dcap, graph, _ = _phase1(graph, mesh,
+                                     stream if needs_vd else "bidir", 2)
         if needs_vd:
             ctx = RunContext(int(graph.n_nodes), vd, dcap)
     # only the epochs aggregate: the draws reduce with one flat psum

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import kronecker_graph
 from repro.core import (AdaptiveConfig, brandes_numpy, grid_graph,
                         rmat_graph, run_adaptive, with_csc_layout)
 from repro.core import bfs, graph as graph_mod
@@ -28,22 +29,8 @@ from repro.runtime import RingSink, Telemetry
 B = 64
 
 
-def _kronecker(scale=10, edgefactor=16, seed=3):
-    """A small Graph500 Kronecker graph (initiator 0.57/0.19/0.19,
-    labels permuted)."""
-    rng = np.random.default_rng(seed)
-    n, m = 1 << scale, edgefactor << scale
-    ij = np.zeros((2, m), np.int64)
-    for bit in range(scale):
-        ii = rng.random(m) > 0.76
-        jj = rng.random(m) > np.where(ii, 0.19 / 0.24, 0.57 / 0.76)
-        ij[0] += ii.astype(np.int64) << bit
-        ij[1] += jj.astype(np.int64) << bit
-    return graph_mod.from_edge_list(rng.permutation(n)[ij].T, n)
-
-
 @pytest.mark.parametrize("make", [lambda: rmat_graph(9, 16, seed=1),
-                                  _kronecker],
+                                  kronecker_graph],
                          ids=["rmat9", "kronecker10"])
 def test_low_diameter_graphs_build_no_layout(monkeypatch, make):
     """A plain Kronecker-class graph samples on the route its size gives,
@@ -160,3 +147,32 @@ def test_epoch_levels_are_the_rounds_trip_counts():
     (end,) = [e.fields for e in tel.events() if e.kind == "run.end"]
     assert (end["bfs_levels"], end["nb_steps"], end["route"]) == \
         (sum(want), 0, "ref")
+
+
+def test_phase1_span_counts_the_sweeps_levels(cold_programs):
+    """The phase.diameter span carries the pull route and the levels the
+    double sweep expanded: on a lattice each sweep expands its source's
+    eccentricity (a Manhattan distance) plus the empty last level.  A
+    repeat call builds no program and counts the same."""
+    side = 16
+    g = grid_graph(side, side)
+    cfg = AdaptiveConfig(eps=0.05, delta=0.1, n0_base=64, max_epochs=1,
+                         sample_batch_size=16)
+    # phase 1 seeds its sweep at PRNGKey(0), whatever the run's key
+    (seed,) = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (1,), 0,
+                                            g.n_nodes))
+    every = np.arange(g.n_nodes)
+    d_seed = _manhattan(side, seed, every)
+    far = int(np.argmax(d_seed))
+    want = d_seed.max() + 1 + _manhattan(side, far, every).max() + 1
+
+    for key in (1, 2):
+        tel = Telemetry([RingSink()], validate=True)
+        run_adaptive(g, config=cfg, key=jax.random.PRNGKey(key),
+                     telemetry=tel)
+        (diam,) = [e.fields for e in tel.events() if e.kind == "span.end"
+                   and e.fields["name"] == "phase.diameter"]
+        (end,) = [e.fields for e in tel.events() if e.kind == "run.end"]
+        assert (diam["phase1_route"], diam["phase1_levels"]) == ("pull",
+                                                                 want)
+    assert end["programs_built"] == 0
